@@ -125,10 +125,11 @@ def _add_compile_cache(p: argparse.ArgumentParser) -> None:
                    action=argparse.BooleanOptionalAction,
                    help="persistent XLA compilation cache for any jax "
                         "this process runs (default on; location: "
-                        "$MAPREDUCE_TPU_CACHE, else the package-"
-                        "adjacent .jax_cache, else the user cache "
-                        "dir).  Without it every worker/server process "
-                        "re-pays the ~100s cold compile")
+                        "$JAX_COMPILATION_CACHE_DIR where set, else "
+                        "the package-adjacent .jax_cache, else the "
+                        "user cache dir).  Without it every "
+                        "worker/server process re-pays the cold "
+                        "compile")
 
 
 def _setup_compile_cache(args) -> Optional[str]:
@@ -2198,15 +2199,16 @@ def cmd_drain(argv: List[str]) -> int:
 
 def cmd_warmup(argv: List[str]) -> int:
     """Prime the persistent XLA compilation cache for the device engine
-    (cold compile is ~100s at bench shapes — the lax.sort comparator;
-    utils/compile_cache.py has the analysis).  Run once per machine /
-    config; afterwards every corpus size hits the warm cache because the
-    auto wave split is corpus-size-independent."""
+    (the cold compile is dominated by the lax.sort comparator).  Run
+    once per machine / config; afterwards every corpus size hits the
+    warm cache because the auto wave split is corpus-size-independent."""
     p = argparse.ArgumentParser(prog="mapreduce_tpu warmup")
     p.add_argument("--chunk-len", type=int, default=1 << 22)
     p.add_argument("--cache-dir", default=None,
-                   help="persistent cache location (default: package-"
-                        "adjacent .jax_cache, or $MAPREDUCE_TPU_CACHE)")
+                   help="persistent cache location where "
+                        "$JAX_COMPILATION_CACHE_DIR is unset (default: "
+                        "package-adjacent .jax_cache); the variable "
+                        "wins where set")
     p.add_argument("--bench", action="store_true",
                    help="use bench.py's engine capacities instead of the "
                         "DeviceWordCount defaults")
@@ -2260,8 +2262,8 @@ def cmd_warmup(argv: List[str]) -> int:
         # the ~100s it just spent compiles again in every process
         print(f"ERROR: compile-cache dir {path!r} is not writable — "
               "this warmup would persist nothing (set "
-              "$MAPREDUCE_TPU_CACHE or --cache-dir to a writable "
-              "path)", file=sys.stderr)
+              "$JAX_COMPILATION_CACHE_DIR or --cache-dir to a "
+              "writable path)", file=sys.stderr)
         return 1
 
     from .engine import DeviceWordCount

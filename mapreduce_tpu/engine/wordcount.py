@@ -98,8 +98,9 @@ def bench_engine_config() -> EngineConfig:
     """The flagship bench's engine capacities (bench.py and the
     ``warmup`` CLI must agree bit-for-bit for the persistent compilation
     cache to hit).  tile_records 104: ~25% headroom over the ~83 words
-    per 512-byte tile of natural text, and measurably less sort work
-    than 128's half-empty record slots (scratch/prof_tune.py).
+    per 512-byte tile of natural text, and fewer half-empty record
+    slots to sort than 128 (the gain is not measured on current
+    hardware).
     combine_in_scan: natural text is duplicate-heavy (a 4MB chunk holds
     ~850K running words but well under 100K uniques), so the in-scan
     combiner shrinks the device-wide sort ~4x; combine_capacity 1<<17

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import compile as _compile_obs
-from ..utils.jax_compat import pcast, shard_map
 
 Params = Dict[str, jax.Array]
 
@@ -116,7 +115,7 @@ def pipeline_forward_local(params: Params, x: jax.Array,
     # produces: varying over the pipeline axis (the body mixes in
     # axis_index) AND over whatever axes shard the batch — zeros derived
     # from inj inherit the latter, pcast adds the former
-    varying = lambda a: pcast(a, model_axis, to="varying")
+    varying = lambda a: jax.lax.pcast(a, model_axis, to="varying")
     outs0 = varying(inj * 0.0)
     buf0 = varying(inj[0] * 0.0)
     (_, outs), _ = jax.lax.scan(tick, (buf0, outs0),
@@ -146,7 +145,7 @@ class PipelinedTrainer:
             nll = -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
             return jax.lax.pmean(nll, "data")
 
-        loss_fn = shard_map(
+        loss_fn = jax.shard_map(
             local_loss, mesh=mesh,
             in_specs=(pspecs, P("data"), P("data")), out_specs=P())
 

@@ -615,8 +615,6 @@ class LedgeredJit:
                 self._plain.add(sig)
                 return self._jit(*args)
             compiled = self._acquire(structs, sig)
-            if compiled is None:
-                return self._jit(*args)
         try:
             return compiled(*args)
         except (TypeError, ValueError):
@@ -630,7 +628,11 @@ class LedgeredJit:
             self._plain.add(sig)
             return self._jit(*args)
 
-    def _acquire(self, structs, sig) -> Optional[Any]:
+    def _acquire(self, structs, sig) -> Any:
+        """Compile through the ledger.  A compile error propagates —
+        once, with its own traceback: retrying the same program under
+        plain ``jit`` would only pay the compile again to raise it
+        again."""
         import jax
 
         replay_doc = None
@@ -643,18 +645,11 @@ class LedgeredJit:
                 # pin the dispatch stack through the traceback
                 logger.warning("replay-info probe for %s failed: %s",
                                self.program, str(exc))
-        try:
-            compiled, _outcome = self._ledger.compile(
-                self._jit, structs, program=self.program,
-                key=self._key, donate_argnums=self._donate,
-                replay=replay_doc, bucket_extra=self._bucket_extra,
-                tier=self.tier)
-        except Exception as exc:
-            logger.warning(
-                "instrumented compile of %s failed (%s); plain jit "
-                "dispatch takes over", self.program, str(exc))
-            self._plain.add(sig)
-            return None
+        compiled, _outcome = self._ledger.compile(
+            self._jit, structs, program=self.program,
+            key=self._key, donate_argnums=self._donate,
+            replay=replay_doc, bucket_extra=self._bucket_extra,
+            tier=self.tier)
         self._compiled[sig] = compiled
         return compiled
 
@@ -668,8 +663,6 @@ class LedgeredJit:
         compiled = self._compiled.get(sig)
         if compiled is None:
             compiled = self._acquire(structs, sig)
-            if compiled is None:  # instrumentation failed: compile raw
-                return self._jit.lower(*structs).compile()
         return compiled
 
     def lower(self, *args: Any, **kwargs: Any) -> Any:

@@ -59,37 +59,37 @@ _PEAKS_BY_KIND = (
     ("a100", (312e12, 2039e9)),
 )
 
-#: platform fallbacks when no kind matched.  The cpu number is a nominal
-#: few-core figure so tier-1 MFU is a small-but-nonzero ratio, not a lie
-#: of precision; override via env for real CPU runs.
-_PEAKS_BY_PLATFORM = {
-    "tpu": (197e12, 819e9),
-    "gpu": (312e12, 2039e9),
-    "cpu": (5e10, 5e10),
-}
-_DEFAULT_PEAKS = (1e12, 1e11)
+#: the CPU carries no device_kind worth matching: a nominal few-core
+#: figure so tier-1 MFU is a small-but-nonzero ratio, not a lie of
+#: precision; override via env for real CPU runs.
+_CPU_PEAKS = (5e10, 5e10)
 
 
 def device_peaks(device: Any = None) -> Dict[str, Any]:
     """Assumed peak FLOP/s and bytes/s for *device* (any object with
     ``device_kind``/``platform`` attrs, e.g. a jax Device), with env
-    overrides; ``peak_source`` says where the numbers came from."""
+    overrides; ``peak_source`` says where the numbers came from.  An
+    accelerator whose ``device_kind`` the table does not know is an
+    error, never a default: a utilisation over a guessed peak is a
+    made-up number."""
     env_f = os.environ.get("MAPREDUCE_TPU_PEAK_FLOPS")
     env_b = os.environ.get("MAPREDUCE_TPU_PEAK_BYTES_PER_S")
     kind = str(getattr(device, "device_kind", "") or "").lower()
     platform = str(getattr(device, "platform", "") or "").lower()
-    flops, nbytes, source = None, None, "default"
-    for sub, peaks in _PEAKS_BY_KIND:
+    for sub, (flops, nbytes) in _PEAKS_BY_KIND:
         if sub in kind:
-            flops, nbytes = peaks
             source = f"kind:{sub}"
             break
-    if flops is None:
-        if platform in _PEAKS_BY_PLATFORM:
-            flops, nbytes = _PEAKS_BY_PLATFORM[platform]
-            source = f"platform:{platform}"
-        else:
-            flops, nbytes = _DEFAULT_PEAKS
+    else:
+        if platform not in ("cpu", "") and not (env_f and env_b):
+            raise ValueError(
+                f"no peak FLOP/s / bytes/s known for device kind "
+                f"{kind!r} (platform {platform!r}): add it to "
+                "obs/profile._PEAKS_BY_KIND with its source, or set "
+                "MAPREDUCE_TPU_PEAK_FLOPS and "
+                "MAPREDUCE_TPU_PEAK_BYTES_PER_S")
+        flops, nbytes = _CPU_PEAKS
+        source = "platform:cpu"
     if env_f:
         flops, source = float(env_f), "env"
     if env_b:
@@ -104,16 +104,13 @@ def device_peaks(device: Any = None) -> Dict[str, Any]:
 
 def program_costs(compiled: Any) -> Optional[Dict[str, float]]:
     """FLOPs / bytes-accessed of one executable from XLA's cost model
-    (``Compiled.cost_analysis()``), normalised across the list-of-dicts
-    and plain-dict shapes JAX versions return.  None when the backend
+    (``Compiled.cost_analysis()``, a dict).  None when the backend
     exposes no usable analysis — callers then fall back to
     :func:`analytic_costs`."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # backend without a cost model: use the fallback
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     flops = float(ca.get("flops", 0.0) or 0.0)

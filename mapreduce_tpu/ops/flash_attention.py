@@ -1,14 +1,13 @@
 """In-tree Pallas flash attention: the transformer's single-chip hot op.
 
-Why a hand-written kernel (the first Pallas use in this repo, and a
-measured one): the unchunked jnp attention materialises the [B, H, T, T]
-f32 score tensor in HBM — at the bench config (B4 H16 T2048) that is
-1.07GB *per layer* re-read across softmax passes, measured 9% of peak on
-v5e (scratch/prof_mfu.py); the lax.scan + jax.checkpoint flash tiling
+Why a hand-written kernel (the first Pallas use in this repo): the
+unchunked jnp attention materialises the [B, H, T, T] f32 score tensor
+in HBM — at B4 H16 T2048 that is 1.07GB *per layer*, re-read across
+softmax passes; the lax.scan + jax.checkpoint flash tiling
 (parallel/ring.py block path) keeps memory bounded but pays scan
-overhead + full recompute, topping out at 34% step MFU
-(scratch/prof_mfu2.py).  A Pallas kernel holds each score tile in VMEM,
-never touching HBM with scores at all (measured: scratch/prof_flash3.py).
+overhead + full recompute.  A Pallas kernel holds each score tile in
+VMEM, never touching HBM with scores at all.  (The three formulations'
+rates are not measured on current hardware.)
 
 Kernel layout is ``[B, H, T, D]`` (Mosaic tiling wants the sequence and
 head_dim in the last two block dims); the wrapper accepts the model's
@@ -91,14 +90,13 @@ def _dispatch_tile(accum, needed, causal, iq, j, block_q, block_kv):
         accum(False)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _fwd_kernel(pids, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, den_scr, acc_scr,
                 *, causal, block_q, block_kv, n_kv):
     # q arrives PRE-SCALED by 1/sqrt(D) (see _fwd_call): one elementwise
     # pass over [B,H,T,D] outside replaces a [block_q,block_kv] scale
     # pass in every tile
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
+    iq, j = pids[2:]
 
     @pl.when(j == 0)
     def _init():
@@ -150,13 +148,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 # -- backward: dQ pass -------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+def _dq_kernel(pids, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, *, scale, causal, block_q, block_kv, n_kv):
     # q is pre-scaled (q^ = q/sqrt(D)); the kernel accumulates dq^ = ds.k
     # and the one final emission multiplies by scale (chain rule through
     # q^ = scale*q), replacing a per-tile [block_q, D] scale pass
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
+    iq, j = pids[2:]
 
     @pl.when(j == 0)
     def _init():
@@ -201,13 +198,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 # -- backward: dK/dV pass ----------------------------------------------------
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(pids, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, causal, block_q, block_kv, n_q):
     # q is pre-scaled, so dK = dS^T . q^ needs NO scale factor at all
     # (dk = dS^T . scale*q exactly)
-    jk = pl.program_id(2)
-    i = pl.program_id(3)
+    jk, i = pids[2:]
 
     @pl.when(i == 0)
     def _init():

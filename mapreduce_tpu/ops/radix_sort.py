@@ -93,7 +93,7 @@ def _digit_base(hist: jax.Array) -> jax.Array:
 # -- kernels -----------------------------------------------------------------
 
 
-def _hist_kernel(d_ref, h_ref, *, nbuckets):
+def _hist_kernel(pids, d_ref, h_ref, *, nbuckets):
     """Per-tile digit histogram: d [1, B] int32 -> h [1, R] int32."""
     d = d_ref[0, :]
     iota = jax.lax.broadcasted_iota(jnp.int32, (d.shape[0], nbuckets), 1)
@@ -109,14 +109,14 @@ def _stable_rank(d, nbuckets):
     return jnp.take_along_axis(csum - 1, d[:, None], axis=1)[:, 0]
 
 
-def _rank_kernel(d_ref, off_ref, r_ref, *, nbuckets):
+def _rank_kernel(pids, d_ref, off_ref, r_ref, *, nbuckets):
     """Global stable rank within each digit bucket (fused-exchange path):
     d [1, B], off [1, R] (exclusive tile offsets) -> r [1, B]."""
     d = d_ref[0, :]
     r_ref[0, :] = off_ref[0, :][d] + _stable_rank(d, nbuckets)
 
 
-def _scatter_kernel(d_ref, off_ref, a1_ref, a2_ref, p_ref,
+def _scatter_kernel(pids, d_ref, off_ref, a1_ref, a2_ref, p_ref,
                     o1_ref, o2_ref, op_ref, *, nbuckets):
     """Stable scatter of one tile's lanes to global sorted positions.
 
@@ -126,9 +126,7 @@ def _scatter_kernel(d_ref, off_ref, a1_ref, a2_ref, p_ref,
     """
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-
-    @pl.when(b == 0)
+    @pl.when(pids[0] == 0)
     def _init():
         for ref in (o1_ref, o2_ref, op_ref):
             ref[...] = jnp.zeros(ref.shape, ref.dtype)
@@ -194,7 +192,7 @@ def _tile_scatter(d2, off, a1, a2, pr, interpret):
         out_specs=[full, full, full],
         out_shape=[pallas_compat.sds((1, npad), jnp.uint32, a1),
                    pallas_compat.sds((1, npad), jnp.uint32, a2),
-                   pallas_compat.sds((1, npad), jnp.int32, pr)],
+                   pallas_compat.sds((1, npad), jnp.int32, a1)],
     )(d2, off, a1, a2, pr)
     return o1[0], o2[0], op_[0]
 
@@ -232,6 +230,11 @@ def radix_sort_pairs(k1: jax.Array, k2: jax.Array, *,
     a1 = jnp.pad(k1, (0, pad), constant_values=_SENT)
     a2 = jnp.pad(k2, (0, pad), constant_values=_SENT)
     pr = jnp.arange(npad, dtype=jnp.int32)
+    # the permutation lane leaves every pass device-varying like the
+    # keys it follows; it must enter the scan carry typed the same way
+    vma = tuple(jax.typeof(a1).vma)
+    if vma:
+        pr = jax.lax.pcast(pr, vma, to="varying")
 
     # One lax.scan per key lane over the 8 digit shifts: the pass body
     # (two kernel programs) is traced ONCE per lane instead of 8 times,

@@ -45,9 +45,10 @@ from . import pallas_compat
 #: at record-buffer build time (device_engine step) — so
 #: (SENTINEL, SENTINEL) is unambiguous.
 SENTINEL = jnp.uint32(0xFFFFFFFF)
-#: plain-int twin for Pallas kernel bodies (a module-level jnp constant
-#: would be a captured traced array, which pallas_call refuses)
-_SENT = np.uint32(0xFFFFFFFF)
+#: its int32 bit pattern for Pallas kernel bodies, which see the key
+#: lanes bitcast to int32 (a module-level jnp constant would be a
+#: captured traced array, which pallas_call refuses)
+_SENT = np.int32(-1)
 
 #: lane width of the fused segmented-reduce kernel's 2-D layout (the
 #: flattened record order is row-major over [rows, _SEG_LANES])
@@ -134,90 +135,59 @@ def segmented_scan(op: Callable, starts: jax.Array,
 # construction (the golden suite pins it, ops- and engine-level).
 
 
-def _seg_ladder(flags: jax.Array, v: jax.Array, op: Callable):
-    """Within-row inclusive segmented scan along axis 1 of ``v`` ([R, L]
-    or [R, L, D]; *flags* [R, L]).  Returns ``(seen, v)``: ``seen[r, l]``
-    = a flag exists in row r at or before lane l, ``v[r, l]`` = op-fold
-    of row r from max(last flag, row start) through l.  Classic
-    Hillis-Steele with a POSITIONAL guard (lanes < d are already
-    complete) so unflagged row starts stay exact without an op
-    identity."""
-    lanes = flags.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, flags.shape, 1)
-    stacked = v.ndim == 3
-
-    def bsel(mask, a, b):
-        return jnp.where(mask[..., None] if stacked else mask, a, b)
-
-    f = flags
-    seen = flags
+def _seg_scan(f, v, op: Callable, shift, idx, span: int):
+    """Inclusive segmented Hillis-Steele scan along one block axis.
+    *f* (int32 0/1 segment-start flags) and *v* (values, [R, L] or
+    [R, L, D]) are scanned with the *shift* primitive
+    (pallas_compat.shift_lanes / shift_rows) over *span* positions
+    indexed by the iota *idx*.  Returns ``(seen, v)``: ``seen`` = a flag
+    exists at or before this position, ``v`` = op-fold from max(last
+    flag, axis start) through it.  The POSITIONAL guard (positions < d
+    are already complete) keeps unflagged axis starts exact without an
+    op identity."""
     d = 1
-    while d < lanes:
-        f_l = jnp.concatenate(
-            [jnp.ones(f.shape[:1] + (d,), bool), f[:, :-d]], axis=1)
-        v_l = jnp.concatenate([v[:, :d], v[:, :-d]], axis=1)
-        v = bsel(f | (lane < d), v, op(v_l, v))
-        f = f | f_l
-        seen = seen | jnp.concatenate(
-            [jnp.zeros(seen.shape[:1] + (d,), bool), seen[:, :-d]], axis=1)
+    while d < span:
+        done = (f > 0) | (idx < d)
+        v = jnp.where(pallas_compat.stacked_mask(done, v), v,
+                      op(shift(v, d, v, idx), v))
+        f = f | shift(f, d, 0, idx)
         d *= 2
-    return seen, v
+    return f > 0, v
 
 
-def _shift1_flat(x: jax.Array, carry) -> jax.Array:
-    """*x* ([R, L]) shifted right by one in flattened row-major order;
-    *carry* (the previous block's last element) fills position [0, 0]."""
-    prev_last = jnp.concatenate(
-        [jnp.full((1, 1), carry, x.dtype), x[:-1, -1:]], axis=0)
-    return jnp.concatenate([prev_last, x[:, :-1]], axis=1)
-
-
-def _cumsum_2level(e: jax.Array, carry) -> jax.Array:
-    """Inclusive int32 cumsum of ``e`` ([R, L]) in flattened order,
-    seeded by *carry* (zeros fill = exact identity)."""
-    R, L = e.shape
-    d = 1
-    while d < L:
-        e = e + jnp.concatenate(
-            [jnp.zeros((R, d), jnp.int32), e[:, :-d]], axis=1)
-        d *= 2
-    rt = e[:, -1]
-    d = 1
-    while d < R:
-        rt = rt + jnp.concatenate([jnp.zeros((d,), jnp.int32), rt[:-d]])
-        d *= 2
-    prefix = jnp.concatenate([jnp.zeros((1,), jnp.int32), rt[:-1]]) + carry
-    return e + prefix[:, None]
-
-
-def _segreduce_kernel(k1_ref, k2_ref, nk1_ref, nk2_ref, *refs,
-                      op: Callable, n_lanes: int, unit: bool, R: int):
-    """One grid step = one [R, _SEG_LANES] block of the sorted lanes.
+def _segreduce_kernel(pids, k1_ref, k2_ref, nk1_ref, nk2_ref, *refs,
+                      op: Callable, n_lanes: int, unit: bool):
+    """One grid step = one [R, _SEG_LANES] block of the sorted lanes
+    (key lanes arrive bitcast to int32: only equality is taken).
     refs layout: n_lanes value in-refs (none when *unit*), then n_out
-    reduced out-refs (1 when *unit*), csum out-ref, then scratch:
-    carry keys (SMEM [2] u32), carry value (VMEM [1, n_out] value
-    dtype), carry end-count (SMEM [1] i32)."""
+    reduced out-refs (1 when *unit*), csum out-ref, then scratch, each a
+    [1, _SEG_LANES] VMEM row: the previous block's last key row (x2),
+    the running combine value and the running end count (both held in
+    every lane)."""
     from jax.experimental import pallas as pl
 
+    shift_l, shift_r = pallas_compat.shift_lanes, pallas_compat.shift_rows
     n_out = 1 if unit else n_lanes
     val_refs = () if unit else refs[:n_lanes]
     red_refs = refs[0 if unit else n_lanes:][:n_out]
     csum_ref = refs[(0 if unit else n_lanes) + n_out]
-    ck_ref, cv_ref, cc_ref = refs[-3:]
-    b = pl.program_id(0)
+    ck1_ref, ck2_ref, cv_ref, cc_ref = refs[-4:]
 
-    @pl.when(b == 0)
+    @pl.when(pids[0] == 0)
     def _init():
-        ck_ref[0] = _SENT
-        ck_ref[1] = _SENT
+        ck1_ref[...] = jnp.full_like(ck1_ref, _SENT)
+        ck2_ref[...] = jnp.full_like(ck2_ref, _SENT)
         cv_ref[...] = jnp.zeros_like(cv_ref)
-        cc_ref[0] = jnp.int32(0)
+        cc_ref[...] = jnp.zeros_like(cc_ref)
 
     k1 = k1_ref[...]
     k2 = k2_ref[...]
+    R, L = k1.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
     valid = jnp.logical_not((k1 == _SENT) & (k2 == _SENT))
-    pk1 = _shift1_flat(k1, ck_ref[0])
-    pk2 = _shift1_flat(k2, ck_ref[1])
+    pk1 = pallas_compat.shift1_flat(k1, ck1_ref[...], lane, row)
+    pk2 = pallas_compat.shift1_flat(k2, ck2_ref[...], lane, row)
     is_start = valid & ((k1 != pk1) | (k2 != pk2))
     nk1 = nk1_ref[...]
     nk2 = nk2_ref[...]
@@ -228,54 +198,43 @@ def _segreduce_kernel(k1_ref, k2_ref, nk1_ref, nk2_ref, *refs,
     if unit:
         v = jnp.ones(k1.shape, jnp.int32)
         op_eff = jnp.add
-        stacked = False
     else:
         lanes = [r[...] for r in val_refs]
-        stacked = n_lanes > 1
-        v = jnp.stack(lanes, axis=-1) if stacked else lanes[0]
+        v = jnp.stack(lanes, axis=-1) if n_lanes > 1 else lanes[0]
         op_eff = op
-    seen, v = _seg_ladder(is_start, v, op_eff)
-    # compose rows + the block carry: the within-row scan's last lane is
-    # each row's (flag, value) summary; an exclusive prefix of those
-    # summaries under the same segmented monoid — seeded by the carry
-    # value in scratch — gives every row the value of the run continuing
-    # into it from before
-    rf = jnp.any(is_start, axis=1)
-    rv = v[:, -1]                       # [R] or [R, D]
-    r_seen, r_inc = _seg_ladder(rf[None, :],
-                                rv[None, ...], op_eff)
-    r_seen, r_inc = r_seen[0], r_inc[0]
-    if stacked:
-        carry_v = cv_ref[0, :]          # [D]
-        comb = jnp.where(r_seen[:, None], r_inc,
-                         op_eff(jnp.broadcast_to(carry_v, r_inc.shape),
-                                r_inc))
-        pv = jnp.concatenate([carry_v[None, :].astype(v.dtype),
-                              comb[:-1]], axis=0)
-        final = jnp.where(seen[..., None], v,
-                          op_eff(jnp.broadcast_to(pv[:, None, :], v.shape),
-                                 v))
+
+    def bsel(mask, a, b):
+        return jnp.where(pallas_compat.stacked_mask(mask, a), a, b)
+
+    seen, v = _seg_scan(is_start.astype(jnp.int32), v, op_eff, shift_l,
+                        lane, L)
+    # compose rows + the block carry: each row's last lane is its
+    # (flag, value) summary; scanning those summaries down the rows
+    # under the same segmented monoid — then folding in the carry value
+    # — gives every row the value of the run continuing into it
+    r_seen, r_inc = _seg_scan(
+        pallas_compat.last_lane(seen.astype(jnp.int32), lane),
+        pallas_compat.last_lane(v, lane), op_eff, shift_r, row, R)
+    carry_v = jnp.broadcast_to(cv_ref[...], v.shape)
+    r_inc = bsel(r_seen, r_inc, op_eff(carry_v, r_inc))
+    final = bsel(seen, v, op_eff(shift_r(r_inc, 1, carry_v, row), v))
+    if n_out > 1:
         for i in range(n_out):
             red_refs[i][...] = final[..., i]
-        cv_ref[0, :] = final[R - 1, _SEG_LANES - 1, :]
     else:
-        carry_v = cv_ref[0, 0]
-        comb = jnp.where(r_seen, r_inc,
-                         op_eff(jnp.broadcast_to(carry_v, r_inc.shape),
-                                r_inc))
-        pv = jnp.concatenate(
-            [jnp.broadcast_to(carry_v, (1,)).astype(v.dtype), comb[:-1]])
-        final = jnp.where(seen, v,
-                          op_eff(jnp.broadcast_to(pv[:, None], v.shape),
-                                 v))
         red_refs[0][...] = final
-        cv_ref[0, 0] = final[R - 1, _SEG_LANES - 1]
+    cv_ref[...] = r_inc[R - 1:R]
 
-    csum = _cumsum_2level(is_end.astype(jnp.int32), cc_ref[0])
-    csum_ref[...] = csum
-    ck_ref[0] = k1[R - 1, _SEG_LANES - 1]
-    ck_ref[1] = k2[R - 1, _SEG_LANES - 1]
-    cc_ref[0] = csum[R - 1, _SEG_LANES - 1]
+    cumsum = functools.partial(pallas_compat.ladder_scan, op=jnp.add,
+                               identity=0)
+    e = cumsum(is_end.astype(jnp.int32), shift=shift_l, idx=lane, span=L)
+    r_tot = cc_ref[...] + cumsum(pallas_compat.last_lane(e, lane),
+                                 shift=shift_r, idx=row, span=R)
+    csum_ref[...] = e + shift_r(r_tot, 1,
+                                jnp.broadcast_to(cc_ref[...], e.shape), row)
+    ck1_ref[...] = k1[R - 1:R]
+    ck2_ref[...] = k2[R - 1:R]
+    cc_ref[...] = r_tot[R - 1:R]
 
 
 def _segment_reduce_pallas(k1s: jax.Array, k2s: jax.Array,
@@ -291,8 +250,8 @@ def _segment_reduce_pallas(k1s: jax.Array, k2s: jax.Array,
 
     N = k1s.shape[0]
     L = _SEG_LANES
-    block = max(L, (int(block) // L) * L)
-    R = block // L
+    R = pallas_compat.block_rows(block, L, 8, interpret)
+    block = R * L
     npad = -(-N // block) * block
     pad = npad - N
 
@@ -311,7 +270,8 @@ def _segment_reduce_pallas(k1s: jax.Array, k2s: jax.Array,
     nk2 = jnp.concatenate([k2p[1:], jnp.full((1,), SENTINEL, jnp.uint32)])
     rows = npad // L
     shape2 = (rows, L)
-    ins = [a.reshape(shape2) for a in (k1p, k2p, nk1, nk2)]
+    ins = [jax.lax.bitcast_convert_type(a, jnp.int32).reshape(shape2)
+           for a in (k1p, k2p, nk1, nk2)]
     if unit_values:
         n_lanes, n_out = 0, 1
         out_dtype = jnp.int32
@@ -328,9 +288,10 @@ def _segment_reduce_pallas(k1s: jax.Array, k2s: jax.Array,
         ins += [padded(v, jnp.zeros((), v.dtype)).astype(out_dtype)
                 .reshape(shape2) for v in vals_s]
     spec = pl.BlockSpec((R, L), lambda i: (i, 0))
+    carry_v = (1, L, n_out) if n_out > 1 else (1, L)
     outs = pallas_compat.pallas_call(
         functools.partial(_segreduce_kernel, op=op, n_lanes=n_lanes,
-                          unit=unit_values, R=R),
+                          unit=unit_values),
         name="segreduce",
         interpret=interpret,
         grid=(npad // block,),
@@ -338,9 +299,10 @@ def _segment_reduce_pallas(k1s: jax.Array, k2s: jax.Array,
         out_specs=[spec] * (n_out + 1),
         out_shape=[pallas_compat.sds(shape2, out_dtype, k1s)] * n_out
         + [pallas_compat.sds(shape2, jnp.int32, k1s)],
-        scratch_shapes=[pltpu.SMEM((2,), jnp.uint32),
-                        pltpu.VMEM((1, max(n_out, 1)), out_dtype),
-                        pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, L), jnp.int32),
+                        pltpu.VMEM((1, L), jnp.int32),
+                        pltpu.VMEM(carry_v, out_dtype),
+                        pltpu.VMEM((1, L), jnp.int32)],
     )(*ins)
     reduced = [o.reshape(-1)[:N] for o in outs[:n_out]]
     end_csum = outs[n_out].reshape(-1)[:N]

@@ -70,9 +70,9 @@ class TokenStream(NamedTuple):
 
 
 def _is_space(b: jax.Array) -> jax.Array:
-    m = b == jnp.uint8(_WS[0])
+    m = b == _WS[0]
     for w in _WS[1:]:
-        m = m | (b == jnp.uint8(w))
+        m = m | (b == w)
     return m
 
 
@@ -194,76 +194,78 @@ TOKENIZE_BLOCK = 4096
 _INT32_MIN = -(2 ** 31)
 
 
-def _tokenize_kernel(b_ref, nb_ref, *refs, multipliers: Tuple[int, ...],
-                     R: int):
+def _tokenize_kernel(pids, b_ref, nb_ref, *refs,
+                     multipliers: Tuple[int, ...]):
     """One grid step = one [R, _TOK_LANES] block of the byte chunk.
-    refs: per-multiplier hash out-refs, then end/start/length out-refs
-    (int32), then scratch: previous-byte space-ness (SMEM [1] i32),
-    per-lane running hash (SMEM [n_lanes] u32), running word-start max
-    (SMEM [1] i32)."""
+    refs: per-multiplier hash out-refs (int32 bit patterns), then
+    end/start/length out-refs (int32), then scratch, each a
+    [1, _TOK_LANES] int32 VMEM row: the previous block's last row of
+    space-ness, the per-lane running hash ([n_lanes, _TOK_LANES]) and
+    the running word-start max (both held in every lane)."""
     from jax.experimental import pallas as pl
 
+    shift_l, shift_r = pallas_compat.shift_lanes, pallas_compat.shift_rows
+    last_lane = pallas_compat.last_lane
     n_lanes = len(multipliers)
     h_refs = refs[:n_lanes]
     end_ref, start_ref, len_ref = refs[n_lanes:n_lanes + 3]
     cps_ref, ch_ref, cs_ref = refs[n_lanes + 3:]
-    blk = pl.program_id(0)
+    blk = pids[0]
 
     @pl.when(blk == 0)
     def _init():
-        cps_ref[0] = jnp.int32(1)   # "the byte before the chunk is a
-        for i in range(n_lanes):    # separator" (position 0 can start)
-            ch_ref[i] = jnp.uint32(0)
-        cs_ref[0] = jnp.int32(_INT32_MIN)
+        # "the byte before the chunk is a separator": position 0 can
+        # start a word
+        cps_ref[...] = jnp.ones_like(cps_ref)
+        ch_ref[...] = jnp.zeros_like(ch_ref)
+        cs_ref[...] = jnp.full_like(cs_ref, _INT32_MIN)
 
-    b = b_ref[...]                  # [R, L] uint8
-    space = _is_space(b)
+    b32 = b_ref[...].astype(jnp.int32)      # [R, L]
+    R, L = b32.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+    space = _is_space(b32)
     word = jnp.logical_not(space)
-    next_space = _is_space(nb_ref[...])
-    is_end = word & next_space
-    # previous byte's space-ness, shifted in flattened order with the
-    # cross-block carry at [0, 0]
+    is_end = word & _is_space(nb_ref[...].astype(jnp.int32))
     sp32 = space.astype(jnp.int32)
-    prev_last = jnp.concatenate(
-        [jnp.full((1, 1), cps_ref[0], jnp.int32), sp32[:-1, -1:]], axis=0)
-    prev_space = jnp.concatenate([prev_last, sp32[:, :-1]], axis=1) > 0
-    is_start = word & prev_space
+    is_start = word & (pallas_compat.shift1_flat(
+        sp32, cps_ref[...], lane, row) > 0)
 
-    # the within-tile scans ARE the module's lax ladders (_hillis_affine
-    # / _hillis_max): plain jnp code, identity-fill, exact — one
-    # spelling shared by both formulations so they cannot drift
-    L = b.shape[1]
-    b32 = b.astype(jnp.uint32)
+    def affine(m, c, shift, idx, span):
+        """Inclusive scan of the maps h -> m*h + c along one block axis
+        (the lax path's _hillis_affine with roll shifts; int32
+        arithmetic wraps to the same bits as uint32)."""
+        d = 1
+        while d < span:
+            m, c = m * shift(m, d, 1, idx), m * shift(c, d, 0, idx) + c
+            d *= 2
+        return m, c
+
     for i, a in enumerate(multipliers):
-        m = jnp.where(word, jnp.uint32(a), jnp.uint32(0))
-        c = jnp.where(word, b32 + jnp.uint32(1), jnp.uint32(0))
-        mw, cw = _hillis_affine(m, c)
-        mi, ci = _hillis_affine(mw[None, :, -1], cw[None, :, -1])
-        mi, ci = mi[0], ci[0]           # inclusive row-total composition
-        hc = ch_ref[i]                  # running hash before this block
+        a32 = int(np.uint32(a).astype(np.int32))
+        mw, cw = affine(jnp.where(word, a32, 0),
+                        jnp.where(word, b32 + 1, 0), shift_l, lane, L)
+        mi, ci = affine(last_lane(mw, lane), last_lane(cw, lane),
+                        shift_r, row, R)
+        hc = jnp.broadcast_to(ch_ref[i:i + 1], (R, L))
         comb = hc * mi + ci             # carry ∘ rows 0..r, value lane
-        cp = jnp.concatenate(
-            [jnp.broadcast_to(hc, (1,)).astype(jnp.uint32), comb[:-1]])
-        h = cp[:, None] * mw + cw
-        h_refs[i][...] = h
-        ch_ref[i] = h[R - 1, L - 1]
+        h_refs[i][...] = shift_r(comb, 1, hc, row) * mw + cw
+        ch_ref[i:i + 1] = comb[R - 1:R]
 
-    pos = (jnp.int32(blk) * jnp.int32(R * L)
-           + jax.lax.broadcasted_iota(jnp.int32, (R, L), 0) * jnp.int32(L)
-           + jax.lax.broadcasted_iota(jnp.int32, (R, L), 1))
-    marks = jnp.where(is_start, pos, jnp.int32(-1))
-    mw = _hillis_max(marks)
-    rinc = _hillis_max(mw[None, :, -1])[0]
-    cmax = cs_ref[0]
-    pmax = jnp.concatenate(
-        [jnp.broadcast_to(cmax, (1,)).astype(jnp.int32),
-         jnp.maximum(rinc, cmax)[:-1]])
-    start = jnp.maximum(mw, pmax[:, None])
+    cummax = functools.partial(pallas_compat.ladder_scan, op=jnp.maximum,
+                               identity=_INT32_MIN)
+    pos = blk * (R * L) + row * L + lane
+    mw = cummax(jnp.where(is_start, pos, -1), shift=shift_l, idx=lane,
+                span=L)
+    cmax = jnp.broadcast_to(cs_ref[...], (R, L))
+    rinc = jnp.maximum(cmax, cummax(last_lane(mw, lane), shift=shift_r,
+                                    idx=row, span=R))
+    start = jnp.maximum(mw, shift_r(rinc, 1, cmax, row))
     start_ref[...] = start
-    len_ref[...] = pos - start + jnp.int32(1)
+    len_ref[...] = pos - start + 1
     end_ref[...] = is_end.astype(jnp.int32)
-    cps_ref[0] = sp32[R - 1, L - 1]
-    cs_ref[0] = start[R - 1, L - 1]
+    cps_ref[...] = sp32[R - 1:R]
+    cs_ref[...] = rinc[R - 1:R]
 
 
 def _tokenize_pallas(chunk: jax.Array, multipliers: Tuple[int, ...],
@@ -275,8 +277,8 @@ def _tokenize_pallas(chunk: jax.Array, multipliers: Tuple[int, ...],
 
     N = chunk.shape[0]
     L = _TOK_LANES
-    block = max(L, (int(block) // L) * L)
-    R = block // L
+    R = pallas_compat.block_rows(block, L, 32, interpret)  # uint8 tiles
+    block = R * L
     npad = -(-N // block) * block
     pad = npad - N
     cp = (jnp.concatenate([chunk, jnp.full((pad,), ord(" "), jnp.uint8)])
@@ -290,20 +292,21 @@ def _tokenize_pallas(chunk: jax.Array, multipliers: Tuple[int, ...],
     n_lanes = len(multipliers)
     outs = pallas_compat.pallas_call(
         functools.partial(_tokenize_kernel,
-                          multipliers=tuple(int(a) for a in multipliers),
-                          R=R),
+                          multipliers=tuple(int(a) for a in multipliers)),
         name="tokenize",
         interpret=interpret,
         grid=(npad // block,),
         in_specs=[spec, spec],
         out_specs=[spec] * (n_lanes + 3),
-        out_shape=[pallas_compat.sds(shape2, jnp.uint32, chunk)] * n_lanes
-        + [pallas_compat.sds(shape2, jnp.int32, chunk)] * 3,
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
-                        pltpu.SMEM((n_lanes,), jnp.uint32),
-                        pltpu.SMEM((1,), jnp.int32)],
+        out_shape=[pallas_compat.sds(shape2, jnp.int32, chunk)]
+        * (n_lanes + 3),
+        scratch_shapes=[pltpu.VMEM((1, L), jnp.int32),
+                        pltpu.VMEM((n_lanes, L), jnp.int32),
+                        pltpu.VMEM((1, L), jnp.int32)],
     )(cp.reshape(shape2), nb.reshape(shape2))
-    keys = jnp.stack([o.reshape(-1)[:N] for o in outs[:n_lanes]], axis=-1)
+    keys = jnp.stack(
+        [jax.lax.bitcast_convert_type(o, jnp.uint32).reshape(-1)[:N]
+         for o in outs[:n_lanes]], axis=-1)
     end, start, length = (o.reshape(-1)[:N] for o in outs[n_lanes:])
     return TokenStream(is_end=end.astype(bool), keys=keys,
                        start=start, length=length)

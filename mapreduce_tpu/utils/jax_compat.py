@@ -1,55 +1,17 @@
-"""Version-bridging aliases for the JAX APIs the device plane uses.
+"""Shared JAX call-site helpers for the device plane.
 
-The engine and model code target current JAX names (``jax.shard_map``,
-``jax.lax.pcast``); CI containers and downstream users may pin older
-releases where ``shard_map`` still lives under ``jax.experimental`` and
-the varying-manual-axes cast does not exist at all.  This module is the
-ONE place that probes versions, so the difference never spreads through
-the engine:
-
-* :func:`shard_map` — ``jax.shard_map`` when present, else the
-  ``jax.experimental.shard_map`` implementation with
-  ``check_rep=False`` defaulted in: the old replication checker
-  false-positives on the engine's scan-carry record buffers (the very
-  hazard the vma ``pcast(..., to="varying")`` annotations fix on
-  current JAX), and its own error message names ``check_rep=False`` as
-  the sanctioned workaround;
-* :func:`pcast` — ``jax.lax.pcast`` when present, else identity: the
-  cast only stamps varying-manual-axes metadata for the vma
-  replication checker, and pre-vma JAX tracks replication itself, so
-  dropping it on those versions changes nothing about the computation.
-* :func:`quiet_unusable_donation` — the shared scoped filter for the
-  expected "donated buffers were not usable" warning at the two places
-  that donate inputs purely to free them (engine wave inputs, trainer
-  epoch batches).
+Written for the installed JAX (0.9.0): call sites spell ``jax.shard_map``
+and ``jax.lax.pcast`` directly.  What stays here is
+:func:`quiet_unusable_donation` — the shared scoped filter for the
+expected "donated buffers were not usable" warning at the places that
+donate inputs purely to free them (engine wave inputs, trainer epoch
+batches).
 """
 
 from __future__ import annotations
 
 import contextlib
 import warnings
-
-import jax
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-promotion JAX: the experimental home
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *args, **kwargs):
-        # current JAX spells the replication checker flag check_vma;
-        # the experimental signature called it check_rep
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        kwargs.setdefault("check_rep", False)
-        return _exp_shard_map(f, *args, **kwargs)
-
-if hasattr(jax.lax, "pcast"):
-    pcast = jax.lax.pcast
-else:
-    def pcast(x, axis_name, to=None):  # noqa: ARG001 - signature parity
-        """Identity on JAX versions without varying-manual-axes."""
-        return x
 
 
 @contextlib.contextmanager

@@ -298,6 +298,97 @@ def test_warmup_cli_replay_and_unwritable_cache(tmp_path, monkeypatch,
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
+# -- where the cache lives (utils/compile_cache) -----------------------------
+
+
+def test_cache_dir_env_var_wins_and_is_left_alone(tmp_path, monkeypatch):
+    """Where $JAX_COMPILATION_CACHE_DIR is set, both enable forms keep
+    the cache THERE — an explicit argument loses, jax.config ends up
+    equal to the variable, the variable itself is never rewritten —
+    and the shape registry lands in the same directory."""
+    from mapreduce_tpu.utils import compile_cache
+
+    d = str(tmp_path / "placed")
+    os.makedirs(d)
+    monkeypatch.setenv(compile_cache.ENV_VAR, d)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        for enable in (compile_cache.enable_persistent_cache,
+                       compile_cache.enable_persistent_cache_lazy):
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert enable() == d
+            assert enable(str(tmp_path / "elsewhere")) == d
+            assert jax.config.jax_compilation_cache_dir == d
+            assert os.environ[compile_cache.ENV_VAR] == d
+        CompileLedger(tracer=Tracer()).compile(
+            _jit_sort(), _structs(), program="t_placed")
+        assert os.path.exists(compile_obs.registry_path())
+        assert os.path.dirname(compile_obs.registry_path()) == d
+        assert not os.path.exists(str(tmp_path / "elsewhere"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_cache_dir_unset_is_the_checkout_default(tmp_path, monkeypatch):
+    """Unset, the cache is <checkout>/.jax_cache; an explicit directory
+    (warmup --cache-dir, these tests) applies only then."""
+    from mapreduce_tpu.utils import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert (compile_cache.enable_persistent_cache()
+                == compile_cache.DEFAULT_DIR)
+        explicit = str(tmp_path / "explicit")
+        assert compile_cache.enable_persistent_cache(explicit) == explicit
+        assert jax.config.jax_compilation_cache_dir == explicit
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_cache_files_land_where_the_variable_says(tmp_path):
+    """A fresh process started with the variable set — the jax-free
+    lazy form first, as the CLI entry points do — writes its XLA cache
+    entries and the shape registry under it, and nothing new under the
+    checkout default."""
+    import subprocess
+    import sys
+
+    from mapreduce_tpu.utils import compile_cache
+
+    d = str(tmp_path / "placed")
+    repo = os.path.dirname(compile_cache.DEFAULT_DIR)
+    default_before = (set(os.listdir(compile_cache.DEFAULT_DIR))
+                      if os.path.isdir(compile_cache.DEFAULT_DIR) else None)
+    code = """
+import sys
+from mapreduce_tpu.utils.compile_cache import enable_persistent_cache_lazy
+assert "jax" not in sys.modules
+path = enable_persistent_cache_lazy()
+import jax, jax.numpy as jnp
+from mapreduce_tpu.obs.compile import wrap_jit
+wrap_jit(lambda x: jnp.sort(x * 2.0), program="t_env")(jnp.arange(256.0))
+assert jax.config.jax_compilation_cache_dir == path, path
+print(path)
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=d,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == d
+    files = set(os.listdir(d))
+    assert compile_obs.REGISTRY_BASENAME in files
+    assert files - {compile_obs.REGISTRY_BASENAME}, files
+    default_after = (set(os.listdir(compile_cache.DEFAULT_DIR))
+                     if os.path.isdir(compile_cache.DEFAULT_DIR) else None)
+    assert default_after == default_before
+
+
 # -- bundles -----------------------------------------------------------------
 
 

@@ -1,7 +1,6 @@
 """Pallas flash attention vs the unsharded oracle (interpret mode on the
 CPU test mesh; the compiled Mosaic path is what bench_train measures on
-hardware — 56.6% step MFU vs 27.5% for the jnp path at 1024-row tiles,
-scratch/prof_mfu3.py).
+hardware, and what the MAPREDUCE_TPU_TESTS=1 cases below check there).
 """
 
 import jax

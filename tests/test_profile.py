@@ -114,6 +114,23 @@ def test_device_peaks_env_override(monkeypatch):
     assert p["peak_source"] == "env"
 
 
+def test_device_peaks_unknown_accelerator_is_an_error():
+    """One table keyed by device_kind: a known kind answers with its
+    source, the CPU keeps its nominal row, and an accelerator the table
+    does not know raises instead of borrowing somebody else's peak."""
+    from types import SimpleNamespace as Dev
+
+    v5e = obs_profile.device_peaks(Dev(device_kind="TPU v5 lite",
+                                       platform="tpu"))
+    assert v5e["peak_source"] == "kind:v5" and v5e["flops_per_s"] == 197e12
+    cpu = obs_profile.device_peaks(Dev(device_kind="cpu", platform="cpu"))
+    assert cpu["peak_source"] == "platform:cpu"
+    for kind, platform in (("TPU v9x", "tpu"), ("Mystery GPU", "gpu")):
+        with pytest.raises(ValueError, match="no peak"):
+            obs_profile.device_peaks(Dev(device_kind=kind,
+                                         platform=platform))
+
+
 def _tiny_wc():
     from mapreduce_tpu.engine import DeviceWordCount
     from mapreduce_tpu.engine.device_engine import EngineConfig
