@@ -593,7 +593,8 @@ class DeviceEngine:
             def step(state, xs):
                 buf_k, buf_v, buf_p, map_oflow, comb_oflow, comb_max = state
                 chunk, idx, j = xs
-                keys, vals, pay, valid, m_oflow = map_fn(chunk, idx, cfg)
+                with jax.named_scope("wave.map"):
+                    keys, vals, pay, valid, m_oflow = map_fn(chunk, idx, cfg)
                 live = idx < n_real
                 valid = valid & live
                 map_oflow = map_oflow + jnp.where(live, m_oflow, 0)
@@ -605,13 +606,14 @@ class DeviceEngine:
                     # folded HERE — a chunk-scale sort + shifted-compare
                     # run-combine — and the big sort sees Tc rows per
                     # chunk instead of T
-                    cu = sorted_unique_reduce(
-                        keys, vals, pay, valid, Tc, cfg.reduce_op,
-                        unit_values=cfg.unit_values,
-                        rank_sort=cfg.rank_sort,
-                        sort_impl=cfg.sort_impl,
-                        segment_impl=cfg.segment_impl,
-                        segment_block=cfg.segment_block)
+                    with jax.named_scope("wave.combine"):
+                        cu = sorted_unique_reduce(
+                            keys, vals, pay, valid, Tc, cfg.reduce_op,
+                            unit_values=cfg.unit_values,
+                            rank_sort=cfg.rank_sort,
+                            sort_impl=cfg.sort_impl,
+                            segment_impl=cfg.segment_impl,
+                            segment_block=cfg.segment_block)
                     keys, vals, pay, valid = (cu.keys, cu.values,
                                               cu.payload, cu.valid)
                     comb_oflow = comb_oflow + jnp.maximum(
@@ -627,11 +629,13 @@ class DeviceEngine:
                 keys = jnp.where(is_sent[:, None], jnp.uint32(0), keys)
                 # invalid rows -> sentinel keys (sort to the end)
                 kk = jnp.where(valid[:, None], keys, SENTINEL)
-                buf_k = jax.lax.dynamic_update_slice(buf_k, kk, (j * Tc, 0))
-                buf_v = jax.lax.dynamic_update_slice(
-                    buf_v, vals, (j * Tc,) + (0,) * (buf_v.ndim - 1))
-                buf_p = jax.lax.dynamic_update_slice(buf_p, pay,
-                                                     (j * Tc, 0))
+                with jax.named_scope("wave.append"):
+                    buf_k = jax.lax.dynamic_update_slice(buf_k, kk,
+                                                         (j * Tc, 0))
+                    buf_v = jax.lax.dynamic_update_slice(
+                        buf_v, vals, (j * Tc,) + (0,) * (buf_v.ndim - 1))
+                    buf_p = jax.lax.dynamic_update_slice(buf_p, pay,
+                                                         (j * Tc, 0))
                 return (buf_k, buf_v, buf_p, map_oflow, comb_oflow,
                         comb_max), None
 
@@ -643,12 +647,13 @@ class DeviceEngine:
             # phases 2+3: one big rank-sort, segmented reduce, compact
             buf_valid = ~((buf_k[:, 0] == SENTINEL)
                           & (buf_k[:, 1] == SENTINEL))
-            local = sorted_unique_reduce(
-                buf_k, buf_v, buf_p, buf_valid, cfg.local_capacity,
-                local_op, unit_values=local_unit, rank_sort=cfg.rank_sort,
-                sort_impl=cfg.sort_impl,
-                segment_impl=cfg.segment_impl,
-                segment_block=cfg.segment_block)
+            with jax.named_scope("wave.local"):
+                local = sorted_unique_reduce(
+                    buf_k, buf_v, buf_p, buf_valid, cfg.local_capacity,
+                    local_op, unit_values=local_unit,
+                    rank_sort=cfg.rank_sort, sort_impl=cfg.sort_impl,
+                    segment_impl=cfg.segment_impl,
+                    segment_block=cfg.segment_block)
             local_oflow = (map_oflow + comb_oflow
                            + jnp.maximum(local.n_unique
                                          - cfg.local_capacity, 0))
@@ -659,26 +664,24 @@ class DeviceEngine:
             # the final sorted-unique pass then merges the fresh rows
             # WITH the running uniques in one sort, replacing the old
             # separate merge dispatch and its concatenate copies
-            ex = partition_exchange(local.keys, local.values, local.payload,
-                                    local.valid, AXIS,
-                                    cfg.exchange_capacity,
-                                    carry=(acc_k[0], acc_v[0], acc_p[0],
-                                           acc_valid[0]),
-                                    pmap=pmap,
-                                    # radix programs fuse the routing
-                                    # plan into the kernel family: one
-                                    # histogram pass yields both the
-                                    # scatter ranks and ex.counts
-                                    impl=("radix"
-                                          if cfg.sort_impl == "radix"
-                                          else "lax"))
+            with jax.named_scope("wave.exchange"):
+                ex = partition_exchange(
+                    local.keys, local.values, local.payload, local.valid,
+                    AXIS, cfg.exchange_capacity,
+                    carry=(acc_k[0], acc_v[0], acc_p[0], acc_valid[0]),
+                    pmap=pmap,
+                    # radix programs fuse the routing plan into the
+                    # kernel family: one histogram pass yields both the
+                    # scatter ranks and ex.counts
+                    impl=("radix" if cfg.sort_impl == "radix" else "lax"))
 
-            fin = sorted_unique_reduce(
-                ex.keys, ex.values, ex.payload, ex.valid, cfg.out_capacity,
-                fin_op, unit_values=False, rank_sort=cfg.rank_sort,
-                sort_impl=cfg.sort_impl,
-                segment_impl=cfg.segment_impl,
-                segment_block=cfg.segment_block)
+            with jax.named_scope("wave.fold"):
+                fin = sorted_unique_reduce(
+                    ex.keys, ex.values, ex.payload, ex.valid,
+                    cfg.out_capacity, fin_op, unit_values=False,
+                    rank_sort=cfg.rank_sort, sort_impl=cfg.sort_impl,
+                    segment_impl=cfg.segment_impl,
+                    segment_block=cfg.segment_block)
             fin_oflow = jnp.maximum(fin.n_unique - cfg.out_capacity, 0)
 
             # LOCAL overflow per device — the host sums across devices
@@ -1322,7 +1325,18 @@ class DeviceEngine:
                 acc = self._acc_init(_steady_cfg(cfg), row_shape,
                                      row_dtype)
                 cost_shapes = None
+                # per-attempt span tree: device_run ⊃ wave ⊃ {upload,
+                # compute, readback}, joined (via the thread's current
+                # span) under the owning job's trace.  Waves OVERLAP —
+                # wave w+1 uploads while wave w computes and a wave's
+                # readback lands depth waves later — so they are
+                # detached spans closed by the readback that proves the
+                # wave's device work finished, not lexical scopes.  Each
+                # begins at the instant its interval starts (never
+                # backdated), so the profiler's trace holds it too.
                 t0 = time.monotonic()
+                run_sp = TRACER.begin("device_run", attempt=attempt,
+                                      waves=W)
                 t_blocked = 0.0
                 wave_oflows = []
                 wave_oflow_vals = {}
@@ -1336,31 +1350,30 @@ class DeviceEngine:
                 upload_ivals = []
                 busy_ivals = []
                 dispatch_t = {}
-                # per-attempt span tree: device_run ⊃ wave ⊃ {upload,
-                # compute, readback}, joined (via the thread's current
-                # span) under the owning job's trace.  Waves OVERLAP —
-                # wave w+1 uploads while wave w computes and a wave's
-                # readback lands depth waves later — so they are
-                # detached spans closed by the readback that proves the
-                # wave's device work finished, not lexical scopes.
-                run_sp = TRACER.begin("device_run", start=t0,
-                                      attempt=attempt, waves=W)
                 wave_spans = {}
+                #: the upload or compute child span now open, closed by
+                #: the attempt's ``finally`` if its stage raises
+                stage_sp = None
 
                 def _read_wave_oflow(j: int) -> None:
                     # the (tiny) overflow VALUE readback both bounds the
                     # dispatch queue and proves wave j's program
                     # finished — so it records the wave's readback child
                     # and closes the wave span
+                    sp = wave_spans.get(j)
                     tr0 = time.monotonic()
-                    wave_oflow_vals[j] = int(
-                        self._host(wave_oflows[j]).sum())
-                    tr1 = time.monotonic()
-                    sp = wave_spans.pop(j, None)
+                    rb_sp = (TRACER.begin("readback", parent=sp,
+                                          kind="overflow")
+                             if sp is not None else None)
+                    try:
+                        wave_oflow_vals[j] = int(
+                            self._host(wave_oflows[j]).sum())
+                    finally:
+                        tr1 = time.monotonic()
+                        if rb_sp is not None:
+                            TRACER.end(rb_sp, tr1)
                     if sp is not None:
-                        TRACER.end(TRACER.begin("readback", parent=sp,
-                                                start=tr0,
-                                                kind="overflow"), tr1)
+                        del wave_spans[j]
                         TRACER.end(sp, tr1)
                         _WAVE_SECONDS.observe(tr1 - sp.t0, stage="wave")
                     _WAVE_SECONDS.observe(tr1 - tr0, stage="readback")
@@ -1389,7 +1402,9 @@ class DeviceEngine:
                         for w in range(W):
                             tb = time.monotonic()
                             wave_spans[w] = TRACER.begin("wave", parent=run_sp,
-                                                         start=tb, wave=w)
+                                                         wave=w)
+                            stage_sp = TRACER.begin("upload",
+                                                    parent=wave_spans[w])
                             if pairs is not None:
                                 ci, ii = pairs[w]
                             else:
@@ -1400,9 +1415,8 @@ class DeviceEngine:
                             # A); the wait is charged to upload
                             jax.block_until_ready(ci)
                             t_up = time.monotonic()
-                            TRACER.end(TRACER.begin("upload",
-                                                    parent=wave_spans[w],
-                                                    start=tb), t_up)
+                            TRACER.end(stage_sp, t_up)
+                            stage_sp = None
                             _WAVE_SECONDS.observe(t_up - tb, stage="upload")
                             t_blocked += t_up - tb
                             upload_ivals.append((tb, t_up))
@@ -1414,6 +1428,9 @@ class DeviceEngine:
                                 # serialization
                                 _read_wave_oflow(w - depth)
                             tc0 = time.monotonic()
+                            stage_sp = TRACER.begin("compute",
+                                                    parent=wave_spans[w],
+                                                    async_dispatch=True)
                             if cost_shapes is None:
                                 # capture BEFORE the dispatch: donation
                                 # invalidates the inputs at call time
@@ -1439,11 +1456,8 @@ class DeviceEngine:
                             acc = list(out[:4]) + list(out[6:])
                             dispatch_t[w] = tc0
                             tc1 = time.monotonic()
-                            TRACER.end(TRACER.begin("compute",
-                                                    parent=wave_spans[w],
-                                                    start=tc0,
-                                                    async_dispatch=True),
-                                       tc1)
+                            TRACER.end(stage_sp, tc1)
+                            stage_sp = None
                             _WAVE_SECONDS.observe(tc1 - tc0, stage="compute")
                             del out
                             # wave w is consumed: drop its input references
@@ -1468,6 +1482,8 @@ class DeviceEngine:
                     # a failed attempt must not leak open wave spans
                     # into the next attempt's timeline
                     t_now = time.monotonic()
+                    if stage_sp is not None:
+                        TRACER.end(stage_sp, t_now, truncated=True)
                     for sp in wave_spans.values():
                         TRACER.end(sp, t_now, truncated=True)
                     wave_spans.clear()

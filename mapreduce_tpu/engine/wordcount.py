@@ -20,6 +20,7 @@ import numpy as np
 
 from jax.sharding import Mesh
 
+from ..obs.trace import TRACER
 from ..ops.compaction import tile_compact
 from ..ops.tokenize import (
     HASH_A1, HASH_A2, HASH_A3, tokenize_hash, shard_text)
@@ -203,14 +204,19 @@ class DeviceWordCount:
         (server.lua:555-600)."""
         import time
 
-        t0 = time.monotonic()
-        # chunk count rounds up to a mesh multiple so every device
-        # participates
-        chunks, L = self._to_chunks(data)
-        t_split = time.monotonic() - t0
-        result = self._engine_for(L).run(chunks, timings=timings,
-                                         waves=waves)
-        out = self._finish(chunks, result, timings)
+        # spans: wordcount ⊃ {split, device_run ⊃ wave..., readback,
+        # materialize}; split and materialize are host work with the
+        # device empty, the same instants timings reports as durations
+        with TRACER.span("wordcount", bytes=len(data)):
+            t0 = time.monotonic()
+            with TRACER.span("split"):
+                # chunk count rounds up to a mesh multiple so every
+                # device participates
+                chunks, L = self._to_chunks(data)
+            t_split = time.monotonic() - t0
+            result = self._engine_for(L).run(chunks, timings=timings,
+                                             waves=waves)
+            out = self._finish(chunks, result, timings)
         if timings is not None:
             timings["split_s"] = round(t_split, 3)
         return out
@@ -235,9 +241,10 @@ class DeviceWordCount:
                      timings: Optional[dict] = None) -> Dict[bytes, int]:
         """Count a corpus previously uploaded with :meth:`stage`."""
         chunks, L, staged = handle
-        result = self._engine_for(L).run(chunks, timings=timings,
-                                         staged=staged)
-        return self._finish(chunks, result, timings)
+        with TRACER.span("wordcount", staged=True):
+            result = self._engine_for(L).run(chunks, timings=timings,
+                                             staged=staged)
+            return self._finish(chunks, result, timings)
 
     def _finish(self, chunks, result,
                 timings: Optional[dict]) -> Dict[bytes, int]:
@@ -246,7 +253,8 @@ class DeviceWordCount:
         import time
 
         t0 = time.monotonic()
-        out = materialize_counts(chunks, result)
+        with TRACER.span("materialize"):
+            out = materialize_counts(chunks, result)
         if timings is not None:
             timings["materialize_s"] = round(time.monotonic() - t0, 3)
         return out

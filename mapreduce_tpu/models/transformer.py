@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import compile as _compile_obs
+from ..obs.trace import TRACER
 from ..ops.flash_attention import flash_attention
 from ..parallel.ring import ring_attention
 
@@ -151,46 +152,62 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
     H_loc = cfg.n_heads // n_model
     D = cfg.head_dim
     E = x.shape[-1]
-    h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype))
+    # stage scopes (metadata only): the device trace books every
+    # operation, backward pass included, to the innermost scope on its
+    # path (obs/compile.CompileLedger.stage_map)
+    with jax.named_scope("tf.attn_proj"):
+        h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype))
+        if cfg.flash:
+            # Pallas fast path: project straight into the kernel's
+            # [B, H, T, D] layout (the transpose folds into the matmul
+            # epilogue — nothing is materialised twice); the kernel runs
+            # on it and one einsum contracts back
+            w = lp["wqkv"].astype(cfg.dtype).reshape(E, 3, H_loc, D)
+            qkv = jnp.einsum("bte,echd->bchtd", h, w)
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+        else:
+            qkv = jnp.einsum("bte,ecf->btcf", h,
+                             lp["wqkv"].astype(cfg.dtype))
+            q, k, v = [qkv[:, :, j].reshape(*qkv.shape[:2], H_loc, D)
+                       for j in range(3)]
     if cfg.flash:
-        # Pallas fast path: project straight into the kernel's
-        # [B, H, T, D] layout (the transpose folds into the matmul
-        # epilogue — nothing is materialised twice), run the tiled
-        # kernel, and contract back in one einsum
-        w = lp["wqkv"].astype(cfg.dtype).reshape(E, 3, H_loc, D)
-        qkv = jnp.einsum("bte,echd->bchtd", h, w)
         # attn_block doubles as the kernel tile request (auto-shrunk to
         # divide T); the kernel default, 1024, is the measured v5e
         # sweet spot
         bk = dict(block_q=cfg.attn_block, block_kv=cfg.attn_block) \
             if cfg.attn_block else {}
-        attn = flash_attention(qkv[:, 0], qkv[:, 1], qkv[:, 2],
-                               causal=True, **bk).astype(cfg.dtype)
-        o = jnp.einsum("bhtd,hde->bte", attn,
-                       lp["wo"].astype(cfg.dtype).reshape(H_loc, D, E))
+        with jax.named_scope("tf.flash"):
+            attn = flash_attention(q, k, v, causal=True,
+                                   **bk).astype(cfg.dtype)
     else:
-        qkv = jnp.einsum("bte,ecf->btcf", h, lp["wqkv"].astype(cfg.dtype))
-        q, k, v = [qkv[:, :, j].reshape(*qkv.shape[:2], H_loc, D)
-                   for j in range(3)]
         # bf16 operands on the MXU with f32 softmax/accumulation inside
-        attn = ring_attention(q, k, v, data_axis, causal=True,
-                              block_size=cfg.attn_block).astype(cfg.dtype)
-        attn = attn.reshape(*attn.shape[:2], H_loc * D)
-        # row-sharded output projection -> psum over the model axis
-        o = jnp.einsum("btf,fe->bte", attn, lp["wo"].astype(cfg.dtype))
-    o = jax.lax.psum(o.astype(jnp.float32), model_axis)
-    x = x + o.astype(cfg.dtype)
+        with jax.named_scope("tf.ring"):
+            attn = ring_attention(q, k, v, data_axis, causal=True,
+                                  block_size=cfg.attn_block
+                                  ).astype(cfg.dtype)
+    with jax.named_scope("tf.attn_proj"):
+        if cfg.flash:
+            o = jnp.einsum("bhtd,hde->bte", attn,
+                           lp["wo"].astype(cfg.dtype).reshape(H_loc, D, E))
+        else:
+            attn = attn.reshape(*attn.shape[:2], H_loc * D)
+            # row-sharded output projection -> psum over the model axis
+            o = jnp.einsum("btf,fe->bte", attn,
+                           lp["wo"].astype(cfg.dtype))
+        o = jax.lax.psum(o.astype(jnp.float32), model_axis)
+        x = x + o.astype(cfg.dtype)
 
-    h = _rmsnorm(x, lp["ln2_scale"].astype(cfg.dtype))
-    if cfg.moe_experts:
-        m, aux = _moe_ffn(h, lp, cfg, model_axis)
-    else:
-        u = jnp.einsum("bte,ef->btf", h, lp["w_in"].astype(cfg.dtype))
-        u = jax.nn.gelu(u)
-        m = jnp.einsum("btf,fe->bte", u, lp["w_out"].astype(cfg.dtype))
-        m = jax.lax.psum(m.astype(jnp.float32), model_axis)
-        aux = jnp.float32(0.0)
-    return x + m.astype(cfg.dtype), aux
+    with jax.named_scope("tf.ffn"):
+        h = _rmsnorm(x, lp["ln2_scale"].astype(cfg.dtype))
+        if cfg.moe_experts:
+            m, aux = _moe_ffn(h, lp, cfg, model_axis)
+        else:
+            u = jnp.einsum("bte,ef->btf", h, lp["w_in"].astype(cfg.dtype))
+            u = jax.nn.gelu(u)
+            m = jnp.einsum("btf,fe->bte", u, lp["w_out"].astype(cfg.dtype))
+            m = jax.lax.psum(m.astype(jnp.float32), model_axis)
+            aux = jnp.float32(0.0)
+        return x + m.astype(cfg.dtype), aux
 
 
 def _moe_ffn(h: jax.Array, lp: Params, cfg: TransformerConfig,
@@ -251,7 +268,8 @@ def forward_local(params: Params, tokens: jax.Array,
     int32; returns ``(hidden [B, T_local, E] f32, aux [] f32)`` where aux
     is the summed MoE load-balance excess (0 for dense layers).  Params
     arrive already sliced by transformer_param_spec."""
-    x = params["embed"][tokens].astype(cfg.dtype)  # [B, T, E]
+    with jax.named_scope("tf.embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)  # [B, T, E]
 
     def layer(x, lp):
         return _layer_local(x, lp, cfg, n_model, data_axis, model_axis)
@@ -307,23 +325,26 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
                                model_axis)
         return (gmax + jnp.log(denom)) - t_logit
 
-    Tc = cfg.loss_block
-    if Tc is None:
-        nll = chunk_nll(x, targets)
-    else:
-        B, T, E = x.shape
-        if T % Tc != 0:
-            raise ValueError(f"loss_block {Tc} must divide T_local {T}")
-        C = T // Tc
-        xs = jnp.moveaxis(x.reshape(B, C, Tc, E), 1, 0)
-        ts = jnp.moveaxis(targets.reshape(B, C, Tc), 1, 0)
-        # recompute each chunk's logits in the backward pass — full
-        # logits never exist in memory, forward or backward
-        body = jax.checkpoint(
-            lambda _, xt: (None, chunk_nll(*xt)))
-        _, nll_chunks = jax.lax.scan(body, None, (xs, ts))
-        nll = jnp.moveaxis(nll_chunks, 0, 1).reshape(B, T)
-    total = nll.mean() + jnp.float32(cfg.moe_aux_weight) * aux
+    # everything from the unembedding on is the loss stage: chunk_nll
+    # is traced where it is called, inside the scope
+    with jax.named_scope("tf.loss"):
+        Tc = cfg.loss_block
+        if Tc is None:
+            nll = chunk_nll(x, targets)
+        else:
+            B, T, E = x.shape
+            if T % Tc != 0:
+                raise ValueError(f"loss_block {Tc} must divide T_local {T}")
+            C = T // Tc
+            xs = jnp.moveaxis(x.reshape(B, C, Tc, E), 1, 0)
+            ts = jnp.moveaxis(targets.reshape(B, C, Tc), 1, 0)
+            # recompute each chunk's logits in the backward pass — full
+            # logits never exist in memory, forward or backward
+            body = jax.checkpoint(
+                lambda _, xt: (None, chunk_nll(*xt)))
+            _, nll_chunks = jax.lax.scan(body, None, (xs, ts))
+            nll = jnp.moveaxis(nll_chunks, 0, 1).reshape(B, T)
+        total = nll.mean() + jnp.float32(cfg.moe_aux_weight) * aux
     return jax.lax.pmean(total, data_axis)
 
 
@@ -372,8 +393,9 @@ class TransformerTrainer:
         def train_step(params, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, tokens, targets)
-            params = jax.tree.map(lambda p, g: p - learning_rate * g,
-                                  params, grads)
+            with jax.named_scope("tf.update"):
+                params = jax.tree.map(lambda p, g: p - learning_rate * g,
+                                      params, grads)
             return params, loss
 
         # ledgered jits (obs/compile): compile spans + seconds + shape
@@ -412,9 +434,10 @@ class TransformerTrainer:
             def train_step_opt(params, opt_state, tokens, targets):
                 loss, grads = jax.value_and_grad(loss_fn)(
                     params, tokens, targets)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("tf.update"):
+                    updates, opt_state = optimizer.update(
+                        grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, loss
 
             self._train_step_opt = _compile_obs.wrap_jit(
@@ -464,8 +487,15 @@ class TransformerTrainer:
         return jax.device_put(x, sh), jax.device_put(y, sh)
 
     def step(self, params: Params, tokens: np.ndarray):
-        x, y = self.place_batch(tokens)
-        return self._train_step(params, x, y)
+        """One SGD step; returns (params, loss) without waiting for the
+        device.  Spans ``train_step ⊃ {place_batch, dispatch}``: the two
+        ``device_put``s, and the call into the ledgered jit (which
+        returns once the program is enqueued)."""
+        with TRACER.span("train_step"):
+            with TRACER.span("place_batch"):
+                x, y = self.place_batch(tokens)
+            with TRACER.span("dispatch"):
+                return self._train_step(params, x, y)
 
     # -- optimizer (optax) path -----------------------------------------
 
@@ -484,8 +514,11 @@ class TransformerTrainer:
     def step_opt(self, params: Params, opt_state, tokens: np.ndarray):
         """One optimizer step; returns (params, opt_state, loss)."""
         self._need_tx()
-        x, y = self.place_batch(tokens)
-        return self._train_step_opt(params, opt_state, x, y)
+        with TRACER.span("train_step", optimizer=True):
+            with TRACER.span("place_batch"):
+                x, y = self.place_batch(tokens)
+            with TRACER.span("dispatch"):
+                return self._train_step_opt(params, opt_state, x, y)
 
     # -- checkpointing (the reference's GridFS-serialized trainer role,
     # common.lua:24-39; rides the sharded manifest-committed layer of

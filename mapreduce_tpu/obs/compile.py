@@ -60,6 +60,7 @@ import hashlib
 import json
 import logging
 import os
+import re
 import sys
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -100,6 +101,56 @@ _BUCKET_GAUGE = gauge(
     "mrtpu_compile_shape_buckets",
     "shape buckets known to the compile ledger (labels: "
     "scope=memory|disk)")
+
+
+#: ``Compiled.as_text()``, line by line: a computation's header, one
+#: instruction (``%fusion.151 = ... metadata={op_name="jit(f)/..." ...}``),
+#: and the computations an instruction calls (a ``while``'s body, a
+#: fusion's fused computation, a conditional's branches)
+_HLO_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.MULTILINE)
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'\bmetadata=\{[^}]*?\bop_name="([^"]*)"')
+_HLO_CALLED = re.compile(
+    r"\b(?:body|condition|calls|to_apply|branch_computations)="
+    r"(?:\{([^}]*)\}|(%?[\w.\-]+))")
+
+
+def hlo_op_paths(text: str) -> Dict[str, str]:
+    """``{instruction name: op_name path}`` of one HLO module's text.
+    An instruction the compiler made itself (a copy, a fusion it split)
+    carries no path: it takes that of the instruction whose computation
+    it sits in, so what runs inside a ``while`` of the loss is the
+    loss's."""
+    paths: Dict[str, str] = {}
+    inside: Dict[str, str] = {}       # instruction -> its computation
+    called_by: Dict[str, str] = {}    # computation -> calling instruction
+    computation = ""
+    for line in text.splitlines():
+        instr = _HLO_INSTRUCTION.match(line)
+        if instr is None:
+            header = _HLO_COMPUTATION.match(line)
+            if header is not None:
+                computation = header.group(1)
+            continue
+        name = instr.group(1)
+        inside[name] = computation
+        op_name = _HLO_OP_NAME.search(line)
+        if op_name is not None and op_name.group(1):
+            paths[name] = op_name.group(1)
+        for several, one in _HLO_CALLED.findall(line):
+            for callee in (several or one).split(", "):
+                called_by.setdefault(callee.lstrip("%"), name)
+    for name, computation in inside.items():
+        seen = set()
+        while name not in paths and computation in called_by \
+                and computation not in seen:
+            seen.add(computation)
+            caller = called_by[computation]
+            if caller in paths:
+                paths[name] = paths[caller]
+            computation = inside.get(caller, "")
+    return paths
 
 
 def cache_dir() -> Optional[str]:
@@ -485,6 +536,35 @@ class CompileLedger:
         if cdir:
             self._persist(registry_path(cdir), bucket, record)
         return compiled, outcome
+
+    # -- from instruction to stage -------------------------------------------
+
+    def stage_map(self, program: str) -> Dict[str, Dict[str, str]]:
+        """``{HLO module name: {instruction name: op_name path}}`` of the
+        executables of *program* the ledger retains: the program's own
+        account of which ``jax.named_scope`` each instruction came from
+        (``fusion.151 -> jit(wave)/.../wave.local/sur.compact/gather``).
+        A device trace names an operation by its instruction and its
+        module; this is the other half of that join
+        (:func:`hlo_op_paths`).  A fusion carries its root's path, so
+        one that XLA formed across a scope boundary is booked whole to
+        its root's scope.  Two retained executables
+        whose modules share a name share an entry, the newer one's
+        instructions winning.
+
+        Computed on demand from ``Compiled.as_text()`` — megabytes for
+        the wave program — so a trace reader calls it after the run,
+        never inside one."""
+        with self._lock:
+            execs = [compiled for (prog, _key, _sig), (compiled, _bucket)
+                     in self._execs.items() if prog == program]
+        out: Dict[str, Dict[str, str]] = {}
+        for compiled in execs:
+            text = compiled.as_text()
+            module = _HLO_MODULE.search(text)
+            out.setdefault(module.group(1) if module else "", {}).update(
+                hlo_op_paths(text))
+        return out
 
     # -- snapshots ---------------------------------------------------------
 

@@ -29,6 +29,20 @@ Two span surfaces:
   computes, and a wave's readback lands after later waves dispatched),
   so their spans are built by hand and closed when the readback proves
   the device work finished.
+
+Both surfaces also write into the PROFILER's trace: in a process that
+has imported jax, every span opens a ``jax.profiler.TraceAnnotation`` of
+its name when it starts and closes it when it ends, with ``span_id``,
+``parent_id`` and the span's scalar args as the annotation's stats.
+Under ``jax.profiler.start_trace`` the program's spans therefore sit in
+the ``.xplane.pb`` on the device operations' clock, which is what lets
+a trace reader say what the host was doing while the chip sat idle;
+with no trace running an annotation is a flag test.  The profiler
+stamps its own clock on entry, so a BACKDATED interval cannot be
+bridged: ``span(start=...)``, ``begin(start=...)`` and :meth:`Tracer.
+record` (the worker's job and claim spans) reach the ring only.  A
+process that never loads jax (a docserver, a host-plane worker) writes
+its ring as before and pays nothing.
 """
 
 from __future__ import annotations
@@ -37,6 +51,7 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -61,7 +76,8 @@ class Span:
     """A live span; ``args`` may be mutated until the span closes (e.g.
     to stamp an ``outcome``)."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "args")
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "args",
+                 "_annotation")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str], t0: float,
@@ -72,6 +88,26 @@ class Span:
         self.parent_id = parent_id
         self.t0 = t0
         self.args = args
+        self._annotation = None      # the profiler's twin, while open
+
+    def _annotate(self) -> None:
+        """Open this span's twin in the profiler's trace (module
+        docstring); nothing where jax is not loaded."""
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return
+        stats = {k: v for k, v in self.args.items()
+                 if isinstance(v, (str, int, float))}
+        if self.parent_id is not None:
+            stats["parent_id"] = self.parent_id
+        self._annotation = profiler.TraceAnnotation(
+            self.name, span_id=self.span_id, **stats)
+        self._annotation.__enter__()
+
+    def _close_annotation(self) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
 
 
 class Tracer:
@@ -134,7 +170,8 @@ class Tracer:
 
         ``start`` (a ``time.monotonic()`` stamp) backdates the span — the
         worker uses it so the per-job root span covers the claim RPC that
-        *preceded* knowing there was a job at all.
+        *preceded* knowing there was a job at all.  A backdated span is
+        ring-only: the profiler's trace cannot hold it.
         """
         parent = self.current()
         trace_id = parent[0] if parent else _new_id()
@@ -142,17 +179,21 @@ class Tracer:
                   parent[1] if parent else None,
                   start if start is not None else time.monotonic(),
                   dict(args))
+        if start is None:
+            sp._annotate()
         st = self._stack()
         st.append((sp.trace_id, sp.span_id))
         try:
             yield sp
         finally:
             st.pop()
+            sp._close_annotation()
             self._record(sp, time.monotonic())
 
     def record(self, name: str, t0: float, t1: float, **args: Any) -> None:
         """Record an already-elapsed interval as a child of the current
-        span (the worker's retroactive ``claim`` span)."""
+        span (the worker's retroactive ``claim`` span).  Ring-only: the
+        profiler's trace cannot hold an interval that is already over."""
         parent = self.current()
         sp = Span(name, parent[0] if parent else _new_id(), _new_id(),
                   parent[1] if parent else None, t0, dict(args))
@@ -167,16 +208,21 @@ class Tracer:
         under the thread's current span context.  For work whose
         lifetime crosses lexical scope (the engine's overlapping waves);
         close it with :meth:`end`.  All timestamps are
-        ``time.monotonic()``."""
+        ``time.monotonic()``.  With *start* the span is backdated and
+        ring-only; without, it is open in the profiler's trace from now
+        until :meth:`end`."""
         if parent is not None:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
             cur = self.current()
             trace_id = cur[0] if cur else _new_id()
             parent_id = cur[1] if cur else None
-        return Span(name, trace_id, _new_id(), parent_id,
-                    start if start is not None else time.monotonic(),
-                    dict(args))
+        sp = Span(name, trace_id, _new_id(), parent_id,
+                  start if start is not None else time.monotonic(),
+                  dict(args))
+        if start is None:
+            sp._annotate()
+        return sp
 
     def end(self, sp: Span, stop: Optional[float] = None,
             **args: Any) -> None:
@@ -184,6 +230,7 @@ class Tracer:
         caller's job — ending twice records the span twice)."""
         if args:
             sp.args.update(args)
+        sp._close_annotation()
         self._record(sp, stop if stop is not None else time.monotonic())
 
     def _record(self, sp: Span, t1: float) -> None:

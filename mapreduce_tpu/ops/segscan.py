@@ -451,71 +451,78 @@ def sorted_unique_reduce(keys: jax.Array, values, payload: jax.Array,
     else:
         v2 = values if values.ndim == 2 else values[:, None]
         n_val_lanes = v2.shape[1]
-    if sort_impl == "argsort":
-        # tier-0: two-pass stable argsort — each pass sorts ONE key
-        # lane plus the running permutation (2 operands, 1 key), and
-        # stability composes them into the exact 2-key permutation
-        iota = jnp.arange(N, dtype=jnp.int32)
-        _k2s, p1 = jax.lax.sort((k2, iota), num_keys=1)
-        k1s, perm = jax.lax.sort((k1[p1], p1), num_keys=1)
-        k2s = k2[perm]
-        v2s = v2[perm] if n_val_lanes else None
-        vals_s = [v2s[:, i] for i in range(n_val_lanes)]
-        pay_s = payload[perm]
-        pays_s = [pay_s[:, i] for i in range(Q)]
-    elif sort_impl == "radix":
-        # no comparator at all: Pallas LSD radix over the hash-key lanes
-        # (ops/radix_sort), bit-identical to the variadic permutation;
-        # record lanes always ride the rank-sort gather transport
-        from .radix_sort import radix_sort_pairs
-        k1s, k2s, perm = radix_sort_pairs(k1, k2, interpret=interpret)
-        v2s = v2[perm] if n_val_lanes else None
-        vals_s = [v2s[:, i] for i in range(n_val_lanes)]
-        pay_s = payload[perm]
-        pays_s = [pay_s[:, i] for i in range(Q)]
-    elif rank_sort:
-        iota = jnp.arange(N, dtype=jnp.int32)
-        k1s, k2s, perm = jax.lax.sort((k1, k2, iota), num_keys=2)
-        v2s = v2[perm] if n_val_lanes else None
-        vals_s = [v2s[:, i] for i in range(n_val_lanes)]
-        pay_s = payload[perm]
-        pays_s = [pay_s[:, i] for i in range(Q)]
-    else:
-        pay_lanes = [payload[:, i] for i in range(Q)]
-        val_lanes = [v2[:, i] for i in range(n_val_lanes)]
-        sorted_ops = jax.lax.sort(tuple([k1, k2] + val_lanes + pay_lanes),
-                                  num_keys=2)
-        k1s, k2s = sorted_ops[0], sorted_ops[1]
-        vals_s = list(sorted_ops[2:2 + len(val_lanes)])
-        pays_s = list(sorted_ops[2 + len(val_lanes):])
+    # the three stages are named for the device trace (metadata only):
+    # obs/compile's stage map books every operation to the innermost
+    # scope on its path
+    with jax.named_scope("sur.sort"):
+        if sort_impl == "argsort":
+            # tier-0: two-pass stable argsort — each pass sorts ONE key
+            # lane plus the running permutation (2 operands, 1 key), and
+            # stability composes them into the exact 2-key permutation
+            iota = jnp.arange(N, dtype=jnp.int32)
+            _k2s, p1 = jax.lax.sort((k2, iota), num_keys=1)
+            k1s, perm = jax.lax.sort((k1[p1], p1), num_keys=1)
+            k2s = k2[perm]
+            v2s = v2[perm] if n_val_lanes else None
+            vals_s = [v2s[:, i] for i in range(n_val_lanes)]
+            pay_s = payload[perm]
+            pays_s = [pay_s[:, i] for i in range(Q)]
+        elif sort_impl == "radix":
+            # no comparator at all: Pallas LSD radix over the hash-key
+            # lanes (ops/radix_sort), bit-identical to the variadic
+            # permutation; record lanes always ride the rank-sort gather
+            # transport
+            from .radix_sort import radix_sort_pairs
+            k1s, k2s, perm = radix_sort_pairs(k1, k2, interpret=interpret)
+            v2s = v2[perm] if n_val_lanes else None
+            vals_s = [v2s[:, i] for i in range(n_val_lanes)]
+            pay_s = payload[perm]
+            pays_s = [pay_s[:, i] for i in range(Q)]
+        elif rank_sort:
+            iota = jnp.arange(N, dtype=jnp.int32)
+            k1s, k2s, perm = jax.lax.sort((k1, k2, iota), num_keys=2)
+            v2s = v2[perm] if n_val_lanes else None
+            vals_s = [v2s[:, i] for i in range(n_val_lanes)]
+            pay_s = payload[perm]
+            pays_s = [pay_s[:, i] for i in range(Q)]
+        else:
+            pay_lanes = [payload[:, i] for i in range(Q)]
+            val_lanes = [v2[:, i] for i in range(n_val_lanes)]
+            sorted_ops = jax.lax.sort(
+                tuple([k1, k2] + val_lanes + pay_lanes), num_keys=2)
+            k1s, k2s = sorted_ops[0], sorted_ops[1]
+            vals_s = list(sorted_ops[2:2 + len(val_lanes)])
+            pays_s = list(sorted_ops[2 + len(val_lanes):])
 
-    if segment_impl == "pallas":
-        reduced, end_csum = _segment_reduce_pallas(
-            k1s, k2s, vals_s, op, unit_values, segment_block, interpret)
-    else:
-        reduced, end_csum = _segment_reduce_lax(
-            k1s, k2s, vals_s, op, unit_values)
+    with jax.named_scope("sur.segreduce"):
+        if segment_impl == "pallas":
+            reduced, end_csum = _segment_reduce_pallas(
+                k1s, k2s, vals_s, op, unit_values, segment_block, interpret)
+        else:
+            reduced, end_csum = _segment_reduce_lax(
+                k1s, k2s, vals_s, op, unit_values)
 
-    # compact run ends by GATHER: searchsorted over the cumulative end
-    # count finds the j-th run-end row (no O(N) scatter).  Shared
-    # verbatim between the two segment_impls, so the kernel's
-    # equivalence surface is exactly (reduced lanes, end_csum).
-    n_unique = end_csum[-1] if N > 0 else jnp.int32(0)
-    targets = jnp.arange(1, capacity + 1, dtype=jnp.int32)
-    out_idx = jnp.searchsorted(end_csum, targets, side="left")
-    out_idx = jnp.clip(out_idx, 0, N - 1)
-    out_valid = targets <= n_unique
+    with jax.named_scope("sur.compact"):
+        # compact run ends by GATHER: searchsorted over the cumulative
+        # end count finds the j-th run-end row (no O(N) scatter).  Shared
+        # verbatim between the two segment_impls, so the kernel's
+        # equivalence surface is exactly (reduced lanes, end_csum).
+        n_unique = end_csum[-1] if N > 0 else jnp.int32(0)
+        targets = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+        out_idx = jnp.searchsorted(end_csum, targets, side="left")
+        out_idx = jnp.clip(out_idx, 0, N - 1)
+        out_valid = targets <= n_unique
 
-    out_keys = jnp.stack([k1s[out_idx], k2s[out_idx]], axis=-1)
-    out_vals = [r[out_idx] for r in reduced]
-    out_vals = (jnp.stack(out_vals, axis=-1) if len(out_vals) > 1
-                else out_vals[0])
-    out_pay = jnp.stack([p[out_idx] for p in pays_s], axis=-1)
-    zero = jnp.zeros((), out_vals.dtype)
-    out_vals = jnp.where(
-        out_valid.reshape((-1,) + (1,) * (out_vals.ndim - 1)), out_vals,
-        zero)
-    out_keys = jnp.where(out_valid[:, None], out_keys, jnp.uint32(0))
-    out_pay = jnp.where(out_valid[:, None], out_pay, jnp.int32(0))
+        out_keys = jnp.stack([k1s[out_idx], k2s[out_idx]], axis=-1)
+        out_vals = [r[out_idx] for r in reduced]
+        out_vals = (jnp.stack(out_vals, axis=-1) if len(out_vals) > 1
+                    else out_vals[0])
+        out_pay = jnp.stack([p[out_idx] for p in pays_s], axis=-1)
+        zero = jnp.zeros((), out_vals.dtype)
+        out_vals = jnp.where(
+            out_valid.reshape((-1,) + (1,) * (out_vals.ndim - 1)), out_vals,
+            zero)
+        out_keys = jnp.where(out_valid[:, None], out_keys, jnp.uint32(0))
+        out_pay = jnp.where(out_valid[:, None], out_pay, jnp.int32(0))
     return SortedUnique(out_keys, out_vals, out_pay, out_valid,
                         n_unique.astype(jnp.int32))
