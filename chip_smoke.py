@@ -19,7 +19,11 @@ means:
   (167.8M parameters; ``bench_train.py``) with finite, falling loss and
   the flash kernels compiled — called directly on one device, per ring
   step on several — then the reference-parity ``"loop"`` trainer through
-  ``cli train``;
+  ``cli train`` (the looped configuration, ``loop_steps`` > 1 through
+  ``TransformerTrainer.step_opt``, is not driven here: its standing
+  proof on the chip is its benchmark cell, ``python3 benchmark/run.py
+  --workload train-ouro-4k``, which checks step 0 against
+  ``benchmark/reference_looplm.py`` at the published widths);
 * the compile cache where the environment placed it, and nowhere else.
 
 It claims no speed.  It exits non-zero before doing any work when the

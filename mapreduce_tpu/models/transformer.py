@@ -29,11 +29,26 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import compile as _compile_obs
+from ..obs import metrics as _metrics
 from ..obs.trace import TRACER
 from ..ops.flash_attention import flash_attention
 from ..parallel.ring import ring_attention
+from .looplm import apply_rope, looped_loss, rope_tables
 
 Params = Dict[str, jax.Array]
+
+_LAYER_APPS = _metrics.counter(
+    "mrtpu_train_layer_applications_total",
+    "transformer layer applications dispatched: loop_steps x n_layers a "
+    "training step")
+_PASS_LOSS = _metrics.gauge(
+    "mrtpu_train_loop_pass_loss",
+    "a looped model's mean next-token loss after each pass over the "
+    "layer stack, at the last step observed (labels: pass)")
+_EXIT_MASS = _metrics.gauge(
+    "mrtpu_train_exit_mass",
+    "a looped model's mean exit probability of each pass, at the last "
+    "step observed; sums to 1 over pass (labels: pass)")
 
 
 @dataclass(frozen=True)
@@ -87,11 +102,36 @@ class TransformerConfig:
     #: the jnp online-softmax path off-TPU.  True forces the kernel
     #: (tests run the interpreter on CPU); False forces jnp everywhere.
     flash: Any = None
+    #: LOOPED depth (weight-shared, "universal transformer" steps): the
+    #: whole stack of n_layers runs loop_steps times over every token
+    #: with the SAME parameters, so a weight's gradient is the sum over
+    #: its uses and activations are loop_steps * n_layers deep.  With
+    #: loop_steps > 1 the head, an exit gate and a loss apply after
+    #: every pass (loss_local) and the step returns one more array
+    loop_steps: int = 1
+    #: rotary position embedding on q and k (rotate-half over all of
+    #: head_dim, no scaling) with this base; None = no position encoding
+    rope_theta: Any = None
+    #: gated three-matrix FFN, silu(h w_gate) * (h w_in) then w_out;
+    #: False = the two-matrix GELU FFN
+    ffn_gated: bool = False
+    #: an RMSNorm on each sublayer's OUTPUT as well as its input
+    sandwich_norm: bool = False
+    #: an RMSNorm after the last layer; in a looped model it closes
+    #: every pass, and its output is what the next pass starts from
+    final_norm: bool = False
+    #: weight of the exit distribution's negative entropy in the looped
+    #: objective (read only when loop_steps > 1)
+    exit_entropy_weight: float = 0.1
 
     def validate(self, n_model: int) -> None:
         assert self.n_heads % n_model == 0, "heads must split over model axis"
         assert self.ffn % n_model == 0
         assert self.vocab % n_model == 0
+        assert self.loop_steps >= 1
+        assert not (self.ffn_gated and self.moe_experts), \
+            "the experts are two-matrix GELU FFNs"
+        assert self.rope_theta is None or self.head_dim % 2 == 0
         if self.moe_experts:
             assert self.moe_experts == n_model, (
                 "expert parallelism maps one expert per model-axis rank: "
@@ -121,15 +161,29 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
         if cfg.moe_experts:
             params[f"L{i}.w_router"] = norm(keys[k0 + 4],
                                             (E, cfg.moe_experts), E)
+        if cfg.ffn_gated:
+            params[f"L{i}.w_gate"] = norm(keys[k0 + 5], (E, F), E)
+        if cfg.sandwich_norm:
+            params[f"L{i}.ln1_out_scale"] = jnp.ones((E,), jnp.float32)
+            params[f"L{i}.ln2_out_scale"] = jnp.ones((E,), jnp.float32)
+    if cfg.final_norm:
+        params["final_scale"] = jnp.ones((E,), jnp.float32)
+    if cfg.loop_steps > 1:
+        # one exit gate for every pass; a key of its own, so that the
+        # tensors above are what they are without it
+        params["exit_w"] = norm(jax.random.fold_in(key, 1), (E,), E)
+        params["exit_b"] = jnp.zeros((1,), jnp.float32)
     return params
 
 
 def transformer_param_spec(name: str) -> P:
     """Tensor-parallel placement by name: head/column-sharded projections,
-    row-sharded outputs, replicated norms/embeddings/router.  The same
+    row-sharded outputs, replicated norms/embeddings/router/exit gate.
+    The gated FFN's w_gate is column-sharded like w_in: the product of
+    the two is elementwise over the local columns.  The same
     w_in/w_out shards double as per-rank EXPERTS under expert parallelism
     (moe_experts) — the layout is identical, only the math changes."""
-    if name.endswith((".wqkv", ".w_in")):
+    if name.endswith((".wqkv", ".w_in", ".w_gate")):
         return P(None, None, "model") if name.endswith("wqkv") \
             else P(None, "model")
     if name.endswith((".wo", ".w_out")):
@@ -146,9 +200,12 @@ def _rmsnorm(x, scale):
 
 
 def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
-                 n_model: int, data_axis: str, model_axis: str):
+                 n_model: int, data_axis: str, model_axis: str,
+                 rope=None):
     """One transformer block on the local sequence shard (inside
-    shard_map); ``lp`` holds this layer's params without the L<i> prefix."""
+    shard_map); ``lp`` holds this layer's params without the L<i> prefix,
+    ``rope`` the rotary tables of :func:`looplm.rope_tables` (None = no
+    position encoding)."""
     H_loc = cfg.n_heads // n_model
     D = cfg.head_dim
     E = x.shape[-1]
@@ -170,6 +227,19 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                              lp["wqkv"].astype(cfg.dtype))
             q, k, v = [qkv[:, :, j].reshape(*qkv.shape[:2], H_loc, D)
                        for j in range(3)]
+    if rope is not None:
+        with jax.named_scope("tf.rope"):
+            # q and k rotate as ONE tensor, sliced from the projection's
+            # output in its layout: [B, 3, H, T, D] for the kernel,
+            # [B, T, 3, H*D] for the ring.  (Rotated apart, the compiler
+            # merges the two into a fusion that carries no scope.)
+            if cfg.flash:
+                qk = apply_rope(qkv[:, :2], rope, 3)
+                q, k = qk[:, 0], qk[:, 1]
+            else:
+                qk = apply_rope(qkv[:, :, :2].reshape(
+                    *qkv.shape[:2], 2, H_loc, D), rope, 1)
+                q, k = qk[:, :, 0], qk[:, :, 1]
     if cfg.flash:
         # attn_block doubles as the kernel tile request (auto-shrunk to
         # divide T).  The kernel default is 1024: for the one-pass
@@ -198,6 +268,9 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
             o = jnp.einsum("btf,fe->bte", attn,
                            lp["wo"].astype(cfg.dtype))
         o = jax.lax.psum(o.astype(jnp.float32), model_axis)
+        if cfg.sandwich_norm:
+            # the sublayer's output is whole only after the psum
+            o = _rmsnorm(o, lp["ln1_out_scale"])
         x = x + o.astype(cfg.dtype)
 
     with jax.named_scope("tf.ffn"):
@@ -206,10 +279,18 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
             m, aux = _moe_ffn(h, lp, cfg, model_axis)
         else:
             u = jnp.einsum("bte,ef->btf", h, lp["w_in"].astype(cfg.dtype))
-            u = jax.nn.gelu(u)
+            if cfg.ffn_gated:
+                g = jnp.einsum("bte,ef->btf", h,
+                               lp["w_gate"].astype(cfg.dtype))
+                u = (jax.nn.silu(g.astype(jnp.float32))
+                     * u.astype(jnp.float32)).astype(cfg.dtype)
+            else:
+                u = jax.nn.gelu(u)
             m = jnp.einsum("btf,fe->bte", u, lp["w_out"].astype(cfg.dtype))
             m = jax.lax.psum(m.astype(jnp.float32), model_axis)
             aux = jnp.float32(0.0)
+        if cfg.sandwich_norm:
+            m = _rmsnorm(m.astype(jnp.float32), lp["ln2_out_scale"])
         return x + m.astype(cfg.dtype), aux
 
 
@@ -269,24 +350,64 @@ def forward_local(params: Params, tokens: jax.Array,
                   data_axis: str = "data", model_axis: str = "model"):
     """Local-block forward INSIDE shard_map: ``tokens`` [B, T_local]
     int32; returns ``(hidden [B, T_local, E] f32, aux [] f32)`` where aux
-    is the summed MoE load-balance excess (0 for dense layers).  Params
-    arrive already sliced by transformer_param_spec."""
+    is the summed MoE load-balance excess (0 for dense layers).  A looped
+    model (``loop_steps`` R > 1) returns the hidden state after EVERY
+    pass, [R, B, T_local, E] in ``cfg.dtype`` (what the next pass read).
+    Params arrive already sliced by transformer_param_spec."""
     with jax.named_scope("tf.embed"):
         x = params["embed"][tokens].astype(cfg.dtype)  # [B, T, E]
+    rope = None
+    if cfg.rope_theta is not None:
+        with jax.named_scope("tf.rope"):
+            rope = rope_tables(cfg, tokens.shape[1], data_axis)
 
-    def layer(x, lp):
-        return _layer_local(x, lp, cfg, n_model, data_axis, model_axis)
+    def layer(x, lp, rope):
+        return _layer_local(x, lp, cfg, n_model, data_axis, model_axis,
+                            rope)
+
+    def final_norm(x, scale):
+        with jax.named_scope("tf.final_norm"):
+            return _rmsnorm(x, scale.astype(cfg.dtype))
 
     if cfg.remat:
-        layer = jax.checkpoint(layer)
-    aux_total = jnp.float32(0.0)
-    for i in range(cfg.n_layers):
-        prefix = f"L{i}."
-        lp = {k[len(prefix):]: v for k, v in params.items()
-              if k.startswith(prefix)}
-        x, aux = layer(x, lp)
-        aux_total = aux_total + aux
-    return x.astype(jnp.float32), aux_total
+        layer, final_norm = (jax.checkpoint(f) for f in (layer, final_norm))
+
+    def stack(x):
+        """The n_layers once, then the final norm."""
+        aux_total = jnp.float32(0.0)
+        for i in range(cfg.n_layers):
+            prefix = f"L{i}."
+            lp = {k[len(prefix):]: v for k, v in params.items()
+                  if k.startswith(prefix)}
+            x, aux = layer(x, lp, rope)
+            aux_total = aux_total + aux
+        if cfg.final_norm:
+            x = final_norm(x, params["final_scale"])
+        return x, aux_total
+
+    if cfg.loop_steps == 1:
+        x, aux_total = stack(x)
+        return x.astype(jnp.float32), aux_total
+
+    # depth by re-use of weights: one compiled body of n_layers, run
+    # loop_steps times.  The scan's transpose carries ONE float32
+    # gradient accumulator a weight and adds each pass's share to it; a
+    # Python unroll would leave the loop_steps partial gradients of a
+    # weight to the compiler's scheduling, at 4 bytes a parameter each
+    def one_pass(x, _):
+        x, aux = stack(x)
+        return x, (x, aux)
+
+    # every operation written in the body has a stage of its own; what
+    # takes the loop's is the scan's own machinery (each pass's saved
+    # layer inputs written to and read from their stack, each pass's
+    # share added to a weight's gradient) AND whatever the compiler
+    # makes inside the body without a scope: read it beside (unscoped)
+    # (looplm.pass_loop_share; PERF.md section 3)
+    with jax.named_scope("tf.pass_loop"):
+        _, (hs, auxs) = jax.lax.scan(one_pass, x, None,
+                                     length=cfg.loop_steps)
+    return hs, auxs.sum()
 
 
 def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
@@ -296,7 +417,16 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
     logits stay [B, T, V/n_model]; softmax statistics combine with
     pmax/psum over the model axis; the mean combines with pmean over the
     sequence (data) axis.  ``targets`` are the GLOBAL next tokens for this
-    block (host pre-shifts across shard boundaries)."""
+    block (host pre-shifts across shard boundaries).
+
+    A looped model (``loop_steps`` R > 1) has a head and a loss after
+    every pass and one exit gate ``lam_t = sigmoid(h_t . exit_w +
+    exit_b)``; ``p_t = lam_t prod_{j<t}(1 - lam_j)`` (the last pass takes
+    what is left) is each position's exit distribution, and the
+    objective is the expected loss under it less
+    ``exit_entropy_weight`` times its entropy.  Returns ``(objective,
+    stats)`` then, ``stats`` [2, R] float32: the mean loss of each pass
+    and the mean exit mass of each pass."""
     x, aux = forward_local(params, tokens, cfg, n_model, data_axis,
                            model_axis)
     w = params["unembed"]  # [E, V_loc]
@@ -327,6 +457,10 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
         t_logit = jax.lax.psum(jnp.where(in_shard, t_logit, 0.0),
                                model_axis)
         return (gmax + jnp.log(denom)) - t_logit
+
+    if cfg.loop_steps > 1:
+        return looped_loss(x, aux, targets, params, chunk_nll, cfg,
+                           data_axis)
 
     # everything from the unembedding on is the loss stage: chunk_nll
     # is traced where it is called, inside the scope
@@ -389,9 +523,15 @@ class TransformerTrainer:
         def sharded_loss(params, tokens, targets):
             return loss_local(params, tokens, targets, cfg, n_model)
 
+        # a looped model's loss comes with its per-pass statistics
+        # (loss_local); step_opt returns them after the loss.  The SGD
+        # step and the fused steps stay the dense model's (step refuses
+        # a looped one): no caller trains a looped model through them
+        looped = cfg.loop_steps > 1
         loss_fn = jax.shard_map(
             sharded_loss, mesh=mesh,
-            in_specs=(pspecs, tok_spec, tok_spec), out_specs=P())
+            in_specs=(pspecs, tok_spec, tok_spec),
+            out_specs=(P(), P()) if looped else P())
 
         def train_step(params, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(
@@ -435,13 +575,14 @@ class TransformerTrainer:
             import optax
 
             def train_step_opt(params, opt_state, tokens, targets):
-                loss, grads = jax.value_and_grad(loss_fn)(
+                out, grads = jax.value_and_grad(loss_fn, has_aux=looped)(
                     params, tokens, targets)
                 with jax.named_scope("tf.update"):
                     updates, opt_state = optimizer.update(
                         grads, opt_state, params)
                     params = optax.apply_updates(params, updates)
-                return params, opt_state, loss
+                # a looped model: (loss, stats)
+                return (params, opt_state, *(out if looped else (out,)))
 
             self._train_step_opt = _compile_obs.wrap_jit(
                 train_step_opt, program="tf_step_opt",
@@ -470,11 +611,20 @@ class TransformerTrainer:
 
         return tree_map_with_path(place, opt_state)
 
-    def _opt_init(self, params):
+    def init_opt_state(self, params):
+        """The optimizer's fresh state for *params*, placed on the mesh;
+        under ``jax.jit`` it is made on the devices."""
+        self._need_tx()
         return self._place_opt_state(self.tx.init(params))
 
-    def init_params(self) -> Params:
-        params = init_transformer(jax.random.key(self.seed), self.cfg)
+    def init_params(self, key=None) -> Params:
+        """Fresh params on the mesh, from the trainer's seed or from
+        *key*.  Under ``jax.jit`` with *key* as the argument ONE compiled
+        program serves every seed (the seed is a constant of it
+        otherwise)."""
+        if key is None:
+            key = jax.random.key(self.seed)
+        params = init_transformer(key, self.cfg)
         return {n: jax.device_put(
                     a, NamedSharding(self.mesh, self._pspecs[n]))
                 for n, a in params.items()}
@@ -493,12 +643,30 @@ class TransformerTrainer:
         """One SGD step; returns (params, loss) without waiting for the
         device.  Spans ``train_step ⊃ {place_batch, dispatch}``: the two
         ``device_put``s, and the call into the ledgered jit (which
-        returns once the program is enqueued)."""
+        returns once the program is enqueued).  A looped model trains
+        through :meth:`step_opt`, which returns its per-pass statistics;
+        this step refuses one."""
+        if self.cfg.loop_steps > 1:
+            raise RuntimeError(
+                "a looped model (loop_steps > 1) trains through step_opt; "
+                "the SGD step carries no per-pass statistics")
         with TRACER.span("train_step"):
             with TRACER.span("place_batch"):
                 x, y = self.place_batch(tokens)
             with TRACER.span("dispatch"):
+                _LAYER_APPS.inc(self.cfg.n_layers)
                 return self._train_step(params, x, y)
+
+    def observe_passes(self, stats) -> np.ndarray:
+        """Read a looped step's ``stats`` ([2, R]: each pass's mean loss,
+        each pass's mean exit mass) back to the host — which waits for
+        the step — and set ``mrtpu_train_loop_pass_loss{pass}`` and
+        ``mrtpu_train_exit_mass{pass}`` from it; returns it as numpy."""
+        stats = np.asarray(stats)
+        for t in range(stats.shape[1]):
+            _PASS_LOSS.set(float(stats[0, t]), **{"pass": t + 1})
+            _EXIT_MASS.set(float(stats[1, t]), **{"pass": t + 1})
+        return stats
 
     # -- optimizer (optax) path -----------------------------------------
 
@@ -512,15 +680,18 @@ class TransformerTrainer:
         """-> (params, opt_state) for the optax path (optimizer= set)."""
         self._need_tx()
         params = self.init_params()
-        return params, self._opt_init(params)
+        return params, self.init_opt_state(params)
 
     def step_opt(self, params: Params, opt_state, tokens: np.ndarray):
-        """One optimizer step; returns (params, opt_state, loss)."""
+        """One optimizer step; returns (params, opt_state, loss), and
+        for a looped model ``stats`` after the loss, as
+        :meth:`observe_passes` takes it."""
         self._need_tx()
         with TRACER.span("train_step", optimizer=True):
             with TRACER.span("place_batch"):
                 x, y = self.place_batch(tokens)
             with TRACER.span("dispatch"):
+                _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers)
                 return self._train_step_opt(params, opt_state, x, y)
 
     # -- checkpointing (the reference's GridFS-serialized trainer role,
@@ -532,8 +703,18 @@ class TransformerTrainer:
         (n_heads=4/head_dim=8 vs 8/4 give IDENTICAL wqkv shapes) that no
         shape check can."""
         c = self.cfg
-        return (f"v{c.vocab}.e{c.embed}.l{c.n_layers}.h{c.n_heads}."
-                f"d{c.head_dim}.f{c.ffn}.moe{c.moe_experts}")
+        tag = (f"v{c.vocab}.e{c.embed}.l{c.n_layers}.h{c.n_heads}."
+               f"d{c.head_dim}.f{c.ffn}.moe{c.moe_experts}")
+        block = (c.loop_steps, c.rope_theta, c.ffn_gated, c.sandwich_norm,
+                 c.final_norm)
+        if block != (1, None, False, False, False):
+            # passes and rotary base change no shape: the same tensors
+            # would load into another function.  A dense block keeps the
+            # tag its checkpoints were written under
+            tag += (f".loop{c.loop_steps}.rope{c.rope_theta}."
+                    f"gated{int(c.ffn_gated)}.sandwich"
+                    f"{int(c.sandwich_norm)}.final{int(c.final_norm)}")
+        return tag
 
     def save(self, path: str, params: Params, step: int = 0,
              opt_state=None, keep: int = 3) -> None:
@@ -655,7 +836,7 @@ class TransformerTrainer:
         host, opt_host, saved_tree, step = self._load_host(path)
         params = self._place_params(host)
         if opt_host is None:
-            return params, self._opt_init(params), step
+            return params, self.init_opt_state(params), step
         template = jax.eval_shape(self.tx.init, params)
         named, treedef = flatten_with_names(template)
         want_names = ["opt/" + n for n, _ in named]

@@ -1,0 +1,544 @@
+"""The looped language model (PR 28): ``TransformerConfig.loop_steps`` and
+the block that Ouro-2.6B needs (rotary positions, gated FFN, sandwich
+norms, final norm, exit gate), against the plain float32 reference
+``benchmark/reference_looplm.py`` on seeded weights.
+
+CPU, toy size (E 64, 2 layers, R 3, 4 heads of 16, vocabulary 512, T 128,
+loss_block 64): objective, per-pass losses, exit masses and the gradient
+of EVERY parameter — the shared weights' is the sum over the passes —
+with recomputation on and off, the flash kernel under the Pallas
+interpreter and the jnp path, and on a 2-device ``model`` mesh.  No time
+here is a device number.
+"""
+
+import functools
+import json
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from mapreduce_tpu.models.transformer import (TransformerConfig,
+                                              TransformerTrainer,
+                                              init_transformer, loss_local,
+                                              transformer_param_spec)
+from mapreduce_tpu.obs.compile import LEDGER
+from mapreduce_tpu.obs.metrics import REGISTRY
+from mapreduce_tpu.parallel import make_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+R = 3
+MODEL = dict(vocab=512, embed=64, n_layers=2, n_heads=4, head_dim=16,
+             ffn=128, loss_block=64, loop_steps=R, rope_theta=1e6,
+             ffn_gated=True, sandwich_norm=True, final_norm=True,
+             exit_entropy_weight=0.1)
+REFERENCE = dict(n_layers=2, n_heads=4, head_dim=16, loop_steps=R,
+                 rope_theta=1e6, beta=0.1, block=32)
+TOKENS = np.random.default_rng(0).integers(0, MODEL["vocab"], size=(2, 129),
+                                           dtype=np.int32)
+
+#: (dtype, remat, flash, devices on the model axis)
+CASES = [("float32", False, False, 1), ("float32", True, False, 1),
+         ("float32", False, True, 1), ("float32", True, True, 1),
+         ("float32", True, False, 2), ("float32", True, True, 2),
+         ("bfloat16", True, True, 1)]
+#: Tolerances, relative to the reference's largest magnitude.  In
+#: float32 the system differs from the reference only by the order of
+#: its sums (blocked attention, chunked loss, psum over two devices):
+#: a few float32 units through 6 layer applications, measured 3e-6 on
+#: the worst gradient.  In bfloat16 every product's operands carry 8
+#: bits (4e-3 a product); the means over 256 positions read 1e-4 to
+#: 3e-3 and single gradient elements up to 7e-2 of the tensor's largest.
+TOL = {"float32": dict(objective=1e-5, pass_loss=1e-5, exit_mass=1e-5,
+                       grad=1e-4),
+       "bfloat16": dict(objective=1e-3, pass_loss=2e-3, exit_mass=5e-3,
+                        grad=0.15)}
+GROUPS = {"embedding": ("embed",), "head": ("unembed",),
+          "attention": (".wqkv", ".wo"),
+          "ffn": (".w_in", ".w_gate", ".w_out"),
+          "norms": ("_scale",), "exit_gate": ("exit_w", "exit_b")}
+
+
+def case_id(case):
+    dtype, remat, flash, n_model = case
+    return (f"{dtype}-{'remat' if remat else 'saved'}-"
+            f"{'flash' if flash else 'jnp'}-model{n_model}")
+
+
+def seeded_params(cfg):
+    """The program's init with every vector (norm scales, gate) moved off
+    its all-ones or all-zeros start, so that each one's gradient and its
+    place in the mathematics are tested."""
+    params = init_transformer(jax.random.key(3), cfg)
+    key = jax.random.key(7)
+    return {n: (a + 0.1 * jax.random.normal(jax.random.fold_in(key, i),
+                                            a.shape) if a.ndim == 1 else a)
+            for i, (n, a) in enumerate(sorted(params.items()))}
+
+
+@functools.lru_cache(maxsize=None)
+def reference():
+    from benchmark import reference_looplm
+
+    params = seeded_params(TransformerConfig(**MODEL))
+
+    def objective(p):
+        out = reference_looplm.reference_outputs(
+            p, TOKENS[:, :-1], TOKENS[:, 1:], **REFERENCE)
+        return out[0], out[1:]
+
+    (obj, (losses, masses)), grads = jax.jit(jax.value_and_grad(
+        objective, has_aux=True))(params)
+    return (float(obj), np.asarray(losses), np.asarray(masses),
+            {n: np.asarray(g) for n, g in grads.items()})
+
+
+@functools.lru_cache(maxsize=None)
+def system(case):
+    """``loss_local`` under ``shard_map`` exactly as the trainer wraps
+    it, differentiated: (objective, stats [2, R], gradients)."""
+    dtype, remat, flash, n_model = case
+    cfg = TransformerConfig(dtype=jnp.dtype(dtype), remat=remat, flash=flash,
+                            **MODEL)
+    mesh = make_mesh(devices=jax.devices()[:n_model], n_model=n_model)
+    params = seeded_params(cfg)
+    f = jax.shard_map(
+        lambda p, x, y: loss_local(p, x, y, cfg, n_model), mesh=mesh,
+        in_specs=({n: transformer_param_spec(n) for n in params},
+                  P(None, "data"), P(None, "data")),
+        out_specs=(P(), P()))
+    (obj, stats), grads = jax.jit(jax.value_and_grad(f, has_aux=True))(
+        params, TOKENS[:, :-1], TOKENS[:, 1:])
+    return (float(obj), np.asarray(stats),
+            {n: np.asarray(g) for n, g in grads.items()})
+
+
+# -- the system against the reference ----------------------------------------
+
+
+@pytest.mark.parametrize("case", CASES, ids=case_id)
+def test_objective_matches_the_reference(case):
+    want, got = reference()[0], system(case)[0]
+    assert abs(got - want) / abs(want) <= TOL[case[0]]["objective"]
+
+
+@pytest.mark.parametrize("case", CASES, ids=case_id)
+def test_pass_losses_match_the_reference(case):
+    want, got = reference()[1], system(case)[1][0]
+    assert got.shape == (R,)
+    assert np.max(np.abs(got - want) / want) <= TOL[case[0]]["pass_loss"]
+
+
+@pytest.mark.parametrize("case", CASES, ids=case_id)
+def test_exit_masses_match_the_reference_and_sum_to_one(case):
+    want, got = reference()[2], system(case)[1][1]
+    assert np.max(np.abs(got - want)) <= TOL[case[0]]["exit_mass"]
+    assert abs(float(got.sum()) - 1.0) <= 1e-5
+    assert abs(float(want.sum()) - 1.0) <= 1e-5
+    assert (got > 0.05).all()        # a gate that is open at every pass
+
+
+@pytest.mark.parametrize("group", sorted(GROUPS))
+@pytest.mark.parametrize("case", CASES, ids=case_id)
+def test_every_gradient_matches_the_reference(case, group):
+    want, got = reference()[3], system(case)[2]
+    assert set(got) == set(want)
+    names = [n for n in want if n.endswith(GROUPS[group])]
+    assert names and all(np.abs(want[n]).max() > 0 for n in names)
+    for n in names:
+        gap = np.abs(got[n] - want[n]).max() / np.abs(want[n]).max()
+        assert gap <= TOL[case[0]]["grad"], (n, gap)
+
+
+def test_every_parameter_is_in_a_gradient_group():
+    names = set(reference()[3])
+    covered = {n for n in names for ends in GROUPS.values()
+               if n.endswith(ends)}
+    assert covered == names
+
+
+def test_a_shared_weights_gradient_is_the_sum_over_its_passes():
+    """With R passes a layer's weight is used R times and its gradient
+    is the sum over the uses: a finite difference of the whole objective
+    in one element of a shared weight agrees with the reference's
+    gradient there, and the looped system's with both."""
+    from benchmark import reference_looplm
+
+    params = seeded_params(TransformerConfig(**MODEL))
+    name = "L0.w_out"
+
+    @jax.jit
+    def objective(p):
+        return reference_looplm.reference_outputs(
+            p, TOKENS[:, :-1], TOKENS[:, 1:], **REFERENCE)[0]
+
+    whole = reference()[3][name]
+    looped = system(CASES[1])[2][name]
+    eps = 1e-3
+    bumped = dict(params, **{name: params[name].at[3, 5].add(eps)})
+    fd = (float(objective(bumped)) - float(objective(params))) / eps
+    assert looped[3, 5] == pytest.approx(whole[3, 5], rel=1e-3)
+    assert whole[3, 5] == pytest.approx(fd, rel=0.05)
+
+
+# -- the dense program is what it was ----------------------------------------
+
+
+def test_loop_steps_one_is_the_dense_program_bit_for_bit():
+    dense = dict(vocab=512, embed=64, n_layers=2, n_heads=4, head_dim=16,
+                 ffn=128, loss_block=64, flash=False)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    a = TransformerTrainer(mesh, TransformerConfig(**dense), seed=5)
+    b = TransformerTrainer(mesh, TransformerConfig(
+        **dense, loop_steps=1, rope_theta=None, ffn_gated=False,
+        sandwich_norm=False, final_norm=False), seed=5)
+    pa, pb = a.init_params(), b.init_params()
+    # the looped tensors do not exist, and the old ones are the old ones
+    assert sorted(pa) == sorted(pb) == sorted(
+        ["embed", "unembed"] + [f"L{i}.{n}" for i in range(2) for n in (
+            "ln1_scale", "ln2_scale", "wqkv", "wo", "w_in", "w_out")])
+    x = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    shapes = jax.eval_shape(a.init_params)
+    assert a._train_step.lower(shapes, x, x).as_text() == \
+        b._train_step.lower(shapes, x, x).as_text()
+    for _ in range(2):
+        pa, la = a.step(pa, TOKENS)
+        pb, lb = b.step(pb, TOKENS)
+        assert float(la) == float(lb)
+    assert a._arch_tag() == b._arch_tag() == "v512.e64.l2.h4.d16.f128.moe0"
+
+
+# -- the trainer's normal path ------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def looped_trainer():
+    import optax
+
+    cfg = TransformerConfig(flash=False, remat=True, **MODEL)
+    return TransformerTrainer(
+        make_mesh(devices=jax.devices()[:1]), cfg, seed=11,
+        optimizer=optax.adamw(3e-3, b1=0.9, b2=0.95, weight_decay=0.1))
+
+
+def test_step_opt_lowers_the_objective_on_a_repeated_batch(looped_trainer):
+    tr = looped_trainer
+    params, opt_state = tr.init_state()
+    before = REGISTRY.sum("mrtpu_train_layer_applications_total")
+    seen = []
+    for _ in range(4):
+        params, opt_state, loss, stats = tr.step_opt(params, opt_state,
+                                                     TOKENS)
+        stats = tr.observe_passes(stats)
+        assert stats.shape == (2, R) and np.isfinite(stats).all()
+        assert abs(float(stats[1].sum()) - 1.0) <= 1e-5
+        seen.append(float(loss))
+    assert seen[-1] < seen[0] and all(np.isfinite(seen))
+    # the counter and the two gauges, from the last step's statistics
+    assert REGISTRY.sum("mrtpu_train_layer_applications_total") - before \
+        == 4 * R * MODEL["n_layers"]
+    for t in range(R):
+        assert REGISTRY.value("mrtpu_train_loop_pass_loss",
+                              **{"pass": t + 1}) == float(stats[0, t])
+        assert REGISTRY.value("mrtpu_train_exit_mass",
+                              **{"pass": t + 1}) == float(stats[1, t])
+
+
+def test_sgd_step_refuses_a_looped_model(looped_trainer):
+    """The per-pass statistics come back from ``step_opt`` alone; no
+    caller trains a looped model through the SGD step."""
+    tr = looped_trainer
+    with pytest.raises(RuntimeError, match="step_opt"):
+        tr.step(tr.init_params(), TOKENS)
+
+
+def test_init_params_takes_the_seed_as_an_argument(looped_trainer):
+    """One jitted program for every seed: the key is its argument, and
+    the trainer's own seed gives what it gave."""
+    tr = looped_trainer
+    init = jax.jit(tr.init_params)
+    mine, same, other = (init(jax.random.key(s)) for s in (11, 11, 12))
+    plain = tr.init_params()
+    assert init._cache_size() == 1
+    for n in plain:       # eager and compiled differ in the last place
+        assert np.allclose(mine[n], plain[n], rtol=1e-6, atol=0)
+        assert np.array_equal(mine[n], same[n])
+    assert not np.array_equal(mine["L0.wqkv"], other["L0.wqkv"])
+    _, opt_state = tr.init_state()
+    again = jax.jit(tr.init_opt_state)(plain)
+    assert jax.tree.structure(again) == jax.tree.structure(opt_state)
+
+
+@pytest.mark.parametrize("other", [
+    dict(loop_steps=1), dict(loop_steps=2), dict(rope_theta=1e4),
+    dict(rope_theta=None)], ids=lambda d: "-".join(
+        f"{k}={v}" for k, v in d.items()))
+def test_arch_tag_refuses_a_checkpoint_of_another_function(
+        looped_trainer, other, tmp_path):
+    """Passes and the rotary base change no tensor's shape; only the tag
+    tells such a checkpoint from this trainer's."""
+    tr = looped_trainer
+    model = dict(MODEL, **other)
+    theirs = TransformerTrainer(
+        make_mesh(devices=jax.devices()[:1]),
+        TransformerConfig(flash=False, **model), seed=11)
+    assert theirs._arch_tag() != tr._arch_tag()
+    theirs.save(str(tmp_path), theirs.init_params(), step=1)
+    with pytest.raises(ValueError, match="arch"):
+        tr.load(str(tmp_path))
+    tr.save(str(tmp_path / "mine"), tr.init_params(), step=2)
+    assert tr.load(str(tmp_path / "mine"))[1] == 2
+
+
+def test_arch_tag_refuses_a_dense_checkpoint(looped_trainer, tmp_path):
+    dense = TransformerTrainer(
+        make_mesh(devices=jax.devices()[:1]),
+        TransformerConfig(flash=False, **{k: MODEL[k] for k in (
+            "vocab", "embed", "n_layers", "n_heads", "head_dim", "ffn")}))
+    dense.save(str(tmp_path), dense.init_params(), step=1)
+    with pytest.raises(ValueError, match="arch"):
+        looped_trainer.load(str(tmp_path))
+
+
+@pytest.mark.parametrize("scope", ["tf.rope", "tf.exit_gate",
+                                   "tf.final_norm", "tf.ffn", "tf.attn_proj",
+                                   "tf.loss", "tf.update", "tf.embed"])
+def test_stage_map_books_the_looped_program(looped_trainer, scope):
+    """Forward, recomputed forward and backward all run inside the pass
+    loop's ``while`` bodies; the stage map must still name them."""
+    from benchmark import stages
+
+    tr = looped_trainer
+    tr.step_opt(*tr.init_state(), TOKENS)
+    (paths,) = LEDGER.stage_map("tf_step_opt").values()
+    chains = [stages.stage_chain(p) for p in paths.values()]
+    assert any(scope in c for c in chains)
+    if scope in ("tf.rope", "tf.ffn", "tf.attn_proj"):
+        # the backward pass of the scope, inside the transposed loop
+        assert any("transpose(" in p and scope in stages.stage_chain(p)
+                   and "while" in p for p in paths.values())
+
+
+# -- the benchmark's side: reference, operations, configuration, kind --------
+
+
+def load(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def test_reference_is_independent_and_sets_highest_precision():
+    with open(os.path.join(BENCH, "reference_looplm.py")) as f:
+        source = f.read()
+    code = source.split('"""', 2)[2]
+    assert "mapreduce_tpu" not in code and "import jax" in code
+    assert 'default_matmul_precision("highest")' in code
+    assert "pallas" not in code and "shard_map" not in code
+
+
+def test_reference_with_rounded_operands_leaves_the_reference():
+    """``operand_dtype`` is how the chip run reads what 8-bit operands
+    would give; it must move the numbers, and ``None`` must not."""
+    from benchmark import reference_looplm
+
+    params = seeded_params(TransformerConfig(**MODEL))
+    args = (params, TOKENS[:, :-1], TOKENS[:, 1:])
+    want = reference()
+    exact = reference_looplm.reference_outputs(*args, **REFERENCE)
+    assert float(exact[0]) == pytest.approx(want[0], rel=1e-6)
+    rough = reference_looplm.reference_outputs(
+        *args, **REFERENCE, operand_dtype=jnp.float8_e4m3fn)
+    fine = reference_looplm.reference_outputs(
+        *args, **REFERENCE, operand_dtype=jnp.bfloat16)
+    gap = lambda out: float(np.max(np.abs(np.asarray(out[2]) - want[2])))
+    assert gap(rough) > 4 * gap(fine) > 0
+
+
+def test_required_flops_at_the_cells_sizes():
+    from benchmark import flops_looplm
+
+    config = load(BENCH, "configs", "ouro-2.6b-l8.json")
+    model, train = config["model"], config["train"]
+    assert flops_looplm.matmul_params_per_pass(model) == \
+        8 * 51_380_224 + 2048 * 49152
+    got = flops_looplm.train_step_flops(model, train["batch"],
+                                        train["seq_len"])
+    dense = 6 * 4 * (8 * 51_380_224 + 100_663_296) * 8192
+    attention = 3 * 2 * 2 * 2 * 16 * 4096 ** 2 * 128 * 8 * 4 / 2
+    assert got == dense + attention
+    # the issue's arithmetic
+    assert dense == pytest.approx(1.006e14, rel=1e-3)
+    assert attention == pytest.approx(1.32e13, rel=2e-3)
+    assert got / 197e12 == pytest.approx(0.578, rel=2e-3)
+
+
+def test_configuration_file_holds_the_published_widths():
+    config = load(BENCH, "configs", "ouro-2.6b-l8.json")
+    model = config["model"]
+    published = dict(hidden_size=2048, intermediate_size=5632, head_dim=128,
+                     num_attention_heads=16, num_key_value_heads=16,
+                     vocab_size=49152, total_ut_steps=4, rope_theta=1000000,
+                     rms_norm_eps=1e-06, max_position_embeddings=65536,
+                     max_window_layers=48, early_exit_threshold=1,
+                     hidden_act="silu", tie_word_embeddings=False)
+    assert {k: config[k] for k in published} == published
+    assert config["reduced"] == ["num_hidden_layers"]
+    assert config["num_hidden_layers"] == model["n_layers"] == 8 >= 6
+    assert config["published"]["num_hidden_layers"] == 48 \
+        == len(config["layer_types"])
+    assert (model["embed"], model["ffn"], model["head_dim"],
+            model["n_heads"], model["vocab"], model["loop_steps"],
+            model["rope_theta"]) == (
+        config["hidden_size"], config["intermediate_size"],
+        config["head_dim"], config["num_attention_heads"],
+        config["vocab_size"], config["total_ut_steps"],
+        config["rope_theta"])
+    shapes = jax.eval_shape(lambda: init_transformer(
+        jax.random.key(0), TransformerConfig(**model)))
+    count = sum(int(np.prod(a.shape)) for a in shapes.values())
+    assert count == config["parameters"]["total"] == 612_438_017 \
+        == config["memory"]["taken"]["parameters"]
+    per_layer = sum(int(np.prod(a.shape)) for n, a in shapes.items()
+                    if n.startswith("L0.") and a.ndim > 1)
+    assert per_layer == config["parameters"]["per_layer_matrices"]
+    for depth in ("taken", "refused"):
+        total = config["memory"][depth]
+        assert total["arguments_gb"] + total["temporaries_gb"] == \
+            pytest.approx(total["total_gb"]) and total["total_gb"] < 15.5
+        assert total["arguments_gb"] == pytest.approx(
+            12 * total["parameters"] / 1e9, abs=2e-3)   # p, mu, nu
+
+
+def toy_cell():
+    from benchmark import run
+
+    manifest, _entry, cell, config = run.load_cell("train-ouro-4k")
+    config["model"].update({k: MODEL[k] for k in (
+        "vocab", "embed", "n_layers", "n_heads", "head_dim", "ffn",
+        "loss_block", "loop_steps")})
+    # float32 compute: the kind's limits are set for means over the
+    # cell's 8,192 positions, and over the toy's 256 bfloat16 rounding
+    # alone reads past them (1.2e-4 on the objective against 1e-4)
+    config["model"]["dtype"] = jnp.float32
+    config["train"].update(seq_len=128, reference_block=32)
+    return manifest, cell, config
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_kind_loop_yields_the_contracts_object(traced):
+    from benchmark import run
+
+    manifest, cell, config = toy_cell()
+    result = run.measure(manifest, cell, config, seed=2**31 + 28,
+                         seconds=0.5, traced=traced,
+                         devices=jax.devices()[:1], t_start=time.monotonic())
+    assert set(result) == {"correct", "attempted", "failed", "metrics",
+                           "device"}
+    assert result["correct"] is True and result["failed"] == 0
+    # no TPU plane in a CPU trace and no peak for a CPU: the trace's
+    # metrics and the mfu are left out of the line
+    assert set(result["metrics"]) == (
+        {"looplm.step_ms"} if traced else {"train_tok_rate", "setup_s"})
+    json.dumps(result)
+
+
+def test_stage_report_reads_the_waiting_files_of_the_cell():
+    from benchmark import stage_report, stages
+
+    _manifest, cell, config = toy_cell()
+    waiting = {m["name"] for m in stage_report.metric_files("train-ouro-4k")
+               if any(g in m["read"] for g in stages.READ_GROUPS)}
+    assert waiting == {"looplm." + n for n in (
+        "loss_share", "update_share", "ffn_share", "attn_proj_share",
+        "rope_share", "exit_gate_share", "unscoped_share", "dispatch_ms",
+        "pass_loop_share", "final_norm_share")}
+    result = stage_report.report(cell, config, seed=2**31 + 29,
+                                 devices=jax.devices()[:1])
+    assert result["correct"] is True
+    assert set(result["metrics"]) == {"looplm.step_ms", "looplm.dispatch_ms"}
+
+
+def test_kind_faults_name_each_limit():
+    from benchmark.kinds import looped_trainer as kind
+
+    _manifest, cell, config = toy_cell()
+    c = kind.Cell(config, cell, 5, jax.devices()[:1])
+    assert len(list(c.faults())) == len(kind.LIMITS) == 6   # none compared
+    c.gaps = dict.fromkeys(kind.LIMITS, 0.0)
+    assert not list(c.faults())
+    for name, limit in kind.LIMITS.items():
+        c.gaps = dict(dict.fromkeys(kind.LIMITS, 0.0), **{name: 2 * limit})
+        (fault,) = c.faults()
+        assert name in fault
+    c.gaps = dict.fromkeys(kind.LIMITS, 0.0)
+    c.mass_sum_gap = 1e-3
+    (fault,) = c.faults()
+    assert "sum to 1" in fault
+
+
+@functools.lru_cache(maxsize=None)
+def control_readings():
+    from benchmark import looplm_controls
+
+    _manifest, cell, config = toy_cell()
+    return looplm_controls.controls(cell, config, seed=2**31 + 30,
+                                    devices=jax.devices()[:1])
+
+
+@pytest.mark.parametrize("reading, limits", [
+    ("trainer", ()),
+    ("float8_e4m3fn", ("pass_loss", "gradient")),
+    ("unchanged", ("gradient", "update", "update_rule"))])
+def test_controls_read_through_faults(reading, limits):
+    """The step-0 comparison lets the trainer through and neither the
+    reference in 8-bit operands nor a state the step left unchanged,
+    which reads 1 in all three numbers taken after the update."""
+    got = control_readings()[reading]
+    assert set(got["gaps"]) == {"objective", "pass_loss", "exit_mass",
+                                "gradient", "update", "update_rule"}
+    assert bool(got["faults"]) == bool(limits)
+    for name in limits:
+        assert any(f"step-0 {name} " in f for f in got["faults"])
+    if reading == "unchanged":
+        for name in limits:
+            assert got["gaps"][name] == pytest.approx(1.0, abs=1e-6)
+
+
+def test_adamw_first_step_is_optax_adamws():
+    """The reference writes AdamW's first step out; optax's, from fresh
+    moments, adds the same to a parameter."""
+    import optax
+    from benchmark import reference_looplm
+
+    hyper = dict(learning_rate=3e-3, b1=0.9, b2=0.95, eps=1e-8,
+                 weight_decay=0.1)
+    p = jax.random.normal(jax.random.key(1), (64, 32))
+    g = 1e-3 * jax.random.normal(jax.random.key(2), (64, 32))
+    tx = optax.adamw(**hyper)
+    updates, _ = tx.update(g, tx.init(p), p)
+    mine = reference_looplm.adamw_first_step(p, g, **hyper)
+    assert np.abs(np.asarray(mine - updates)).max() <= 1e-8   # of 3e-3
+    assert float(jnp.abs(mine).max()) > 1e-3
+
+
+def test_reference_gradients_recompute_without_changing_a_number():
+    from benchmark import reference_looplm
+
+    params = seeded_params(TransformerConfig(**MODEL))
+    (obj, losses, masses), grads = jax.jit(
+        lambda p: reference_looplm.reference_gradients(
+            p, TOKENS[:, :-1], TOKENS[:, 1:], **REFERENCE))(params)
+    want = reference()
+    assert float(obj) == pytest.approx(want[0], rel=1e-6)
+    assert np.allclose(losses, want[1], rtol=1e-6)
+    assert np.allclose(masses, want[2], atol=1e-7)
+    for n, g in want[3].items():
+        assert np.abs(np.asarray(grads[n]) - g).max() \
+            <= 1e-5 * np.abs(g).max(), n

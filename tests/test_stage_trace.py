@@ -30,6 +30,10 @@ WAVE_SCOPES = ["wave.map", "wave.combine", "wave.append", "wave.local",
                "sur.compact"]
 TF_SCOPES = ["tf.embed", "tf.attn_proj", "tf.flash", "tf.ffn", "tf.loss",
              "tf.update"]
+#: scopes only a looped configuration's program has (PR 28;
+#: tests/test_looplm.py finds each in that program's stage map)
+LOOP_SCOPES = ["tf.rope", "tf.exit_gate", "tf.pass_loop",
+               "tf.final_norm"]
 
 
 def load(*parts):
@@ -462,7 +466,7 @@ def test_metric_file_has_a_reader_and_waits_if_run_py_has_none(name):
     assert d["source"] == ("program_span" if mine == ["program_span"]
                            else "device_trace")
     if "stage" in d["read"] and d["read"]["stage"] != stages.UNSCOPED:
-        assert d["read"]["stage"] in WAVE_SCOPES + TF_SCOPES
+        assert d["read"]["stage"] in WAVE_SCOPES + TF_SCOPES + LOOP_SCOPES
     for cell in d["workloads"]:
         assert d in stage_report.metric_files(cell)
 
