@@ -300,9 +300,12 @@ def bench_longctx(mesh):
     flash kernel's O(block²) score memory + sequence-chunked loss;
     README's long-context story as a runnable number — same context
     length whatever the mesh, so the metric compares across machines).
-    No rematerialisation: with the kernel, activations fit at 32K and
-    remat costs 30% (measured 17.1k vs 12.8k tok/s); remat=True remains
-    the knob that reaches 65K/128K single-chip."""
+    No rematerialisation: with the kernel, activations fit at 32K
+    (9.2 GB of a v5e's 16, PERF.md section 4).  remat=True keeps a
+    layer's input and the kernel's output and row statistics and runs
+    the rest of the layer's forward again in the backward pass; what
+    that costs at this shape, and whether it reaches 65K/128K on one
+    chip: not measured on current hardware."""
     from mapreduce_tpu.models.transformer import TransformerConfig
 
     cfg = TransformerConfig(

@@ -61,6 +61,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -403,6 +404,14 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
     return dq, dk, dv
 
 
+#: names the forward rule puts on its two results, for a caller's
+#: ``jax.checkpoint(policy=save_only_these_names(*KEPT_NAMES))`` to keep
+#: them across the backward pass in place of a second ``flash_fwd``
+#: call (models/transformer.py, the ``remat`` branch); where no policy
+#: lists them a name is the identity
+KEPT_NAMES = ("flash_out", "flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash_lse(q, k, v, cfgt):
     return _fwd_call(q, k, v, cfgt)
@@ -410,7 +419,17 @@ def _flash_lse(q, k, v, cfgt):
 
 def _flash_lse_fwd(q, k, v, cfgt):
     out, lse = _fwd_call(q, k, v, cfgt)
-    return (out, lse), (q, k, v, out, lse)
+    # named BEFORE they leave both as outputs and as residuals, so that
+    # the residuals ARE the named values: a name put on the result
+    # outside the custom_vjp names another variable, and the
+    # recomputation would still run the kernel to make these.  The row
+    # statistics are a residual as dense [B, H, T]: the kernel's
+    # [B, H, T, 1] is padded to 128 lanes in HBM (67 MB where this is
+    # 0.5, at B2 H16 T4096; compiled for a described v5e, PR 29).  Where
+    # nothing keeps them the compiler folds the two reshapes away
+    out = checkpoint_name(out, KEPT_NAMES[0])
+    rows = checkpoint_name(lse[..., 0], KEPT_NAMES[1])
+    return (out, rows[..., None]), (q, k, v, out, rows)
 
 
 def _flash_lse_bwd(cfgt, res, cots):
@@ -419,9 +438,9 @@ def _flash_lse_bwd(cfgt, res, cots):
     i.e. the kernels run unchanged with delta' = delta - dlse.  (dv has
     no lse term: lse is independent of V.)  flash_attention discards
     lse, so its dlse arrives as zeros and the fold is a no-op there."""
-    q, k, v, out, lse = res
+    q, k, v, out, rows = res
     do, dlse = cots
-    return _bwd_call(q, k, v, out, lse, do, cfgt, dlse=dlse)
+    return _bwd_call(q, k, v, out, rows[..., None], do, cfgt, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -446,7 +465,10 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``(out, lse [B, H, T, 1] f32)`` — the partial-softmax form ring
     attention needs to combine per-ring-step results across devices
     (parallel/ring.py); fully differentiable including through uses of
-    lse.  Same tiling/auto-shrink rules as :func:`flash_attention`."""
+    lse.  Same tiling/auto-shrink rules as :func:`flash_attention`, and
+    the same names on its residuals: a caller that keeps them by name
+    keeps ``n_data`` partial outputs a layer, so the trainer lists the
+    names only on the local path (models/transformer.py)."""
     cfgt = _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret)
     return _flash_lse(q, k, v, cfgt)
 
@@ -463,6 +485,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     the Pallas interpreter off-TPU (the CPU test mesh) and the compiled
     Mosaic kernel on TPU.  Block sizes shrink to T when T is smaller;
     T must divide by the (shrunk) blocks.
+
+    The two residuals the backward kernels read beside q, k, v — the
+    output and the row statistics — carry :data:`KEPT_NAMES`, so that a
+    caller's ``jax.checkpoint(policy=save_only_these_names(*KEPT_NAMES))``
+    keeps them and its recomputation runs no forward kernel.
     """
     if layout == "bthd":
         q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))

@@ -15,6 +15,7 @@ from mapreduce_tpu.ops.flash_attention import (flash_attention,
                                                flash_attention_lse)
 from mapreduce_tpu.parallel import make_mesh
 from mapreduce_tpu.parallel.ring import full_attention_reference
+from tests.kernel_calls import eqns, kernel_calls
 
 
 def _qkv(B=2, T=256, H=3, D=16, dtype=jnp.float32):
@@ -293,18 +294,6 @@ def test_backward_grid_shapes(grid, causal, dtype, use_lse, acc_tiles,
             err_msg=f"d{name} mismatch ({grid}, causal={causal})")
 
 
-def _eqns(jaxpr):
-    """Every equation of *jaxpr*, those of nested jaxprs (custom_vjp
-    bodies, kernel bodies, branches) included."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for param in eqn.params.values():
-            for sub in param if isinstance(param, (list, tuple)) else [param]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
-
-
 def test_backward_visits_each_tile_once():
     """Structure, not numbers: a traced backward holds three kernel
     programs, and the products sit where the one-pass design puts them —
@@ -323,15 +312,107 @@ def test_backward_visits_each_tile_once():
         lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, causal=False, block_q=64, block_kv=64)),
         argnums=(0, 1, 2)))(q, k, v)
-    kernels = [e for e in _eqns(jaxpr.jaxpr)
+    kernels = [e for e, _ in eqns(jaxpr.jaxpr)
                if e.primitive.name == "pallas_call"]
     dots = {e.params["name"]: sum(
         inner.primitive.name == "dot_general"
-        for inner in _eqns(e.params["jaxpr"])) for e in kernels}
+        for inner, _ in eqns(e.params["jaxpr"])) for e in kernels}
     assert len(kernels) == 3, [e.params["name"] for e in kernels]
     assert dots == {"flash_fwd": 2, "flash_dkv": 5, "flash_dq": 0}
     for name, was in before.items():
         assert builds(name) == was + 1, name
+
+
+# -- what a checkpoint policy can keep of the kernel (PR 29) -----------------
+
+#: entry point -> (calls the kernel, names the caller's policy lists,
+#: flash_fwd calls in the gradient under that policy).  Both entry
+#: points name the same two residuals; what is kept is the policy's
+_NAMING = {
+    # local attention, as the trainer wraps it with the kernel on: the
+    # output and the row statistics are kept, and the recomputation
+    # runs no forward kernel
+    "local": (lambda q, k, v: flash_attention(q, k, v, interpret=True),
+              fa.KEPT_NAMES, 1),
+    # the ring's entry point, as the trainer wraps the ring path: it
+    # makes n_data partial outputs a layer, keeping those multiplies
+    # the bytes, so the policy lists no name.  Nothing is kept, as under
+    # the bare checkpoint before PR 29, and the recomputation runs the
+    # kernel again
+    "ring-lse": (lambda q, k, v: flash_attention_lse(
+        q, k, v, interpret=True)[0], (), 2),
+}
+
+
+def _under_policy(entry):
+    call, listed, _ = _NAMING[entry]
+    return jax.checkpoint(
+        lambda q, k, v: call(q, k, v).astype(jnp.float32).sum(),
+        policy=jax.checkpoint_policies.save_only_these_names(*listed))
+
+
+@pytest.mark.parametrize("entry", sorted(_NAMING))
+def test_residual_names_by_entry_point(entry):
+    """Which of the kernel's residuals a naming policy keeps: the two
+    named ones in their dense shapes where it lists them, and nothing
+    where it lists none."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    B, H, T, D = 1, 2, 64, 16
+    q, k, v = (a.swapaxes(1, 2) for a in _qkv(B=B, T=T, H=H, D=D))
+    kept, names = [], set()
+    for aval, why in saved_residuals(_under_policy(entry), q, k, v):
+        if "from the argument" in why:
+            continue                                    # q, k, v
+        kept.append(aval.shape)
+        if "named" in why:
+            names.add(why.split("'")[1])
+    listed = set(_NAMING[entry][1])
+    if not listed:
+        assert kept == [] and names == set()
+        return
+    # the row statistics are kept dense, without the kernel's unit minor
+    # dimension (padded to 128 lanes in a TPU's memory).  The output is
+    # also the checkpointed function's result here, and such a residual
+    # is listed as the no-op jax puts behind it, not by its name
+    assert sorted(kept) == [(B, H, T), (B, H, T, D)]
+    assert {fa.KEPT_NAMES[1]} <= names <= listed
+
+
+@pytest.mark.parametrize("entry", sorted(_NAMING))
+def test_forward_kernel_calls_in_a_checkpointed_gradient(entry):
+    """The gradient of a checkpointed call holds ONE forward kernel when
+    its residuals are named and kept, two when they are not (the
+    recomputation makes them again); the backward kernels run once."""
+    q, k, v = (a.swapaxes(1, 2) for a in _qkv(B=1, T=64, H=2, D=16))
+    jaxpr = jax.make_jaxpr(jax.grad(_under_policy(entry), argnums=(0, 1, 2))
+                           )(q, k, v)
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert calls == {"flash_fwd": _NAMING[entry][2], "flash_dkv": 1,
+                     "flash_dq": 1}
+
+
+@pytest.mark.parametrize("wrapping", ["policy", "bare"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_named_residuals_leave_the_gradient_bit_identical(causal, wrapping):
+    """Naming changes which values are kept, not one bit of a result:
+    under the keeping policy and under a bare checkpoint (which
+    recomputes them), against the gradient with no checkpoint."""
+    q, k, v = (a.swapaxes(1, 2) for a in _qkv(B=1, T=128, H=2, D=16))
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, causal=causal, interpret=True,
+                                block_q=64, block_kv=64) * w).sum()
+
+    f = {"policy": jax.checkpoint(
+             loss, policy=jax.checkpoint_policies.save_only_these_names(
+                 *fa.KEPT_NAMES)),
+         "bare": jax.checkpoint(loss)}[wrapping]
+    want = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 # -- hardware-gated: the compiled Mosaic path -------------------------------
