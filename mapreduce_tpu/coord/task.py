@@ -23,7 +23,7 @@ import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils.constants import (
-    STATUS, TASK_STATUS, DEFAULT_JOB_LEASE, MAX_IDLE_COUNT)
+    STATUS, TASK_STATUS, DEFAULT_JOB_LEASE, MAX_IDLE_COUNT, MAX_JOB_RETRIES)
 from . import docstore
 from .connection import Connection
 
@@ -205,8 +205,14 @@ class Task:
         if st in (TASK_STATUS.WAIT, TASK_STATUS.FINISHED):
             return [], st
         coll = self.jobs_ns()
-        claimable = {"status": {"$in": [int(STATUS.WAITING),
-                                        int(STATUS.BROKEN)]}}
+        # a BROKEN job at its retry cap is the server's to promote to
+        # FAILED (Server._poll_phase), not a worker's to run again:
+        # handed out on, a job that always fails is retried faster than
+        # the server polls, ends every worker of the pool
+        # (MAX_WORKER_RETRIES each), and the task waits for ever
+        retryable = {"status": int(STATUS.BROKEN),
+                     "repetitions": {"$lt": MAX_JOB_RETRIES}}
+        claimable = {"$or": [{"status": int(STATUS.WAITING)}, retryable]}
         queries: List[Dict[str, Any]] = []
         if (st == TASK_STATUS.MAP and self.iteration() > 1
                 and self._cached_map_ids):
@@ -214,7 +220,7 @@ class Task:
                 # prefer jobs whose output this host already has locally
                 queries.append({**claimable,
                                 "_id": {"$in": self._cached_map_ids}})
-                queries.append({"status": int(STATUS.BROKEN)})
+                queries.append(retryable)
             else:
                 queries.append(claimable)
         else:

@@ -21,9 +21,14 @@ from typing import Any, Dict, List
 _conf: Dict[str, Any] = {"blobs": [], "num_reducers": 4, "storage": None}
 RESULT: Dict[str, int] = {}
 
-associative_reducer = True
-commutative_reducer = True
-idempotent_reducer = True
+# Declared NOT associative/commutative/idempotent: the reduce then calls
+# reducefn for every key (job.lua:264-284 skips it for a key with one
+# value otherwise, and every cold word has one), so a delayed worker's
+# reduce of a cold partition is slow too and its median job is a delayed
+# one whichever jobs it happens to claim.
+associative_reducer = False
+commutative_reducer = False
+idempotent_reducer = False
 
 
 def _injected_delay() -> None:

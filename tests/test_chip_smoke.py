@@ -37,7 +37,7 @@ def test_import_touches_no_device():
     code = ("import sys, chip_smoke; "
             "assert 'jax' not in sys.modules, 'import pulled in jax'")
     proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                          capture_output=True, text=True, timeout=120)
+                          capture_output=True, text=True, timeout=90)
     assert proc.returncode == 0, proc.stderr[-2000:]
 
 
@@ -79,7 +79,7 @@ def test_fails_without_the_program(tmp_path):
     shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
-                          capture_output=True, text=True, timeout=120,
+                          capture_output=True, text=True, timeout=90,
                           env=env)
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""

@@ -7,9 +7,6 @@ launched with a per-job sleep) and the injected key skew (every hot*
 word routed to partition P00000 by tests/skew_mods.py)."""
 
 import json
-import os
-import subprocess
-import sys
 import time
 import uuid
 
@@ -21,8 +18,7 @@ from mapreduce_tpu.obs import analysis
 from mapreduce_tpu.obs.profile import validate_trace
 from mapreduce_tpu.server import Server
 from mapreduce_tpu.storage import BlobServer
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests.cli_workers import child_env, cli_workers
 
 N_SPLITS = 8
 N_REDUCERS = 4
@@ -42,19 +38,14 @@ def fresh_modules():
     spec.clear_caches()
 
 
-def _spawn_worker(connstr, name, env):
-    return subprocess.Popen(
-        [sys.executable, "-m", "mapreduce_tpu.cli", "worker",
-         connstr, "skw", "--name", name, "--max-iter", "400",
-         # claim-batch 1 + no claim-ahead keep each job span a clean
-         # per-job claim->write interval: a batch's later jobs backdate
-         # to the batch claim, and a prefetched claim backdates to
-         # BEFORE the previous job finished — both are queueing, not
-         # execution, and both inflate the fast worker's median enough
-         # to mask the injected straggler under the ratio test
-         "--claim-batch", "1", "--no-claim-ahead",
-         "--telemetry-interval", "0.1"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+# claim-batch 1 + no claim-ahead keep each job span a clean per-job
+# claim->write interval: a batch's later jobs backdate to the batch
+# claim, and a prefetched claim backdates to BEFORE the previous job
+# finished — both are queueing, not execution, and both inflate the fast
+# worker's median enough to mask the injected straggler under the ratio
+# test
+WORKER_ARGS = ("--claim-batch", "1", "--no-claim-ahead",
+               "--telemetry-interval", "0.1")
 
 
 def test_three_process_timeline_and_diagnosis(tmp_path, capsys):
@@ -76,15 +67,12 @@ def test_three_process_timeline_and_diagnosis(tmp_path, capsys):
         st.write(name, text)
         blobs.append(name)
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env_slow = dict(env)
-    env_slow["MRTPU_SKEW_DELAY"] = str(STRAGGLE_S)
-
-    p_fast = _spawn_worker(connstr, "wfast", env)
-    p_slow = _spawn_worker(connstr, "wslow", env_slow)
-    try:
+    with cli_workers(
+            connstr, "skw", 2,
+            args=[("--name", "wfast", *WORKER_ARGS),
+                  ("--name", "wslow", *WORKER_ARGS)],
+            envs=[child_env(),
+                  child_env(MRTPU_SKEW_DELAY=str(STRAGGLE_S))]) as workers:
         m = "tests.skew_mods"
         params = {r: m for r in ("taskfn", "mapfn", "partitionfn",
                                  "reducefn", "finalfn")}
@@ -97,17 +85,7 @@ def test_three_process_timeline_and_diagnosis(tmp_path, capsys):
         t_loop0 = time.monotonic()
         stats = server.loop()
         t_loop1 = time.monotonic()
-    finally:
-        rcs = []
-        for pr in (p_fast, p_slow):
-            try:
-                rcs.append(pr.wait(timeout=90))
-            except subprocess.TimeoutExpired:
-                pr.kill()
-                rcs.append("killed")
-    assert rcs == [0, 0], [
-        (rc, pr.stderr.read().decode()[-400:])
-        for rc, pr in zip(rcs, (p_fast, p_slow))]
+    assert workers.rcs == [0, 0], workers.tails()
     assert stats["map"]["failed"] == 0
     from tests.skew_mods import RESULT
     assert set(RESULT) == expected_uniques

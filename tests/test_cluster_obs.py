@@ -8,7 +8,6 @@ import os
 import signal
 import subprocess
 import sys
-import threading
 import time
 import uuid
 
@@ -24,8 +23,7 @@ from mapreduce_tpu.obs.profile import validate_trace
 from mapreduce_tpu.obs.trace import TRACER, Tracer
 from mapreduce_tpu.server import Server
 from mapreduce_tpu.worker import spawn_worker_threads
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tests.cli_workers import child_env, cli_workers
 
 
 @pytest.fixture(autouse=True)
@@ -394,54 +392,18 @@ def test_diagnose_falls_back_to_job_seconds_metrics():
 
 # -- flight recorder ---------------------------------------------------------
 
-def _wait_for_line(stream, needle, timeout=30.0):
-    found = threading.Event()
-
-    def reader():
-        for raw in stream:
-            if needle in raw:
-                found.set()
-                return
-
-    t = threading.Thread(target=reader, daemon=True)
-    t.start()
-    assert found.wait(timeout), f"never saw {needle!r} in child stderr"
-
-
-def _worker_cmd(tmp_path, trace_out, max_iter):
-    return [sys.executable, "-m", "mapreduce_tpu.cli", "worker",
-            f"dir://{tmp_path}/board", "flightdb",
-            "--max-iter", str(max_iter), "--trace-out", str(trace_out)]
-
-
-def _child_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    return env
-
-
 def test_flight_recorder_dumps_on_sigterm(tmp_path):
     """A SIGTERM'd worker must leave its telemetry behind: the flight
     trace parses as a Chrome trace, the metrics snapshot parses as
     Prometheus text, and the exit code is the conventional 143."""
     trace_out = tmp_path / "w.trace.json"
-    proc = subprocess.Popen(
-        _worker_cmd(tmp_path, trace_out, max_iter=2000),
-        env=_child_env(), stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE, text=True)
-    try:
-        # the worker logs its start at INFO before entering the poll
-        # loop; SIGTERM before that could beat the handler install
-        _wait_for_line(proc.stderr, "starting")
+    # the worker logs its start at INFO before entering the poll loop;
+    # SIGTERM before that could beat the handler install
+    with cli_workers(f"dir://{tmp_path}/board", "flightdb", 1,
+                     args=[("--trace-out", str(trace_out))]) as workers:
         time.sleep(0.2)
-        proc.send_signal(signal.SIGTERM)
-        rc = proc.wait(timeout=30)
-    finally:
-        if proc.poll() is None:
-            proc.kill()
-            proc.wait(timeout=10)
-    assert rc == 143, rc
+        workers.procs[0].send_signal(signal.SIGTERM)
+    assert workers.rcs == [143], workers.tails()
     flight_trace = str(trace_out) + ".flight.trace.json"
     flight_metrics = str(trace_out) + ".flight.metrics.prom"
     assert os.path.exists(flight_trace), "flight trace missing"
@@ -461,8 +423,10 @@ def test_flight_recorder_silent_on_normal_exit(tmp_path):
     flight files' absence is what makes their presence a signal."""
     trace_out = tmp_path / "n.trace.json"
     proc = subprocess.run(
-        _worker_cmd(tmp_path, trace_out, max_iter=1),
-        env=_child_env(), capture_output=True, timeout=60)
+        [sys.executable, "-m", "mapreduce_tpu.cli", "worker",
+         f"dir://{tmp_path}/board", "flightdb",
+         "--max-iter", "1", "--trace-out", str(trace_out)],
+        env=child_env(), capture_output=True, timeout=60)
     assert proc.returncode == 0, proc.stderr[-500:]
     assert os.path.exists(trace_out)
     assert not os.path.exists(str(trace_out) + ".flight.trace.json")

@@ -83,7 +83,8 @@ def test_find_and_modify_atomic_claim(store):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     assert sorted(claimed) == sorted(f"j{i}" for i in range(20))
     assert len(set(claimed)) == 20
 

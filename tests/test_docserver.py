@@ -79,8 +79,9 @@ def test_concurrent_retry_waits_for_inflight_original():
         t1.start()
         time.sleep(0.1)  # original is mid-update when the duplicate lands
         t2.start()
-        t1.join()
-        t2.join()
+        t1.join(timeout=30)
+        t2.join(timeout=30)
+        assert not (t1.is_alive() or t2.is_alive())
         assert [r["ok"] for r in replies] == [True, True]
         assert srv.store.find_one("c", {"_id": "a"})["n"] == 1  # applied once
     finally:

@@ -12,7 +12,8 @@ from mapreduce_tpu import spec
 from mapreduce_tpu.examples import naive
 from mapreduce_tpu.server import Server
 from mapreduce_tpu.worker import spawn_worker_threads
-from mapreduce_tpu.utils.constants import STATUS, TASK_STATUS
+from mapreduce_tpu.utils.constants import (
+    MAX_JOB_RETRIES, STATUS, TASK_STATUS)
 from tests import faulty_mods
 
 M = "tests.faulty_mods"
@@ -75,6 +76,37 @@ def test_permanent_failure_becomes_FAILED_and_phase_completes(corpus):
     oracle = naive.wordcount([f for i, f in enumerate(corpus) if i != 2])
     assert faulty_mods.RESULT == oracle
     assert f"f2" not in faulty_mods.RESULT
+
+
+def test_a_job_that_always_fails_does_not_end_the_whole_pool(corpus):
+    """The server polls slower than the workers retry, as on a loaded
+    machine: the job at its retry cap waits for the server to fail it, it
+    is not handed out again.  Handed out on (to 9 repetitions), it took
+    every worker's MAX_WORKER_RETRIES between two polls and loop() never
+    returned: the case above hung the tier-1 run that way."""
+    import threading
+
+    faulty_mods.reset(corpus, always_fail_key=2)
+    connstr = f"mem://{uuid.uuid4().hex}"
+    threads = spawn_worker_threads(connstr, "ft2s", 3)
+    server = Server(connstr, "ft2s")
+    server.configure(_params(corpus))
+    server.poll_sleep = 0.5
+    stats = {}
+    t = threading.Thread(target=lambda: stats.update(server.loop()),
+                         daemon=True)
+    t.start()
+    t.join(timeout=30)
+    docs = {d["_id"]: d for d in
+            server.cnn.connect().find(server.task.map_jobs_ns())}
+    assert not t.is_alive(), (
+        "loop() still waits", [th.is_alive() for th in threads], docs)
+    assert docs["2"]["status"] == int(STATUS.FAILED)
+    assert docs["2"]["repetitions"] == MAX_JOB_RETRIES
+    assert stats["map"]["failed"] == 1
+    assert "f2" not in faulty_mods.RESULT
+    for th in threads:
+        th.join(timeout=30)
 
 
 def test_dead_worker_lease_reaped_end_to_end(corpus):

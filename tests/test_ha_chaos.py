@@ -174,9 +174,9 @@ def test_sigkill_primary_mid_stream(tmp_path):
         # release the pinned job only now: its heartbeat/claim traffic
         # provably spanned the failover
         chaos_mods.HOLD.set()
-        driver.join(timeout=120)
+        driver.join(timeout=60)
         assert "stats" in stats_box, "server.loop did not finish"
-        _wait(lambda: feeder.get("done"), 120,
+        _wait(lambda: feeder.get("done"), 60,
               "session feed loop did not finish")
 
         # exactly-once witness across the failover: every job STARTED

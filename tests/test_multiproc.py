@@ -10,8 +10,7 @@ import os
 import socket
 import subprocess
 import sys
-
-import pytest
+import time
 
 
 def _free_port() -> int:
@@ -20,6 +19,31 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+def _outputs(procs, seconds=90.0):
+    """The children's outputs, under ONE bound for all of them together
+    (they ran in 15 s here), and none of them left alive."""
+    deadline = time.monotonic() + seconds
+    outs, late = [], False
+    try:
+        for p in procs:
+            try:
+                out, _ = p.communicate(
+                    timeout=max(deadline - time.monotonic(), 0.1))
+            except subprocess.TimeoutExpired:
+                late = True
+                p.kill()
+                out, _ = p.communicate(timeout=10)
+            outs.append(out)
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.wait(timeout=10)
+    assert not late, (f"children not done in {seconds:g} s:\n"
+                      + "\n--- next child ---\n".join(outs))
+    return outs
 
 
 def test_two_process_mesh_runs_engine_and_trainstep():
@@ -39,15 +63,7 @@ def test_two_process_mesh_runs_engine_and_trainstep():
             env=env, cwd=repo)
         for i in range(2)
     ]
-    outs = []
-    try:
-        for p in procs:
-            out, _ = p.communicate(timeout=540)
-            outs.append(out)
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
+    outs = _outputs(procs)
     for i, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"process {i} failed:\n{out}"
         assert "MARKER devices global=8 local=4" in out, out
@@ -98,15 +114,7 @@ def test_combined_topology_distributed_engine_over_http_planes():
                 text=True, env=env, cwd=repo)
             for i in range(2)
         ]
-        outs = []
-        try:
-            for p in procs:
-                out, _ = p.communicate(timeout=540)
-                outs.append(out)
-        finally:
-            for p in procs:
-                if p.poll() is None:
-                    p.kill()
+        outs = _outputs(procs)
         for i, (p, out) in enumerate(zip(procs, outs)):
             assert p.returncode == 0, f"process {i} failed:\n{out}"
             assert "MARKER devices global=8 local=4" in out, out

@@ -122,7 +122,8 @@ def test_thread_safety_under_contention():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     assert c.value(worker="w") == 8000
 
 

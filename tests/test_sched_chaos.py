@@ -150,7 +150,7 @@ def test_admission_keeps_weighted_fairness_under_churn(tmp_path):
         runner = TaskRunner(direct, sch).start()
         workers = [ScheduledWorker(direct, name="fw0").start()]
         # churn the pool while the queue drains
-        give_up = time.monotonic() + 120
+        give_up = time.monotonic() + 90
         churned = 0
         while time.monotonic() < give_up:
             done = [d for d in sch.list_tasks(state=DONE)]

@@ -432,7 +432,7 @@ def test_stage_report_refuses_the_cpu():
         [sys.executable, os.path.join(BENCH, "stage_report.py"),
          "--workload", "train-dense-32k", "--seed", "1"],
         cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=120)
+        capture_output=True, text=True, timeout=90)
     assert proc.returncode == 1
     assert proc.stdout == "" and "needs 1 TPU chip" in proc.stderr
 

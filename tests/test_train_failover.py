@@ -371,7 +371,7 @@ def _child_env():
     return env
 
 
-def _wait_for_line(stream, needle, timeout=90.0):
+def _wait_for_line(stream, needle, timeout=60.0):
     found = threading.Event()
 
     def reader():
@@ -403,7 +403,7 @@ def test_sigterm_trainer_dumps_flight_telemetry(tmp_path):
         # and that at least one checkpoint committed
         _wait_for_line(proc.stderr, "epoch 1:")
         proc.send_signal(signal.SIGTERM)
-        rc = proc.wait(timeout=60)
+        rc = proc.wait(timeout=30)
     finally:
         if proc.poll() is None:
             proc.kill()
