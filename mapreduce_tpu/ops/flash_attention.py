@@ -13,14 +13,21 @@ Kernel layout is ``[B, H, T, D]`` (Mosaic tiling wants the sequence and
 head_dim in the last two block dims); the wrapper accepts the model's
 native ``[B, T, H, D]`` too and transposes, but the transformer feeds
 the kernel layout directly so no transpose is ever materialised.  The
-grid is ``(B, H, T/block_q, T/block_kv)`` — KV innermost, so the
-(m, den, acc) online-softmax state for one Q tile lives in VMEM scratch
-across KV steps while Pallas double-buffers the KV tile DMAs against the
-MXU.  Causal Q tiles skip above-diagonal KV tiles entirely — the index
-map redirects the skipped DMA to the next tile that will be needed (the
-shipped-kernel trick), so neither FLOPs nor bytes are wasted.  Score
-memory is O(block_q x block_kv) whatever T is, so the same kernel serves
-the 2048-token bench and the 32K long-context config.
+grid is ``(B, H, needed tiles)``: its innermost axis counts the (Q tile,
+KV tile) pairs the attention needs and nothing else, Q tile by Q tile
+with KV ascending, so the (m, den, acc) online-softmax state for one Q
+tile lives in VMEM scratch across its KV steps while Pallas
+double-buffers the KV tile DMAs against the MXU.  Which pair a step is
+stands in small int32 tables made at trace time from the shapes and
+``causal`` (``_fwd_tables``, ``_bwd_tables``) and handed to the kernel
+by scalar prefetch: index maps and body read ``qt[t]``, ``kt[t]`` and
+the step's flags.  Causal attention needs 528 of a 32K head's 1,024
+tile pairs, and the grid has 528 steps; non-causal calls (the ring
+path's below-diagonal steps, ``Tq != Tk``) run the same kernels on a
+table that lists every pair.  A mask of another shape (documents,
+windows) is another table.  Score memory is O(block_q x block_kv)
+whatever T is, so the same kernel serves the 2048-token bench and the
+32K long-context config.
 
 Backward is ONE pass over the needed (q tile, kv tile) pairs, wired
 through ``jax.custom_vjp`` with (q, k, v, out, lse) residuals —
@@ -29,13 +36,18 @@ activation memory O(B T H D), never O(T²).  Per tile the scores
 ``dS = P * (dP - delta)`` are computed once and feed all three
 gradients (``dV += P^T dO``, ``dK += dS^T Q^``, ``dQ_i += dS K``): five
 products a tile, where the two-pass recomputation (a dQ pass over KV
-tiles, then a dKV pass over Q tiles) ran seven.  The grid is
-``(B, H, passes, T/block_kv, T/block_q)`` — Q innermost: dK/dV of one KV
-tile live in VMEM scratch across the inner loop, and the float32 dQ of
-EVERY Q tile lives in VMEM scratch across the whole (kv, q) loop (16 MiB
-at T=32768, D=128, of a v5e core's 128), added to in kv order and
-written out on the last kv row.  lse/delta ride as ``[B, H, T, 1]`` so
-their tiles obey lane tiling without 128x replication.
+tiles, then a dKV pass over Q tiles) ran seven.  The grid is again
+``(B, H, needed tiles)``, here KV tile by KV tile with Q ascending from
+the row's first needed Q tile: dK/dV of one KV tile live in VMEM scratch
+across its row, and the float32 dQ of EVERY Q tile lives in VMEM scratch
+across the whole loop (16 MiB at T=32768, D=128, of a v5e core's 128),
+added to in kv order; a Q tile is written out at the step of its last
+contribution (causal with square tiles: the diagonal step, which opens
+its KV row), through an output block whose index comes from a table and
+moves only after such a step, so each block leaves once and complete.
+A KV tile no Q tile needs (causal, ``Tk > Tq``) keeps one step that
+writes its zero dK, dV.  lse/delta ride as ``[B, H, T, 1]`` so their
+tiles obey lane tiling without 128x replication.
 
 The step keeps two backward Pallas programs, and only one of them does
 the work: ``flash_dkv`` is the tile loop above (it emits dK, dV and the
@@ -61,10 +73,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics as _obs
 from .pallas_compat import default_interpret, pallas_call, pick_block, sds
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
@@ -81,6 +95,111 @@ def _on_diag(iq, j, block_q, block_kv):
     return j * block_kv <= iq * block_q + block_q - 1
 
 
+# -- the grid of needed tiles ------------------------------------------------
+# A kernel's innermost grid axis counts (Q tile, KV tile) pairs, and
+# which pair a step is stands in int32 tables made here, at trace time,
+# from the shapes and `causal`.  They reach the kernel by scalar
+# prefetch: the index maps and the body read ``qt[t]``, ``kt[t]``.  A
+# step's flags say what it does beside its tile's products.
+
+_WORK = 1       # the pair is needed: run the tile's products
+_OPENS = 2      # first step of its row: zero the row's scratch
+_CLOSES = 4     # last step of its row: emit the row's output blocks
+_DQ_DONE = 8    # flash_dkv: the Q tile's last contribution, emit its dQ
+
+_GRID_STEPS = _obs.gauge(
+    "mrtpu_flash_grid_steps",
+    "innermost grid steps a head of the flash kernel just traced "
+    "(labels: kernel, kind): kind=steps is the grid axis' length, "
+    "kind=needed how many of them are needed (Q tile, KV tile) pairs; "
+    "the two differ only by the rows no Q tile needs (causal, Tk > Tq, "
+    "or a several-pass backward), which keep one step that writes zeros")
+
+
+def _row_flags(k, n):
+    return (_OPENS if k == 0 else 0) | (_CLOSES if k == n - 1 else 0)
+
+
+def _tables(*columns):
+    """The columns as int32 arrays, and a function that hands them to a
+    trace.  That function is jitted: its results are then values the
+    trace computes, where bare arrays would be constants of the
+    caller's jaxpr, and a ``jax.checkpoint`` around the caller lists
+    every constant its backward pass reads among the residuals it
+    keeps."""
+    columns = tuple(np.asarray(c, np.int32) for c in columns)
+    return columns, jax.jit(lambda: tuple(jnp.asarray(c) for c in columns))
+
+
+def _count_steps(kernel, flags):
+    _GRID_STEPS.set(len(flags), kernel=kernel, kind="steps")
+    _GRID_STEPS.set(int(np.count_nonzero(flags & _WORK)), kernel=kernel,
+                    kind="needed")
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_tables(n_q, n_kv, block_q, block_kv, causal):
+    """``(qt, kt, flags)`` of flash_fwd's grid: the needed pairs, Q tile
+    by Q tile with KV ascending.  A row is a Q tile's: its online-softmax
+    state opens at KV tile 0, which every Q tile needs, and o, lse leave
+    at its last pair (the diagonal tile, or the last KV tile)."""
+    qt, kt, flags = [], [], []
+    for iq in range(n_q):
+        row = [j for j in range(n_kv)
+               if not causal or _on_diag(iq, j, block_q, block_kv)]
+        for n, j in enumerate(row):
+            qt.append(iq)
+            kt.append(j)
+            flags.append(_WORK | _row_flags(n, len(row)))
+    return _tables(qt, kt, flags)
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_tables(n_q, n_kv, block_q, block_kv, causal, q_tiles):
+    """``(qt, kt, pt, dqt, flags)`` of flash_dkv's grid: the needed
+    pairs, KV tile by KV tile with Q ascending from the row's first
+    needed Q tile; with several passes (``q_tiles < n_q``) one pass's
+    rows after the other's, ``pt`` a step's pass.  A row is a KV tile's
+    within a pass: dK, dV open at its first pair and leave at its last.
+    A row that no Q tile of the pass needs keeps one step without
+    ``_WORK``, on the pass's last Q tile (the tile the row before ended
+    on, so nothing is fetched for it), because its dK, dV block must
+    still be written: zeros.
+
+    A Q tile's dQ is complete at its last needed pair (``_DQ_DONE``),
+    and ``dqt[t]`` is the dQ block a step holds: the Q tile that is
+    done next, at or after *t* (past the last one, still that).  So the
+    block index moves only after a step that wrote the block, and every
+    block is held for one run of steps, written once at the run's end,
+    and never leaves before it is complete."""
+    qt, kt, pt, flags = [], [], [], []
+    for c in range(n_q // q_tiles):
+        tiles = range(c * q_tiles, (c + 1) * q_tiles)
+        for j in range(n_kv):
+            row = [iq for iq in tiles
+                   if not causal or _on_diag(iq, j, block_q, block_kv)]
+            work = _WORK if row else 0
+            row = row or [tiles[-1]]
+            for n, iq in enumerate(row):
+                qt.append(iq)
+                kt.append(j)
+                pt.append(c)
+                flags.append(work | _row_flags(n, len(row)))
+    done = {iq: t for t, iq in enumerate(qt) if flags[t] & _WORK}
+    for t in done.values():
+        flags[t] |= _DQ_DONE
+    dqt, held = [], qt[max(done.values())]
+    for t in reversed(range(len(qt))):
+        if flags[t] & _DQ_DONE:
+            held = qt[t]
+        dqt.append(held)
+    return _tables(qt, kt, pt, dqt[::-1], flags)
+
+
+def _flag(flags, bit):
+    return (flags & bit) != 0
+
+
 # -- forward -----------------------------------------------------------------
 
 
@@ -93,33 +212,36 @@ def _crosses_diag(iq, j, block_q, block_kv):
     return j * block_kv + block_kv - 1 > iq * block_q
 
 
-def _dispatch_tile(accum, needed, causal, iq, j, block_q, block_kv):
-    """Run *accum(mask)* under the masked/full split all three kernels
-    share: diagonal-crossing tiles take the masked body, strictly-below
-    tiles the unmasked one, non-causal always unmasked."""
+def _dispatch_tile(accum, work, causal, iq, j, block_q, block_kv):
+    """Run *accum(mask)* under the masked/full split the kernels share:
+    diagonal-crossing tiles take the masked body, strictly-below tiles
+    the unmasked one, non-causal always unmasked.  *work* is the step's
+    ``_WORK`` flag; every non-causal step has it."""
     if not causal:
         accum(False)
         return
     diag = _crosses_diag(iq, j, block_q, block_kv)
 
-    @pl.when(needed & diag)
+    @pl.when(work & diag)
     def _tile_masked():
         accum(True)
 
-    @pl.when(needed & jnp.logical_not(diag))
+    @pl.when(work & jnp.logical_not(diag))
     def _tile_full():
         accum(False)
 
 
-def _fwd_kernel(pids, q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _fwd_kernel(pids, qt_ref, kt_ref, ft_ref,
+                q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, den_scr, acc_scr,
-                *, causal, block_q, block_kv, n_kv):
+                *, causal, block_q, block_kv):
     # q arrives PRE-SCALED by 1/sqrt(D) (see _fwd_call): one elementwise
     # pass over [B,H,T,D] outside replaces a [block_q,block_kv] scale
     # pass in every tile
-    iq, j = pids[2:]
+    t = pids[2]
+    iq, j, flags = qt_ref[t], kt_ref[t], ft_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when(_flag(flags, _OPENS))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         den_scr[...] = jnp.zeros_like(den_scr)
@@ -127,7 +249,6 @@ def _fwd_kernel(pids, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q_start = iq * block_q
     k_start = j * block_kv
-    needed = _on_diag(iq, j, block_q, block_kv) if causal else True
 
     def _accum(mask):
         q = q_ref[0, 0]  # [block_q, D]
@@ -154,12 +275,13 @@ def _fwd_kernel(pids, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:, 0:1] = m_new
         den_scr[:, 0:1] = den
 
-    _dispatch_tile(_accum, needed, causal, iq, j, block_q, block_kv)
+    # every step of this grid is a needed pair
+    _dispatch_tile(_accum, True, causal, iq, j, block_q, block_kv)
 
-    # emit once, on the final KV step (the j-loop keeps (m, den, acc) in
+    # emit once, at the row's last pair (the row keeps (m, den, acc) in
     # VMEM scratch; dividing every step cost a [block_q, D] divide + log
     # per tile for values that never left VMEM)
-    @pl.when(j == n_kv - 1)
+    @pl.when(_flag(flags, _CLOSES))
     def _emit():
         den = jnp.maximum(den_scr[:, 0:1], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / den).astype(o_ref.dtype)
@@ -169,29 +291,31 @@ def _fwd_kernel(pids, q_ref, k_ref, v_ref, o_ref, lse_ref,
 # -- backward: the one-pass tile loop (flash_dkv) ----------------------------
 
 
-def _dkv_kernel(pids, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(pids, qt_ref, kt_ref, pt_ref, dqt_ref, ft_ref,
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dqa_ref, dk_scr, dv_scr, dq_scr,
-                *, causal, block_q, block_kv, n_kv, q_tiles):
+                *, causal, block_q, block_kv, q_tiles):
     # q is pre-scaled, so dK = dS^T . q^ needs NO scale factor at all
     # (dk = dS^T . scale*q exactly); dq^ = dS . k picks scale up in the
     # flash_dq epilogue (chain rule through q^ = scale*q)
-    c, jk, i = pids[2:]
-    iq = c * q_tiles + i        # Q tile in the whole sequence
+    t = pids[2]
+    iq, jk, flags = qt_ref[t], kt_ref[t], ft_ref[t]
+    i = iq - pt_ref[t] * q_tiles    # the Q tile's place in its pass
 
-    @pl.when(i == 0)
+    @pl.when(_flag(flags, _OPENS))
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # every Q tile of the pass is visited at jk == 0 (needed or not), so
-    # the whole accumulator is zeroed before anything adds to it
+    # KV tile 0 is needed by every Q tile, causal or not, and its row
+    # opens a pass: the whole accumulator is zeroed before anything adds
+    # to it
     @pl.when(jk == 0)
     def _init_dq():
         dq_scr[i] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
 
     q_start = iq * block_q
     k_start = jk * block_kv
-    needed = _on_diag(iq, jk, block_q, block_kv) if causal else True
 
     def _accum(mask):
         q = q_ref[0, 0]
@@ -231,16 +355,18 @@ def _dkv_kernel(pids, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _dispatch_tile(_accum, needed, causal, iq, jk, block_q, block_kv)
+    _dispatch_tile(_accum, _flag(flags, _WORK), causal, iq, jk,
+                   block_q, block_kv)
 
-    @pl.when(i == q_tiles - 1)
+    @pl.when(_flag(flags, _CLOSES))
     def _emit():
         dk_ref[0, 0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
-    # the last KV row visits every Q tile once more: each leaves VMEM
-    # there, after its last contribution
-    @pl.when(jk == n_kv - 1)
+    # a Q tile leaves VMEM at the step of its last contribution (square
+    # tiles, causal: the diagonal step, which opens its KV row), through
+    # the block that dqt holds for it (see _bwd_tables)
+    @pl.when(_flag(flags, _DQ_DONE))
     def _emit_dq():
         dqa_ref[0, 0] = dq_scr[i]
 
@@ -255,20 +381,28 @@ def _dq_kernel(pids, dqa_ref, dq_ref, *, scale):
 # -- pallas_call wrappers ----------------------------------------------------
 
 
-def _q_index(b, h, i, j):
-    return (b, h, i, 0)
+# every index map takes the grid indices (b, h, t) and then the tables,
+# qt and kt first.  A map that reads a table costs a grid step about
+# 0.02 us on a v5e, a read in the body nothing that shows (PERF.md
+# section 6, PR 32): what only the body needs stays out of the maps
 
 
-def _make_kv_index(causal, block_q, block_kv, n_kv):
-    def kv_index(b, h, i, j):
-        if not causal:
-            return (b, h, j, 0)
-        # skipped (above-diagonal) tiles redirect their DMA to tile 0 —
-        # the first tile the NEXT Q block will need — so no bytes stream
-        # for tiles the kernel won't touch
-        return (b, h, jax.lax.select(
-            _on_diag(i, j, block_q, block_kv), j, 0), 0)
-    return kv_index
+def _q_index(b, h, t, qt, *_):
+    return (b, h, qt[t], 0)
+
+
+def _kv_index(b, h, t, qt, kt, *_):
+    return (b, h, kt[t], 0)
+
+
+def _part_index(b, h, t, qt, kt, pt, *_):
+    return (pt[t], b, h, kt[t], 0)
+
+
+# the dQ^ block a step holds is the table's: it moves only after a step
+# that wrote the block, so each is written back once, complete
+def _dqa_index(b, h, t, qt, kt, pt, dqt, *_):
+    return (b, h, dqt[t], 0)
 
 
 def _fwd_call(q, k, v, cfgt):
@@ -277,17 +411,18 @@ def _fwd_call(q, k, v, cfgt):
     Tk = k.shape[2]
     n_q, n_kv = Tq // block_q, Tk // block_kv
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)  # q^ = q/sqrt(D)
-    kv_index = _make_kv_index(causal, block_q, block_kv, n_kv)
+    tables, prefetched = _fwd_tables(n_q, n_kv, block_q, block_kv, causal)
+    _count_steps("flash_fwd", tables[-1])
     q_spec = pl.BlockSpec((1, 1, block_q, D), _q_index)
-    kv_spec = pl.BlockSpec((1, 1, block_kv, D), kv_index)
+    kv_spec = pl.BlockSpec((1, 1, block_kv, D), _kv_index)
     row_spec = pl.BlockSpec((1, 1, block_q, 1), _q_index)
     kernel = functools.partial(
-        _fwd_kernel, causal=causal,
-        block_q=block_q, block_kv=block_kv, n_kv=n_kv)
+        _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv)
     out, lse = pallas_call(
         kernel,
         name="flash_fwd",
-        grid=(B, H, n_q, n_kv),
+        grid=(B, H, len(tables[0])),
+        num_scalar_prefetch=len(tables),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[_sds(q.shape, q.dtype, q),
@@ -296,7 +431,7 @@ def _fwd_call(q, k, v, cfgt):
                         pltpu.VMEM((block_q, 128), jnp.float32),
                         pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-    )(q, k, v)
+    )(*prefetched(), q, k, v)
     return out, lse
 
 
@@ -328,54 +463,36 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
         delta = delta - dlse.astype(jnp.float32)
 
     # One pass: KV tiles outer, Q tiles inner, each needed (q, kv) pair
-    # visited once.  dK, dV of the KV tile sit in VMEM scratch across the
-    # inner loop; the float32 dQ^ of EVERY Q tile of the pass sits in
-    # VMEM scratch across the whole (jk, i) loop and leaves on the last
-    # KV row.  A (Tq x D) accumulator larger than _DQ_ACC_BYTES splits
-    # the Q rows into n_pass ranges on one more grid axis: each range is
-    # a whole pass with its own float32 dK, dV partial sums, added below
-    # (K and V stream once per range).
+    # visited once.  dK, dV of the KV tile sit in VMEM scratch across its
+    # row; the float32 dQ^ of EVERY Q tile of the pass sits in VMEM
+    # scratch across the whole loop, and a tile leaves at its last
+    # contribution.  A (Tq x D) accumulator larger than _DQ_ACC_BYTES
+    # splits the Q rows into n_pass ranges, run one after the other on
+    # the same grid axis (the tables list one range's rows, then the
+    # next's): each range is a whole pass with its own float32 dK, dV
+    # partial sums, added below (K and V stream once per range).
     fits = max(_DQ_ACC_BYTES // (block_q * D * 4), 1)
     q_tiles = max(t for t in range(1, min(fits, n_q) + 1) if n_q % t == 0)
     n_pass = n_q // q_tiles
+    tables, prefetched = _bwd_tables(n_q, n_kv, block_q, block_kv, causal,
+                                     q_tiles)
+    _count_steps("flash_dkv", tables[-1])
 
-    # causal skips the Q tiles wholly ABOVE KV tile j's columns, which
-    # open every row of the grid; their DMA is redirected to the first
-    # tile the row needs (held to the pass's range), so that it streams
-    # in while the skipped steps pass.  The dQ^ block index moves only
-    # on the last KV row: until then one never-written block stays put
-    # and nothing is written back.
-    def q_index(b, h, c, j, i):
-        iq = c * q_tiles + i
-        if causal:
-            first = jnp.minimum((j * block_kv) // block_q,
-                                c * q_tiles + q_tiles - 1)
-            iq = jnp.maximum(iq, first)
-        return (b, h, iq, 0)
-
-    def kv_index(b, h, c, j, i):
-        return (b, h, j, 0)
-
-    def part_index(b, h, c, j, i):
-        return (c, b, h, j, 0)
-
-    def dqa_index(b, h, c, j, i):
-        return (b, h, c * q_tiles + jnp.where(j == n_kv - 1, i, 0), 0)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D), q_index)
-    kv_spec = pl.BlockSpec((1, 1, block_kv, D), kv_index)
-    row_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
-    part_spec = pl.BlockSpec((1, 1, 1, block_kv, D), part_index)
+    q_spec = pl.BlockSpec((1, 1, block_q, D), _q_index)
+    kv_spec = pl.BlockSpec((1, 1, block_kv, D), _kv_index)
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), _q_index)
+    part_spec = pl.BlockSpec((1, 1, 1, block_kv, D), _part_index)
     part_dtype = k.dtype if n_pass == 1 else jnp.float32
     vmem = q_tiles * block_q * D * 4 + _BWD_TILE_BYTES
     dk, dv, dqa = pallas_call(
         functools.partial(_dkv_kernel, causal=causal, block_q=block_q,
-                          block_kv=block_kv, n_kv=n_kv, q_tiles=q_tiles),
+                          block_kv=block_kv, q_tiles=q_tiles),
         name="flash_dkv",
-        grid=(B, H, n_pass, n_kv, q_tiles),
+        grid=(B, H, len(tables[0])),
+        num_scalar_prefetch=len(tables),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[part_spec, part_spec,
-                   pl.BlockSpec((1, 1, block_q, D), dqa_index)],
+                   pl.BlockSpec((1, 1, block_q, D), _dqa_index)],
         out_shape=[_sds((n_pass,) + k.shape, part_dtype, k),
                    _sds((n_pass,) + v.shape, part_dtype, v),
                    _sds(q.shape, jnp.float32, q)],
@@ -384,7 +501,7 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
                         pltpu.VMEM((q_tiles, block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(*prefetched(), q, k, v, do, lse, delta)
     dk, dv = (part.sum(axis=0).astype(x.dtype) if n_pass > 1 else part[0]
               for part, x in ((dk, k), (dv, v)))
 

@@ -98,7 +98,8 @@ def sds(shape: Sequence[int], dtype: Any, like: Any) -> jax.ShapeDtypeStruct:
 
 def pallas_call(kernel, *, name: str, grid: Sequence[int],
                 interpret: Optional[bool] = None,
-                cost_estimate: Optional[Any] = None, **kwargs):
+                cost_estimate: Optional[Any] = None,
+                num_scalar_prefetch: int = 0, **kwargs):
     """``pl.pallas_call`` with the repo-wide CPU-fallback policy applied
     and the build counted (*name* labels the kernel family in
     ``mrtpu_pallas_kernel_builds_total``).  *cost_estimate* forwards a
@@ -113,7 +114,14 @@ def pallas_call(kernel, *, name: str, grid: Sequence[int],
     with a literal or a scratch value (every non-trivial kernel); a
     branch jaxpr keeps the types of kernel trace time, where the
     checking is off — but cannot resolve ``program_id`` itself.  Mosaic
-    lowering never re-types the body and gets it unwrapped."""
+    lowering never re-types the body and gets it unwrapped.
+
+    With *num_scalar_prefetch* = n the first n operands of the call are
+    small int32 tables that reach scalar memory before the grid starts
+    (``pltpu.PrefetchScalarGridSpec``): every index map takes them as
+    refs after its grid indices, and the kernel as
+    ``kernel(pids, *table_refs, *refs)`` — which block a grid step
+    works on is then read from a table, not computed from its index."""
     from jax.experimental import pallas as pl  # lazy: see module note
 
     interp = default_interpret(interpret)
@@ -121,7 +129,18 @@ def pallas_call(kernel, *, name: str, grid: Sequence[int],
                        mode="interpret" if interp else "mosaic")
     if cost_estimate is not None:
         kwargs["cost_estimate"] = cost_estimate
+    grid = tuple(grid)
     n_axes = len(grid)
+    if num_scalar_prefetch:
+        from jax.experimental.pallas import tpu as pltpu
+
+        kwargs["grid_spec"] = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+            in_specs=kwargs.pop("in_specs"),
+            out_specs=kwargs.pop("out_specs"),
+            scratch_shapes=kwargs.pop("scratch_shapes", ()))
+    else:
+        kwargs["grid"] = grid
 
     def body(*refs):
         pids = tuple(pl.program_id(a) for a in range(n_axes))
@@ -130,8 +149,7 @@ def pallas_call(kernel, *, name: str, grid: Sequence[int],
         else:
             kernel(pids, *refs)
 
-    return pl.pallas_call(body, name=name, grid=tuple(grid),
-                          interpret=interp, **kwargs)
+    return pl.pallas_call(body, name=name, interpret=interp, **kwargs)
 
 
 # -- in-kernel building blocks for the blocked scan kernels ------------------

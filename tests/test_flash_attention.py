@@ -211,8 +211,8 @@ def test_ring_flash_matches_oracle():
 # flash_dkv keeps dK/dV of a KV tile in scratch across the inner Q loop and
 # the float32 dQ of every Q tile in scratch across the whole (kv, q) loop;
 # a block index that repeats on consecutive grid steps is neither
-# re-fetched nor written back, and the causal skip redirects block
-# indices.  Each case below is a grid on which one of those can go wrong.
+# re-fetched nor written back, and the grid's block indices come from
+# tables.  Each case below is a grid on which one of those can go wrong.
 
 
 def _oracle_lse(q, k, v, causal):
@@ -321,6 +321,195 @@ def test_backward_visits_each_tile_once():
     assert dots == {"flash_fwd": 2, "flash_dkv": 5, "flash_dq": 0}
     for name, was in before.items():
         assert builds(name) == was + 1, name
+
+
+# -- the grid of needed tiles (PR 32) ----------------------------------------
+# Both tile loops run on a grid whose innermost axis counts the needed
+# (Q tile, KV tile) pairs; which pair a step is, and what it opens, closes
+# or completes, stands in int32 tables made at trace time.  The tables are
+# held here to what they must say, apart from any kernel: Pallas keeps an
+# output block in VMEM while its index stays and writes it back when the
+# index moves, whatever the body wrote, so a block whose index comes back
+# later, or moves before the body's write, is silently wrong on the chip
+# and right under the interpreter.
+
+
+def _tile_counts(grid):
+    Tq, Tk, block_q, block_kv = _BWD_GRIDS[grid]
+    return Tq // block_q, Tk // block_kv, block_q, block_kv
+
+
+def _allowed(iq, j, block_q, block_kv, causal):
+    return not causal or bool(fa._on_diag(iq, j, block_q, block_kv))
+
+
+def _written_back(blocks, writes, adds_to):
+    """Replay what the pipeline does with one output over the grid's
+    steps.  *blocks*: the block index a step holds; *writes*: whether
+    the body writes the held block there; *adds_to*: the block a step's
+    products add to, None for a step without products.  Every run of one
+    index must hold exactly one write, and no step may add to a block
+    after that block's write.  Returns ``{block: runs}``: a block that
+    is written back once has one run."""
+    runs, wrote_at, start = {}, {}, 0
+    for t in range(1, len(blocks) + 1):
+        if t < len(blocks) and blocks[t] == blocks[start]:
+            continue
+        written = [u for u in range(start, t) if writes[u]]
+        assert len(written) == 1, (blocks[start], start, t, written)
+        runs[blocks[start]] = runs.get(blocks[start], 0) + 1
+        wrote_at[blocks[start]] = written[0]
+        start = t
+    for t, block in enumerate(adds_to):
+        assert block is None or wrote_at[block] >= t, (t, block)
+    return runs
+
+
+_TABLE_CASES = ([(g, c, None) for g in _BWD_GRIDS for c in (True, False)]
+                + [("q_finer", True, 2), ("kv_finer", False, 1),
+                   ("kv_finer", True, 1), ("tq_ne_tk", True, 1),
+                   ("one_kv_tile", True, 2)])
+
+
+@pytest.mark.parametrize(
+    "grid,causal,acc_tiles", _TABLE_CASES,
+    ids=[f"{g}-{'causal' if c else 'full'}{f'-acc{a}' if a else ''}"
+         for g, c, a in _TABLE_CASES])
+def test_needed_tile_tables(grid, causal, acc_tiles):
+    n_q, n_kv, block_q, block_kv = _tile_counts(grid)
+    flag = lambda flags, bit: [bool(f & bit) for f in flags]
+
+    # forward: Q-major, KV ascending; o and lse leave once a Q tile
+    (qt, kt, flags), _ = fa._fwd_tables(n_q, n_kv, block_q, block_kv, causal)
+    want = [(iq, j) for iq in range(n_q) for j in range(n_kv)
+            if _allowed(iq, j, block_q, block_kv, causal)]
+    assert list(zip(qt.tolist(), kt.tolist())) == want
+    assert all(flag(flags, fa._WORK))
+    # the softmax state opens at each row's first pair and nowhere else
+    assert flag(flags, fa._OPENS) == [
+        t == 0 or qt[t - 1] != qt[t] for t in range(len(qt))]
+    assert _written_back(qt.tolist(), flag(flags, fa._CLOSES),
+                         qt.tolist()) == {iq: 1 for iq in range(n_q)}
+
+    # backward: pass by pass, KV-major, Q ascending from the first
+    # needed Q tile; a row nobody needs keeps one step without _WORK
+    q_tiles = acc_tiles or n_q
+    assert n_q % q_tiles == 0
+    (qt, kt, pt, dqt, flags), _ = fa._bwd_tables(
+        n_q, n_kv, block_q, block_kv, causal, q_tiles)
+    want, rows = [], []
+    for c in range(n_q // q_tiles):
+        for j in range(n_kv):
+            row = [(iq, j, True) for iq in range(c * q_tiles,
+                                                 (c + 1) * q_tiles)
+                   if _allowed(iq, j, block_q, block_kv, causal)]
+            row = row or [((c + 1) * q_tiles - 1, j, False)]
+            want += row
+            rows += [(c, j)] * len(row)
+    work = flag(flags, fa._WORK)
+    assert list(zip(qt.tolist(), kt.tolist(), work)) == want
+    assert list(zip(pt.tolist(), kt.tolist())) == rows
+    assert (pt == qt // q_tiles).all()
+    assert flag(flags, fa._OPENS) == [
+        t == 0 or rows[t - 1] != rows[t] for t in range(len(rows))]
+    # dK, dV: one block a (pass, KV tile), written at the row's end
+    assert _written_back(
+        rows, flag(flags, fa._CLOSES),
+        [r if w else None for r, w in zip(rows, work)]) == {
+            (c, j): 1 for c in range(n_q // q_tiles) for j in range(n_kv)}
+    # dQ: one block a Q tile, held for one run of steps and written at
+    # the tile's last contribution
+    done = flag(flags, fa._DQ_DONE)
+    for iq in range(n_q):
+        mine = [t for t in range(len(qt)) if qt[t] == iq and work[t]]
+        assert [t for t in mine if done[t]] == [mine[-1]]
+        assert dqt[mine[-1]] == iq
+    assert _written_back(
+        dqt.tolist(), done,
+        [q if w else None for q, w in zip(qt.tolist(), work)]) == {
+            iq: 1 for iq in range(n_q)}
+    # the accumulator is zeroed on KV tile 0's row: every Q tile is there
+    for c in range(n_q // q_tiles):
+        assert [q for q, r, w in zip(qt.tolist(), rows, work)
+                if r == (c, 0) and w] == list(range(c * q_tiles,
+                                                    (c + 1) * q_tiles))
+
+
+_GRID_CASES = [("q_finer", True), ("q_finer", False), ("kv_finer", True),
+               ("tq_ne_tk", False), ("tq_ne_tk", True), ("one_tile", True)]
+
+
+@pytest.mark.parametrize("grid,causal", _GRID_CASES,
+                         ids=[f"{g}-{'causal' if c else 'full'}"
+                              for g, c in _GRID_CASES])
+def test_built_kernels_run_the_needed_steps_alone(grid, causal):
+    """The kernels as built: the innermost grid axis of flash_fwd and of
+    flash_dkv is as long as there are needed tiles (plus, backward, one
+    step a KV tile that no Q tile needs), causal or not, and the gauge
+    says the same."""
+    from mapreduce_tpu.obs.metrics import REGISTRY
+
+    Tq, Tk, block_q, block_kv = _BWD_GRIDS[grid]
+    n_q, n_kv = Tq // block_q, Tk // block_kv
+    B, H, D = 1, 2, 16
+    q, k, v = (jnp.zeros((B, H, T, D), jnp.float32) for T in (Tq, Tk, Tk))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention_lse(
+            q, k, v, causal=causal, block_q=block_q,
+            block_kv=block_kv)[0].sum(), argnums=(0, 1, 2)))(q, k, v)
+    grids = {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+             for e, _ in eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    needed = sum(_allowed(iq, j, block_q, block_kv, causal)
+                 for iq in range(n_q) for j in range(n_kv))
+    unseen = sum(not any(_allowed(iq, j, block_q, block_kv, causal)
+                         for iq in range(n_q)) for j in range(n_kv))
+    assert grids == {"flash_fwd": (B, H, needed),
+                     "flash_dkv": (B, H, needed + unseen),
+                     "flash_dq": (B, H, n_q)}
+    read = lambda kernel, kind: REGISTRY.value(
+        "mrtpu_flash_grid_steps", kernel=kernel, kind=kind)
+    assert (read("flash_fwd", "steps"), read("flash_fwd", "needed")) \
+        == (needed, needed)
+    assert (read("flash_dkv", "steps"), read("flash_dkv", "needed")) \
+        == (needed + unseen, needed)
+    if not causal:
+        assert needed == n_q * n_kv and unseen == 0
+
+
+@pytest.mark.parametrize("acc_tiles", [None, 1], ids=["one_pass", "acc1"])
+def test_causal_keys_past_the_queries_get_zero_gradients(acc_tiles,
+                                                         monkeypatch):
+    """Causal with more keys than queries (Tk > Tq): no query sees the
+    KV tiles past the last query row, their dK and dV blocks are still
+    written, as zeros, and everything else matches the oracle."""
+    Tq, Tk, block_q, block_kv = _BWD_GRIDS["tq_ne_tk"]
+    B, H, D = 1, 2, 16
+    if acc_tiles:
+        monkeypatch.setattr(fa, "_DQ_ACC_BYTES", acc_tiles * block_q * D * 4)
+    q, k, v = (jax.random.normal(jax.random.key(i), (B, H, T, D))
+               for i, T in enumerate((Tq, Tk, Tk)))
+
+    def loss_of(attend):
+        def loss(q, k, v):
+            out, lse = attend(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+        return loss
+
+    flash = loss_of(lambda q, k, v: flash_attention_lse(
+        q, k, v, causal=True, block_q=block_q, block_kv=block_kv))
+    oracle = loss_of(lambda q, k, v: _oracle_lse(q, k, v, True))
+    with jax.default_matmul_precision("float32"):
+        gf = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)
+    seen = -(-Tq // block_kv) * block_kv        # whole KV tiles a query sees
+    assert seen < Tk
+    for name, a, b in zip("qkv", gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-4, err_msg=f"d{name}")
+    for name, g in zip("kv", gf[1:]):
+        assert not np.asarray(g[:, :, seen:]).any(), f"d{name}"
+        assert np.asarray(g[:, :, :seen]).any(), f"d{name}"
 
 
 # -- what a checkpoint policy can keep of the kernel (PR 29) -----------------
