@@ -41,8 +41,7 @@ def apply_rope(x: jax.Array, tables, seq_axis: int) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
-def looped_loss(hs, aux, targets, params, chunk_nll, cfg,
-                data_axis: str):
+def looped_loss(hs, targets, params, chunk_nll, cfg, data_axis: str):
     """The looped objective of :func:`transformer.loss_local` from the R hidden
     states ``hs`` [R, B, T, E].  One ``loss_block`` scan covers the R
     passes of every chunk, a (pass, chunk) pair a trip, so that one
@@ -92,8 +91,7 @@ def looped_loss(hs, aux, targets, params, chunk_nll, cfg,
                 some, p * jnp.log(jnp.where(some, p, 1.0)), 0.0).sum(axis=0)
         expected = (p * nll).sum(axis=0)
         total = (expected.mean()
-                 + jnp.float32(cfg.exit_entropy_weight) * neg_entropy.mean()
-                 + jnp.float32(cfg.moe_aux_weight) * aux)
+                 + jnp.float32(cfg.exit_entropy_weight) * neg_entropy.mean())
         stats = jnp.stack([nll.mean(axis=(1, 2)), p.mean(axis=(1, 2))])
     return (jax.lax.pmean(total, data_axis),
             jax.lax.pmean(stats, data_axis))

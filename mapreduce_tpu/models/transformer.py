@@ -34,6 +34,8 @@ from ..obs.trace import TRACER
 from ..ops.flash_attention import KEPT_NAMES, flash_attention
 from ..parallel.ring import ring_attention
 from .looplm import apply_rope, looped_loss, rope_tables
+from .moe import STAT_DROPPED, STAT_ROUTED, routed_experts
+from .operators import grouped_qkv, rmsnorm as _rmsnorm, short_conv
 
 Params = Dict[str, jax.Array]
 
@@ -56,6 +58,22 @@ _EXIT_MASS = _metrics.gauge(
     "mrtpu_train_exit_mass",
     "a looped model's mean exit probability of each pass, at the last "
     "step observed; sums to 1 over pass (labels: pass)")
+_MOE_PAIRS = _metrics.counter(
+    "mrtpu_moe_pairs_held_total",
+    "(token, expert) pairs the routed expert layers computed on the "
+    "experts held here, summed over the layers of every step observed")
+_MOE_DROPPED = _metrics.counter(
+    "mrtpu_moe_dropped_pairs_total",
+    "(token, expert) pairs routed to an expert held here and not "
+    "computed; the layer has room for every pair, so it stays 0")
+_MOE_LOAD = _metrics.gauge(
+    "mrtpu_moe_expert_load_max_over_mean",
+    "the busiest held expert's pairs over the held experts' mean, of an "
+    "expert layer at the last step observed (labels: layer)")
+_MOE_SHARE = _metrics.gauge(
+    "mrtpu_moe_pairs_held_share",
+    "pairs landing on the experts held here over all pairs routed, of "
+    "an expert layer at the last step observed (labels: layer)")
 
 
 @dataclass(frozen=True)
@@ -92,21 +110,21 @@ class TransformerConfig:
     #: single-chip long-context blocker once attention is chunked.
     #: None = unchunked; must divide T_local
     loss_block: Any = None
-    #: EXPERT parallelism (Switch-style top-1 MoE FFN): each model-axis
-    #: rank hosts ONE expert whose hidden width is ffn/n_model — the
-    #: exact parameter shapes and shardings of the dense TP layer, used
-    #: as disjoint experts instead of column shards (so moe_experts must
-    #: equal the mesh's model-axis size).  Tokens are routed by a
-    #: learned router, capacity-gathered per expert (compute per rank is
-    #: O(capacity), not O(tokens)), and gate-weighted back with one
-    #: psum.  Over-capacity tokens fall through on the residual.
-    #: 0 = dense FFN.
+    #: ROUTED EXPERTS (models/moe.py) in the layers ``layer_ffns`` marks
+    #: "moe": a router over ``moe_experts`` sigmoid scores (0 = no expert
+    #: layer) picks ``moe_top_k`` a token, weights renormalised over the
+    #: chosen, each expert a gated FFN of width ``moe_ffn``.  This mesh
+    #: HOLDS ``moe_held`` of them (0 = all) from ``moe_held_offset`` on,
+    #: split evenly over the model axis, and computes their part of the
+    #: result; one held expert a rank is expert parallelism
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
-    #: weight of the Switch auxiliary load-balance loss — without it the
-    #: gate gradient is rich-get-richer (the winning expert's logit only
-    #: grows) and routing collapses onto one expert
-    moe_aux_weight: float = 0.01
+    moe_top_k: int = 1
+    moe_ffn: int = 0
+    moe_held: int = 0
+    moe_held_offset: int = 0
+    #: select by score + a per-expert bias (an untrained buffer,
+    #: ``L<i>.router_bias``) while weighting by the score alone
+    moe_router_bias: bool = False
     #: use the in-tree Pallas flash-attention kernel
     #: (ops/flash_attention.py).  None = auto: the unsharded case
     #: (data axis 1) calls the kernel directly on TPU; the multi-device
@@ -136,23 +154,77 @@ class TransformerConfig:
     #: weight of the exit distribution's negative entropy in the looped
     #: objective (read only when loop_steps > 1)
     exit_entropy_weight: float = 0.1
+    #: each layer's token-mixing operator, "attn" (causal attention) or
+    #: "conv" (models/operators.short_conv, ``conv_taps`` taps a
+    #: channel), and each layer's FFN, "dense" or "moe"; () = "attn" and
+    #: "dense" in every layer
+    layer_ops: Tuple[str, ...] = ()
+    layer_ffns: Tuple[str, ...] = ()
+    conv_taps: int = 3
+    #: key/value heads, each serving n_heads / n_kv_heads consecutive
+    #: query heads (0 = n_heads, and one fused ``wqkv``)
+    n_kv_heads: int = 0
+    #: an RMSNorm with a learned [head_dim] scale over each head of q
+    #: and of k, before the rotary embedding (grouped-query layers)
+    qk_norm: bool = False
+    #: the epsilon of every RMSNorm
+    norm_eps: float = 1e-6
+    #: the head is the embedding table transposed; no ``unembed``
+    tied_embeddings: bool = False
+
+    def __post_init__(self):
+        for name in ("layer_ops", "layer_ffns"):        # lists from JSON
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held or self.moe_experts
+
+    def layer_kind(self, i: int) -> Tuple[str, str]:
+        """``(operator, ffn)`` of layer *i*."""
+        return (self.layer_ops[i] if self.layer_ops else "attn",
+                self.layer_ffns[i] if self.layer_ffns else "dense")
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i)[1] == "moe")
 
     def validate(self, n_model: int) -> None:
         assert self.n_heads % n_model == 0, "heads must split over model axis"
         assert self.ffn % n_model == 0
         assert self.vocab % n_model == 0
         assert self.loop_steps >= 1
-        assert not (self.ffn_gated and self.moe_experts), \
-            "the experts are two-matrix GELU FFNs"
         assert self.rope_theta is None or self.head_dim % 2 == 0
-        if self.moe_experts:
-            assert self.moe_experts == n_model, (
-                "expert parallelism maps one expert per model-axis rank: "
-                f"moe_experts={self.moe_experts} != n_model={n_model}")
+        for kinds, known in ((self.layer_ops, ("attn", "conv")),
+                             (self.layer_ffns, ("dense", "moe"))):
+            assert len(kinds) in (0, self.n_layers) and set(kinds) <= set(
+                known), f"one of {known} a layer, {self.n_layers} layers"
+        assert self.n_heads % self.kv_heads == 0 \
+            and self.kv_heads % n_model == 0, "key/value heads must split"
+        assert self.n_kv_heads or not self.qk_norm, \
+            "the q/k norm belongs to the grouped-query projections"
+        assert self.embed % n_model == 0 or "conv" not in self.layer_ops
+        if self.moe_layers:
+            assert self.loop_steps == 1, "no looped routed layers"
+            assert 1 <= self.moe_top_k <= self.moe_experts and self.moe_ffn
+            assert (self.moe_held_offset + self.experts_held
+                    <= self.moe_experts)
+            assert self.experts_held % n_model == 0, (
+                f"the {self.experts_held} held experts do not divide over "
+                f"{n_model} model ranks")
 
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
-    """Flat named params (names drive the tensor-parallel layout rules)."""
+    """Flat named params (names drive the tensor-parallel layout rules).
+    A layer holds the tensors of its own operator and FFN
+    (``cfg.layer_kind``); a layer's further tensors take keys folded
+    from the layer's six, so that a block of one kind is initialised as
+    it always was."""
     E, H, D, F, V = (cfg.embed, cfg.n_heads, cfg.head_dim, cfg.ffn,
                      cfg.vocab)
     params: Params = {}
@@ -162,20 +234,48 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
 
     keys = jax.random.split(key, 2 + 6 * cfg.n_layers)
     params["embed"] = norm(keys[0], (V, E), 1.0) * 0.02
-    params["unembed"] = norm(keys[1], (E, V), E)
+    if not cfg.tied_embeddings:
+        params["unembed"] = norm(keys[1], (E, V), E)
     for i in range(cfg.n_layers):
         k0 = 2 + 6 * i
+        op, ffn = cfg.layer_kind(i)
         params[f"L{i}.ln1_scale"] = jnp.ones((E,), jnp.float32)
         params[f"L{i}.ln2_scale"] = jnp.ones((E,), jnp.float32)
-        params[f"L{i}.wqkv"] = norm(keys[k0], (E, 3, H * D), E)
-        params[f"L{i}.wo"] = norm(keys[k0 + 1], (H * D, E), H * D)
-        params[f"L{i}.w_in"] = norm(keys[k0 + 2], (E, F), E)
-        params[f"L{i}.w_out"] = norm(keys[k0 + 3], (F, E), F)
-        if cfg.moe_experts:
+        if op == "conv":
+            params[f"L{i}.conv_in"] = norm(keys[k0], (E, 3, E), E)
+            params[f"L{i}.conv_w"] = norm(jax.random.fold_in(keys[k0], 1),
+                                          (E, cfg.conv_taps), cfg.conv_taps)
+            params[f"L{i}.conv_out"] = norm(keys[k0 + 1], (E, E), E)
+        else:
+            if cfg.n_kv_heads:
+                params[f"L{i}.wq"] = norm(keys[k0], (E, H * D), E)
+                params[f"L{i}.wkv"] = norm(jax.random.fold_in(keys[k0], 1),
+                                           (E, 2, cfg.kv_heads * D), E)
+            else:
+                params[f"L{i}.wqkv"] = norm(keys[k0], (E, 3, H * D), E)
+            params[f"L{i}.wo"] = norm(keys[k0 + 1], (H * D, E), H * D)
+            if cfg.qk_norm:
+                params[f"L{i}.q_norm_scale"] = jnp.ones((D,), jnp.float32)
+                params[f"L{i}.k_norm_scale"] = jnp.ones((D,), jnp.float32)
+        if ffn == "moe":
+            X, Fe = cfg.experts_held, cfg.moe_ffn
             params[f"L{i}.w_router"] = norm(keys[k0 + 4],
                                             (E, cfg.moe_experts), E)
-        if cfg.ffn_gated:
-            params[f"L{i}.w_gate"] = norm(keys[k0 + 5], (E, F), E)
+            if cfg.moe_router_bias:
+                # a buffer the steps hold fixed (BUFFERS): spread wide
+                # enough that choosing by score + bias and weighting by
+                # the score are told apart
+                params[f"L{i}.router_bias"] = 0.1 * jax.random.normal(
+                    jax.random.fold_in(keys[k0 + 4], 1),
+                    (cfg.moe_experts,), jnp.float32)
+            params[f"L{i}.moe_w_in"] = norm(keys[k0 + 2], (X, E, Fe), E)
+            params[f"L{i}.moe_w_out"] = norm(keys[k0 + 3], (X, Fe, E), Fe)
+            params[f"L{i}.moe_w_gate"] = norm(keys[k0 + 5], (X, E, Fe), E)
+        else:
+            params[f"L{i}.w_in"] = norm(keys[k0 + 2], (E, F), E)
+            params[f"L{i}.w_out"] = norm(keys[k0 + 3], (F, E), F)
+            if cfg.ffn_gated:
+                params[f"L{i}.w_gate"] = norm(keys[k0 + 5], (E, F), E)
         if cfg.sandwich_norm:
             params[f"L{i}.ln1_out_scale"] = jnp.ones((E,), jnp.float32)
             params[f"L{i}.ln2_out_scale"] = jnp.ones((E,), jnp.float32)
@@ -189,36 +289,92 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
     return params
 
 
+#: name endings of tensors that are part of the model and not trained:
+#: every step leaves them as they are
+BUFFERS = (".router_bias",)
+
+
 def transformer_param_spec(name: str) -> P:
     """Tensor-parallel placement by name: head/column-sharded projections,
     row-sharded outputs, replicated norms/embeddings/router/exit gate.
     The gated FFN's w_gate is column-sharded like w_in: the product of
-    the two is elementwise over the local columns.  The same
-    w_in/w_out shards double as per-rank EXPERTS under expert parallelism
-    (moe_experts) — the layout is identical, only the math changes."""
-    if name.endswith((".wqkv", ".w_in", ".w_gate")):
-        return P(None, None, "model") if name.endswith("wqkv") \
-            else P(None, "model")
-    if name.endswith((".wo", ".w_out")):
+    the two is elementwise over the local columns.  The short
+    convolution's channels split like heads (conv_in by columns, its
+    taps and conv_out by rows); the held experts split over the axis
+    whole, a rank's experts its own."""
+    if name.endswith((".wqkv", ".wkv", ".conv_in")):
+        return P(None, None, "model")
+    if name.endswith((".w_in", ".w_gate", ".wq")):
+        return P(None, "model")
+    if name.endswith((".wo", ".w_out", ".conv_w", ".conv_out")):
         return P("model", None)
+    if name.endswith((".moe_w_in", ".moe_w_gate", ".moe_w_out")):
+        return P("model", None, None)
     if name == "unembed":
         return P(None, "model")
     return P()
 
 
-def _rmsnorm(x, scale):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
-
-
 def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                  n_model: int, data_axis: str, model_axis: str,
-                 rope=None):
+                 rope=None, kind=("attn", "dense")):
     """One transformer block on the local sequence shard (inside
     shard_map); ``lp`` holds this layer's params without the L<i> prefix,
     ``rope`` the rotary tables of :func:`looplm.rope_tables` (None = no
-    position encoding)."""
+    position encoding), ``kind`` the layer's ``(operator, ffn)``.
+    Returns the block's output and, of a routed FFN, its statistics
+    ``{"loads", "chosen", "weights"}`` (models/moe.routed_experts; None
+    otherwise)."""
+    op, ffn = kind
+    eps = cfg.norm_eps
+    if op == "conv":
+        with jax.named_scope("tf.conv_op"):
+            h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype), eps)
+            o = jax.lax.psum(
+                short_conv(h, lp, cfg, data_axis).astype(jnp.float32),
+                model_axis)
+            if cfg.sandwich_norm:
+                o = _rmsnorm(o, lp["ln1_out_scale"], eps)
+            x = x + o.astype(cfg.dtype)
+    else:
+        x = _attention_local(x, lp, cfg, n_model, data_axis, model_axis,
+                             rope)
+
+    if ffn == "moe":
+        # its stages carry scopes of their own (tf.moe_*)
+        with jax.named_scope("tf.ffn"):
+            h = _rmsnorm(x, lp["ln2_scale"].astype(cfg.dtype), eps)
+        m, loads, chosen, weights = routed_experts(
+            h, lp, cfg, n_model, data_axis, model_axis)
+        with jax.named_scope("tf.ffn"):
+            if cfg.sandwich_norm:
+                m = _rmsnorm(m, lp["ln2_out_scale"], eps)
+            return x + m.astype(cfg.dtype), {
+                "loads": loads, "chosen": chosen, "weights": weights}
+    with jax.named_scope("tf.ffn"):
+        h = _rmsnorm(x, lp["ln2_scale"].astype(cfg.dtype), eps)
+        u = jnp.einsum("bte,ef->btf", h, lp["w_in"].astype(cfg.dtype))
+        if cfg.ffn_gated:
+            g = jnp.einsum("bte,ef->btf", h,
+                           lp["w_gate"].astype(cfg.dtype))
+            u = (jax.nn.silu(g.astype(jnp.float32))
+                 * u.astype(jnp.float32)).astype(cfg.dtype)
+        else:
+            u = jax.nn.gelu(u)
+        m = jnp.einsum("btf,fe->bte", u, lp["w_out"].astype(cfg.dtype))
+        m = jax.lax.psum(m.astype(jnp.float32), model_axis)
+        if cfg.sandwich_norm:
+            m = _rmsnorm(m.astype(jnp.float32), lp["ln2_out_scale"], eps)
+        return x + m.astype(cfg.dtype), None
+
+
+def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
+                     n_model: int, data_axis: str, model_axis: str,
+                     rope):
+    """The block's attention sublayer, residual included: causal
+    multi-head attention from one fused ``wqkv``, or grouped-query
+    attention from ``wq`` and ``wkv`` (``cfg.n_kv_heads``,
+    models/operators.grouped_qkv)."""
     H_loc = cfg.n_heads // n_model
     D = cfg.head_dim
     E = x.shape[-1]
@@ -226,8 +382,10 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
     # operation, backward pass included, to the innermost scope on its
     # path (obs/compile.CompileLedger.stage_map)
     with jax.named_scope("tf.attn_proj"):
-        h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype))
-        if cfg.flash:
+        h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype), cfg.norm_eps)
+        if cfg.n_kv_heads:
+            q, k, v = grouped_qkv(h, lp, cfg, n_model, rope)
+        elif cfg.flash:
             # Pallas fast path: project straight into the kernel's
             # [B, H, T, D] layout (the transpose folds into the matmul
             # epilogue — nothing is materialised twice); the kernel runs
@@ -240,7 +398,7 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                              lp["wqkv"].astype(cfg.dtype))
             q, k, v = [qkv[:, :, j].reshape(*qkv.shape[:2], H_loc, D)
                        for j in range(3)]
-    if rope is not None:
+    if rope is not None and not cfg.n_kv_heads:
         with jax.named_scope("tf.rope"):
             # q and k rotate as ONE tensor, sliced from the projection's
             # output in its layout: [B, 3, H, T, D] for the kernel,
@@ -283,79 +441,8 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
         o = jax.lax.psum(o.astype(jnp.float32), model_axis)
         if cfg.sandwich_norm:
             # the sublayer's output is whole only after the psum
-            o = _rmsnorm(o, lp["ln1_out_scale"])
-        x = x + o.astype(cfg.dtype)
-
-    with jax.named_scope("tf.ffn"):
-        h = _rmsnorm(x, lp["ln2_scale"].astype(cfg.dtype))
-        if cfg.moe_experts:
-            m, aux = _moe_ffn(h, lp, cfg, model_axis)
-        else:
-            u = jnp.einsum("bte,ef->btf", h, lp["w_in"].astype(cfg.dtype))
-            if cfg.ffn_gated:
-                g = jnp.einsum("bte,ef->btf", h,
-                               lp["w_gate"].astype(cfg.dtype))
-                u = (jax.nn.silu(g.astype(jnp.float32))
-                     * u.astype(jnp.float32)).astype(cfg.dtype)
-            else:
-                u = jax.nn.gelu(u)
-            m = jnp.einsum("btf,fe->bte", u, lp["w_out"].astype(cfg.dtype))
-            m = jax.lax.psum(m.astype(jnp.float32), model_axis)
-            aux = jnp.float32(0.0)
-        if cfg.sandwich_norm:
-            m = _rmsnorm(m.astype(jnp.float32), lp["ln2_out_scale"])
-        return x + m.astype(cfg.dtype), aux
-
-
-def _moe_ffn(h: jax.Array, lp: Params, cfg: TransformerConfig,
-             model_axis: str) -> Tuple[jax.Array, jax.Array]:
-    """Switch-style top-1 expert-parallel FFN (one expert per model-axis
-    rank).  Activations are replicated over the model axis (the TP
-    invariant), so routing needs NO token exchange: each rank
-    capacity-gathers the tokens its expert owns, runs its [E, ffn/n]
-    expert on just those, scatters back, gate-weights, and ONE psum
-    assembles the disjoint expert outputs — same collective count as the
-    dense TP layer.  Tokens beyond capacity fall through on the residual
-    (standard Switch behavior; the router's load-balance pressure comes
-    from the gate gradient)."""
-    B, T, E = h.shape
-    N = B * T
-    n_exp = cfg.moe_experts
-    cap = max(1, int(N * cfg.moe_capacity_factor / n_exp))
-    rank = jax.lax.axis_index(model_axis)
-
-    flat = h.reshape(N, E)
-    r_logits = jnp.einsum("ne,ex->nx", flat.astype(jnp.float32),
-                          lp["w_router"])  # [N, n_exp]
-    probs = jax.nn.softmax(r_logits, axis=-1)
-    expert = jnp.argmax(r_logits, axis=-1)          # [N]
-    gate = probs[jnp.arange(N), expert]             # [N] chosen-expert prob
-
-    mine = expert == rank
-    order = jnp.argsort(~mine)                      # my tokens first (stable)
-    take = order[:cap]                              # indices into flat
-    took_mine = mine[take]                          # padding rows masked
-    u = jnp.einsum("ce,ef->cf", flat[take], lp["w_in"].astype(cfg.dtype))
-    u = jax.nn.gelu(u)
-    y = jnp.einsum("cf,fe->ce", u, lp["w_out"].astype(cfg.dtype))
-    # gate-weight the [cap, E] expert rows BEFORE the scatter (the
-    # router's gradient path); foreign/padding rows zero out
-    y = y.astype(jnp.float32) * (gate[take] * took_mine)[:, None]
-    out = jnp.zeros((N, E), jnp.float32).at[take].add(y)
-    out = jax.lax.psum(out, model_axis)             # disjoint expert sums
-
-    # Switch auxiliary load-balance loss: n * sum_e(frac_e * meanP_e),
-    # equal to 1 at uniform routing and reported relative to 1 so a
-    # single expert contributes exactly 0.  (Mildly negative values are
-    # possible when argmax picks anti-correlate with mean probs — a
-    # constant shift, gradients unaffected.)  f is argmax-based (no
-    # gradient); the pressure reaches the router through meanP.
-    # Activations are replicated over the model axis, so every rank
-    # computes the identical value — no collective.
-    f = jnp.mean(jax.nn.one_hot(expert, n_exp, dtype=jnp.float32), axis=0)
-    mean_p = probs.mean(axis=0)
-    aux = jnp.float32(n_exp) * jnp.dot(f, mean_p) - 1.0
-    return out.reshape(B, T, E).astype(cfg.dtype), aux
+            o = _rmsnorm(o, lp["ln1_out_scale"], cfg.norm_eps)
+        return x + o.astype(cfg.dtype)
 
 
 def _step_jit_options(mesh: Mesh) -> Dict[str, Any]:
@@ -382,11 +469,15 @@ def remat_kept_bytes(cfg: TransformerConfig, n_model: int, batch: int,
     """Bytes the named residuals of one step take on a device: what
     ``forward_local``'s checkpoint policy keeps beyond each layer's
     input.  The kernel's output ``[B, H_loc, T, D]`` in ``cfg.dtype`` and
-    its float32 row statistics ``[B, H_loc, T]``, a layer application."""
+    its float32 row statistics ``[B, H_loc, T]``, an application of an
+    attention layer (grouped-query attention reaches the kernel with K
+    and V repeated to ``H_loc`` heads)."""
     if not (cfg.remat and cfg.flash):
         return 0
     rows = batch * (cfg.n_heads // n_model) * t_local
-    return cfg.loop_steps * cfg.n_layers * rows * (
+    attention_layers = sum(cfg.layer_kind(i)[0] == "attn"
+                           for i in range(cfg.n_layers))
+    return cfg.loop_steps * attention_layers * rows * (
         cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
 
 
@@ -394,8 +485,11 @@ def forward_local(params: Params, tokens: jax.Array,
                   cfg: TransformerConfig, n_model: int,
                   data_axis: str = "data", model_axis: str = "model"):
     """Local-block forward INSIDE shard_map: ``tokens`` [B, T_local]
-    int32; returns ``(hidden [B, T_local, E] f32, aux [] f32)`` where aux
-    is the summed MoE load-balance excess (0 for dense layers).  A looped
+    int32; returns ``(hidden [B, T_local, E] f32, stats)`` where stats
+    holds the statistics of the routed expert layers, stacked over the
+    layers: ``{"loads" [n, held + 2], "chosen" and "weights" [n, B,
+    T_local, k]}`` (models/moe.routed_experts), None for a model without
+    one.  A looped
     model (``loop_steps`` R > 1) returns the hidden state after EVERY
     pass, [R, B, T_local, E] in ``cfg.dtype`` (what the next pass read).
     Params arrive already sliced by transformer_param_spec."""
@@ -406,13 +500,13 @@ def forward_local(params: Params, tokens: jax.Array,
         with jax.named_scope("tf.rope"):
             rope = rope_tables(cfg, tokens.shape[1], data_axis)
 
-    def layer(x, lp, rope):
+    def layer(x, lp, rope, kind):
         return _layer_local(x, lp, cfg, n_model, data_axis, model_axis,
-                            rope)
+                            rope, kind)
 
     def final_norm(x, scale):
         with jax.named_scope("tf.final_norm"):
-            return _rmsnorm(x, scale.astype(cfg.dtype))
+            return _rmsnorm(x, scale.astype(cfg.dtype), cfg.norm_eps)
 
     if cfg.remat:
         # the backward pass runs each layer's forward again from its
@@ -423,26 +517,29 @@ def forward_local(params: Params, tokens: jax.Array,
         # the policy keeps what a bare jax.checkpoint keeps: the
         # layer's input
         layer = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.save_only_these_names(
+            layer, static_argnums=(3,),
+            policy=jax.checkpoint_policies.save_only_these_names(
                 *(KEPT_NAMES if cfg.flash else ())))
         final_norm = jax.checkpoint(final_norm)
 
     def stack(x):
         """The n_layers once, then the final norm."""
-        aux_total = jnp.float32(0.0)
+        stats = []
         for i in range(cfg.n_layers):
             prefix = f"L{i}."
             lp = {k[len(prefix):]: v for k, v in params.items()
                   if k.startswith(prefix)}
-            x, aux = layer(x, lp, rope)
-            aux_total = aux_total + aux
+            x, layer_stats = layer(x, lp, rope, cfg.layer_kind(i))
+            if layer_stats is not None:
+                stats.append(layer_stats)
         if cfg.final_norm:
             x = final_norm(x, params["final_scale"])
-        return x, aux_total
+        return x, (jax.tree.map(lambda *rows: jnp.stack(rows), *stats)
+                   if stats else None)
 
     if cfg.loop_steps == 1:
-        x, aux_total = stack(x)
-        return x.astype(jnp.float32), aux_total
+        x, stats = stack(x)
+        return x.astype(jnp.float32), stats
 
     # depth by re-use of weights: one compiled body of n_layers, run
     # loop_steps times.  The scan's transpose carries ONE float32
@@ -450,8 +547,8 @@ def forward_local(params: Params, tokens: jax.Array,
     # Python unroll would leave the loop_steps partial gradients of a
     # weight to the compiler's scheduling, at 4 bytes a parameter each
     def one_pass(x, _):
-        x, aux = stack(x)
-        return x, (x, aux)
+        x, _none = stack(x)         # validate: no looped routed layers
+        return x, x
 
     # every operation written in the body has a stage of its own; what
     # takes the loop's is the scan's own machinery (each pass's saved
@@ -460,9 +557,8 @@ def forward_local(params: Params, tokens: jax.Array,
     # makes inside the body without a scope: read it beside (unscoped)
     # (looplm.pass_loop_share; PERF.md section 3)
     with jax.named_scope("tf.pass_loop"):
-        _, (hs, auxs) = jax.lax.scan(one_pass, x, None,
-                                     length=cfg.loop_steps)
-    return hs, auxs.sum()
+        _, hs = jax.lax.scan(one_pass, x, None, length=cfg.loop_steps)
+    return hs, None
 
 
 def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
@@ -481,10 +577,19 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
     objective is the expected loss under it less
     ``exit_entropy_weight`` times its entropy.  Returns ``(objective,
     stats)`` then, ``stats`` [2, R] float32: the mean loss of each pass
-    and the mean exit mass of each pass."""
-    x, aux = forward_local(params, tokens, cfg, n_model, data_axis,
-                           model_axis)
-    w = params["unembed"]  # [E, V_loc]
+    and the mean exit mass of each pass.  A model with routed expert
+    layers returns ``(loss, stats)`` too, ``stats`` being
+    :func:`forward_local`'s."""
+    x, stats = forward_local(params, tokens, cfg, n_model, data_axis,
+                             model_axis)
+    if cfg.tied_embeddings:
+        # this rank's rows of the table, transposed: [E, V_loc]
+        V_loc = cfg.vocab // n_model
+        w = jax.lax.dynamic_slice_in_dim(
+            params["embed"], jax.lax.axis_index(model_axis) * V_loc,
+            V_loc).T
+    else:
+        w = params["unembed"]  # [E, V_loc]
 
     def chunk_nll(x_c, t_c):
         """[B, Tc, E] hidden + [B, Tc] global targets -> [B, Tc] nll.
@@ -514,8 +619,7 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
         return (gmax + jnp.log(denom)) - t_logit
 
     if cfg.loop_steps > 1:
-        return looped_loss(x, aux, targets, params, chunk_nll, cfg,
-                           data_axis)
+        return looped_loss(x, targets, params, chunk_nll, cfg, data_axis)
 
     # everything from the unembedding on is the loss stage: chunk_nll
     # is traced where it is called, inside the scope
@@ -536,8 +640,9 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
                 lambda _, xt: (None, chunk_nll(*xt)))
             _, nll_chunks = jax.lax.scan(body, None, (xs, ts))
             nll = jnp.moveaxis(nll_chunks, 0, 1).reshape(B, T)
-        total = nll.mean() + jnp.float32(cfg.moe_aux_weight) * aux
-    return jax.lax.pmean(total, data_axis)
+        total = nll.mean()
+    loss = jax.lax.pmean(total, data_axis)
+    return loss if stats is None else (loss, stats)
 
 
 class TransformerTrainer:
@@ -578,15 +683,21 @@ class TransformerTrainer:
         def sharded_loss(params, tokens, targets):
             return loss_local(params, tokens, targets, cfg, n_model)
 
-        # a looped model's loss comes with its per-pass statistics
-        # (loss_local); step_opt returns them after the loss.  The SGD
-        # step and the fused steps stay the dense model's (step refuses
-        # a looped one): no caller trains a looped model through them
-        looped = cfg.loop_steps > 1
+        # a looped model's loss comes with its per-pass statistics, a
+        # model with routed expert layers' with theirs (loss_local);
+        # step_opt returns them after the loss.  The SGD step and the
+        # fused steps stay the dense model's (step refuses the others):
+        # no caller trains a looped or routed model through them
+        with_stats = self.with_stats = (cfg.loop_steps > 1
+                                        or bool(cfg.moe_layers))
         loss_fn = jax.shard_map(
             sharded_loss, mesh=mesh,
             in_specs=(pspecs, tok_spec, tok_spec),
-            out_specs=(P(), P()) if looped else P())
+            out_specs=(P(), {"loads": P(),
+                             "chosen": P(None, None, "data", None),
+                             "weights": P(None, None, "data", None)}
+                       if cfg.moe_layers else P())
+            if with_stats else P())
 
         def train_step(params, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(
@@ -631,14 +742,18 @@ class TransformerTrainer:
             import optax
 
             def train_step_opt(params, opt_state, tokens, targets):
-                out, grads = jax.value_and_grad(loss_fn, has_aux=looped)(
+                out, grads = jax.value_and_grad(loss_fn, has_aux=with_stats)(
                     params, tokens, targets)
                 with jax.named_scope("tf.update"):
                     updates, opt_state = optimizer.update(
                         grads, opt_state, params)
+                    # a buffer's gradient is zero; weight decay is not
+                    updates = {n: jnp.zeros_like(u) if n.endswith(BUFFERS)
+                               else u for n, u in updates.items()}
                     params = optax.apply_updates(params, updates)
-                # a looped model: (loss, stats)
-                return (params, opt_state, *(out if looped else (out,)))
+                # a looped or routed model: (loss, stats)
+                return (params, opt_state,
+                        *(out if with_stats else (out,)))
 
             self._train_step_opt = _compile_obs.wrap_jit(
                 train_step_opt, program="tf_step_opt",
@@ -699,13 +814,14 @@ class TransformerTrainer:
         """One SGD step; returns (params, loss) without waiting for the
         device.  Spans ``train_step ⊃ {place_batch, dispatch}``: the two
         ``device_put``s, and the call into the ledgered jit (which
-        returns once the program is enqueued).  A looped model trains
-        through :meth:`step_opt`, which returns its per-pass statistics;
-        this step refuses one."""
-        if self.cfg.loop_steps > 1:
+        returns once the program is enqueued).  A looped model, or one
+        with routed expert layers, trains through :meth:`step_opt`, which
+        returns its statistics; this step refuses one."""
+        if self.with_stats:
             raise RuntimeError(
-                "a looped model (loop_steps > 1) trains through step_opt; "
-                "the SGD step carries no per-pass statistics")
+                "a looped model (loop_steps > 1) or one with routed "
+                "expert layers trains through step_opt; the SGD step "
+                "carries no statistics")
         with TRACER.span("train_step"):
             with TRACER.span("place_batch"):
                 x, y = self.place_batch(tokens)
@@ -732,6 +848,29 @@ class TransformerTrainer:
             _EXIT_MASS.set(float(stats[1, t]), **{"pass": t + 1})
         return stats
 
+    def observe_experts(self, stats) -> np.ndarray:
+        """Read a routed step's ``stats["loads"]`` (one row an expert
+        layer: the pairs each held expert took, the pairs not placed,
+        the pairs routed) back to the host — which waits for the step;
+        ``stats["chosen"]`` and ``stats["weights"]``, every token's
+        experts and their weights, stay on the device for whoever asks —
+        and count
+        ``mrtpu_moe_pairs_held_total`` and
+        ``mrtpu_moe_dropped_pairs_total``, set
+        ``mrtpu_moe_expert_load_max_over_mean{layer}`` and
+        ``mrtpu_moe_pairs_held_share{layer}`` from it; returns it as
+        numpy."""
+        stats = np.asarray(stats["loads"])
+        loads = stats[:, :STAT_DROPPED]
+        _MOE_PAIRS.inc(int(loads.sum()))
+        _MOE_DROPPED.inc(int(stats[:, STAT_DROPPED].sum()))
+        for layer, row, routed in zip(self.cfg.moe_layers, loads,
+                                      stats[:, STAT_ROUTED]):
+            _MOE_LOAD.set(float(row.max() / max(row.mean(), 1e-9)),
+                          layer=layer)
+            _MOE_SHARE.set(float(row.sum() / routed), layer=layer)
+        return stats
+
     # -- optimizer (optax) path -----------------------------------------
 
     def _need_tx(self):
@@ -748,8 +887,9 @@ class TransformerTrainer:
 
     def step_opt(self, params: Params, opt_state, tokens: np.ndarray):
         """One optimizer step; returns (params, opt_state, loss), and
-        for a looped model ``stats`` after the loss, as
-        :meth:`observe_passes` takes it."""
+        ``stats`` after the loss for a looped model, as
+        :meth:`observe_passes` takes it, or one with routed expert
+        layers, as :meth:`observe_experts` does."""
         self._need_tx()
         with TRACER.span("train_step", optimizer=True):
             with TRACER.span("place_batch"):
@@ -771,13 +911,29 @@ class TransformerTrainer:
                f"d{c.head_dim}.f{c.ffn}.moe{c.moe_experts}")
         block = (c.loop_steps, c.rope_theta, c.ffn_gated, c.sandwich_norm,
                  c.final_norm)
-        if block != (1, None, False, False, False):
+        layers = (c.layer_ops, c.layer_ffns, c.n_kv_heads, c.qk_norm,
+                  c.norm_eps, c.tied_embeddings, c.moe_top_k, c.moe_ffn,
+                  c.moe_held, c.moe_held_offset, c.moe_router_bias)
+        patterned = layers != ((), (), 0, False, 1e-6, False, 1, 0, 0, 0,
+                               False)
+        if patterned or block != (1, None, False, False, False):
             # passes and rotary base change no shape: the same tensors
             # would load into another function.  A dense block keeps the
             # tag its checkpoints were written under
             tag += (f".loop{c.loop_steps}.rope{c.rope_theta}."
                     f"gated{int(c.ffn_gated)}.sandwich"
                     f"{int(c.sandwich_norm)}.final{int(c.final_norm)}")
+        if patterned:
+            # which layer is of which kind, which experts are held and
+            # how a token is routed: a held range moved by its offset
+            # has the same shapes and other experts
+            kinds = "".join(op[0] + ffn[0] for op, ffn in map(
+                c.layer_kind, range(c.n_layers)))
+            tag += (f".kinds{kinds}.taps{c.conv_taps}.kv{c.kv_heads}."
+                    f"qkn{int(c.qk_norm)}.eps{c.norm_eps}."
+                    f"tied{int(c.tied_embeddings)}.top{c.moe_top_k}."
+                    f"xf{c.moe_ffn}.held{c.experts_held}at"
+                    f"{c.moe_held_offset}.bias{int(c.moe_router_bias)}")
         return tag
 
     def save(self, path: str, params: Params, step: int = 0,

@@ -37,6 +37,29 @@ class Exchanged(NamedTuple):
     #                       traffic matrix (obs/comms)
 
 
+def routing_plan(dest: jax.Array, n_dest: int,
+                 impl: str = "lax") -> Tuple[jax.Array, jax.Array]:
+    """The routing plan of rows bound for ``dest [N] int32``: ``(rank
+    [N], counts [n_dest])``, each row's rank among the rows of its
+    destination (in row order) and the rows each destination gets.  A
+    row whose ``dest`` is ``n_dest`` or more goes nowhere: it is counted
+    for no destination and its rank means nothing.  The exchange packs
+    its send buffer by it, and the routed expert layer (models/moe.py)
+    puts (token, expert) pairs into expert order by it: ``counts`` is the
+    experts' load.  ``impl`` as in :func:`partition_exchange`."""
+    if impl == "radix":
+        # fused plan: one histogram kernel pass feeds both outputs
+        from ..ops.radix_sort import radix_partition_plan
+        return radix_partition_plan(dest, n_dest)
+    # one-hot cumsum: rank[i] = #{j < i : dest[j] == dest[i]}
+    # (O(N * n_dest) elementwise, n_dest small; avoids a sort)
+    onehot = (dest[:, None] == jnp.arange(n_dest)[None, :]).astype(jnp.int32)
+    rank = jnp.take_along_axis(
+        jnp.cumsum(onehot, axis=0) - 1,
+        jnp.clip(dest, 0, n_dest - 1)[:, None], axis=1)[:, 0]
+    return rank, onehot.sum(axis=0)
+
+
 def partition_exchange(keys: jax.Array, values: jax.Array,
                        payload: jax.Array, valid: jax.Array,
                        axis_name: str, capacity: int,
@@ -91,20 +114,7 @@ def partition_exchange(keys: jax.Array, values: jax.Array,
         dest = pmap[bucket].astype(jnp.int32)
     dest = jnp.where(valid, dest, P)  # invalid -> out-of-range, dropped
 
-    # rank of each row within its destination bucket; counts[d] = rows
-    # wanted per destination (this device's traffic-matrix row)
-    if impl == "radix":
-        # fused plan: one histogram kernel pass feeds both outputs
-        from ..ops.radix_sort import radix_partition_plan
-        rank, counts = radix_partition_plan(dest, P)
-    else:
-        # one-hot cumsum: rank[i] = #{j < i : dest[j] == dest[i]}
-        # (O(N*P) elementwise — P is the mesh size, small; avoids a sort)
-        onehot = (dest[:, None] == jnp.arange(P)[None, :]).astype(jnp.int32)
-        rank = jnp.take_along_axis(
-            jnp.cumsum(onehot, axis=0) - 1,
-            jnp.clip(dest, 0, P - 1)[:, None], axis=1)[:, 0]
-        counts = onehot.sum(axis=0)
+    rank, counts = routing_plan(dest, P, impl)
     overflow = jnp.maximum(counts - capacity, 0).sum()
 
     def scatter(arr, fill=0):

@@ -1,0 +1,363 @@
+"""The pieces PR 33 added under the trainer: the grouped products' kernels
+(``ops/grouped_matmul.py``), the routed expert layer and its row order
+(``models/moe.py``), the gated short convolution and grouped-query
+attention (``models/operators.py``), and the flash kernels at head width
+64.  CPU, toy sizes, seeded; the kernels run under the Pallas interpreter
+here and are compiled for a described v5e at the cell's widths in the
+last tests of the file (no number here is a device number).
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P, SingleDeviceSharding
+
+from mapreduce_tpu.models import moe, operators
+from mapreduce_tpu.models.transformer import (TransformerConfig,
+                                              init_transformer, loss_local,
+                                              transformer_param_spec)
+from mapreduce_tpu.obs.metrics import REGISTRY
+from mapreduce_tpu.ops.flash_attention import flash_attention
+from mapreduce_tpu.ops.grouped_matmul import _grouped, grouped_matmul
+from mapreduce_tpu.parallel import make_mesh
+
+RNG = np.random.default_rng(5)
+
+
+# -- the row order ----------------------------------------------------------
+
+#: pairs' destinations among 3 held experts (3 = landed elsewhere)
+DESTS = {
+    "mixed": RNG.integers(0, 4, size=100),
+    "all held": RNG.integers(0, 3, size=96),
+    "none held": np.full(64, 3),
+    "one expert takes every pair": np.zeros(80, np.int64),
+    "an expert takes none": RNG.choice([0, 2, 3], size=70),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DESTS))
+def test_expert_order_places_every_held_pair_once(name):
+    dest, G, bm = jnp.asarray(DESTS[name], jnp.int32), 3, 16
+    plan = moe.routing_plan(dest, G)
+    counts = np.asarray(plan[1])
+    d = np.asarray(dest)
+    n = len(d)
+    assert moe.tiles_for(n, G, bm) == -(-n // bm) + G    # room for every pair
+    pos, pair_of_row, tile_group, n_tiles = map(np.asarray, moe.expert_order(
+        dest, plan, G, bm, moe.tiles_for(n, G, bm)))
+    M = len(pair_of_row)
+    assert counts.tolist() == [(d == g).sum() for g in range(G)]
+    held = d < G
+    assert (pos[~held] == M).all() and (pos[held] < M).all()
+    assert len(set(pos[held])) == held.sum()      # no two pairs share a row
+    assert (pair_of_row[pos[held]] == np.nonzero(held)[0]).all()
+    assert (pair_of_row < n).sum() == held.sum()  # nothing dropped, no ghost
+    # a pair's row lies in a tile of its expert, in use; an expert's
+    # rows keep the pairs' order; every expert owns a tile
+    tile = pos[held] // bm
+    assert (tile_group[tile] == d[held]).all() and (tile < n_tiles[0]).all()
+    for g in range(G):
+        rows = pos[d == g]
+        assert (np.diff(rows) == 1).all() and (
+            len(rows) == 0 or rows[0] % bm == 0)
+        assert (tile_group[:n_tiles[0]] == g).sum() == max(
+            1, -(-len(rows) // bm))
+
+
+# -- the grouped products ---------------------------------------------------
+
+
+def _grouped_case(name, K, N):
+    dest, G, bm = jnp.asarray(DESTS[name], jnp.int32), 3, 16
+    pos, pair_of_row, tile_group, n_tiles = moe.expert_order(
+        dest, moe.routing_plan(dest, G), G, bm,
+        moe.tiles_for(len(DESTS[name]), G, bm))
+    M = pair_of_row.shape[0]
+    filled = (np.asarray(pair_of_row) < len(DESTS[name]))[:, None]
+    x = jnp.asarray(np.where(filled, RNG.normal(size=(M, K)), 0.0),
+                    jnp.float32)
+    w = jnp.asarray(RNG.normal(size=(G, K, N)), jnp.float32)
+    live = np.arange(M)[:, None] < int(n_tiles[0]) * bm
+    return x, w, tile_group, n_tiles, bm, live
+
+
+@pytest.mark.parametrize("name", sorted(DESTS))
+def test_grouped_kernels_equal_ragged_dot_and_the_dense_product(name):
+    x, w, tile_group, n_tiles, bm, live = _grouped_case(name, 32, 48)
+
+    def run(kernels):
+        def f(x, w):
+            # grouped_matmul's two paths: the kernels, interpreted, and
+            # what the trainer runs off the TPU
+            y = _grouped(x, w, tile_group, n_tiles, bm, kernels, True)
+            y = jnp.where(live, y, 0.0)    # rows past the tiles in use
+            return (y * jnp.cos(y)).sum(), y
+
+        (_, y), (dx, dw) = jax.value_and_grad(f, argnums=(0, 1),
+                                              has_aux=True)(x, w)
+        return np.asarray(y), np.where(live, dx, 0.0), np.asarray(dw)
+
+    kernel, ragged = run(True), run(False)
+    for a, b in zip(kernel, ragged):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-3)
+    by_row = np.asarray(w)[np.repeat(np.asarray(tile_group), bm)]
+    dense = np.einsum("mk,mkn->mn", np.asarray(x), by_row)
+    np.testing.assert_allclose(kernel[0], np.where(live, dense, 0.0),
+                               rtol=1e-4, atol=1e-3)
+    assert REGISTRY.sum("mrtpu_pallas_kernel_builds_total",
+                        kernel="moe_gmm", mode="interpret") > 0
+    assert REGISTRY.sum("mrtpu_pallas_kernel_builds_total",
+                        kernel="moe_tgmm", mode="interpret") > 0
+
+
+def test_grouped_matmul_refuses_rows_that_are_not_the_tables():
+    x, w, tile_group, n_tiles, bm, _ = _grouped_case("mixed", 16, 16)
+    with pytest.raises(ValueError, match="tiles of"):
+        grouped_matmul(x[:-bm], w, tile_group, n_tiles, block_m=bm)
+
+
+# -- the routed layer: the shares add up -------------------------------------
+
+SHARE = dict(vocab=32, embed=32, n_layers=1, n_heads=2, head_dim=16, ffn=32,
+             dtype=jnp.float32, flash=False, layer_ffns=("moe",),
+             moe_experts=64, moe_top_k=4, moe_ffn=16, moe_held=8,
+             moe_router_bias=True)
+
+
+def _layer_on_one_device(cfg, h, lp):
+    mesh = make_mesh(devices=jax.devices()[:1])
+    f = jax.shard_map(
+        lambda h, lp: moe.routed_experts(h, lp, cfg, 1, "data", "model"),
+        mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P(), P(), P()))
+    return jax.jit(f)(h, lp)
+
+
+def test_the_eight_shares_of_a_layer_sum_to_the_uncut_reference():
+    """8 of 64 experts a share: nothing is computed alike on every chip
+    but the router, so the eight partial outputs add up to the whole
+    layer, and the loads to every pair routed."""
+    from benchmark import reference_lfm2moe
+
+    E, X, Fe = SHARE["embed"], SHARE["moe_experts"], SHARE["moe_ffn"]
+    h = jnp.asarray(RNG.normal(size=(2, 24, E)), jnp.float32)
+    full = {"w_router": jnp.asarray(RNG.normal(size=(E, X)), jnp.float32),
+            "router_bias": jnp.asarray(0.1 * RNG.normal(size=X), jnp.float32),
+            "moe_w_gate": jnp.asarray(RNG.normal(size=(X, E, Fe)) / 6,
+                                      jnp.float32),
+            "moe_w_in": jnp.asarray(RNG.normal(size=(X, E, Fe)) / 6,
+                                    jnp.float32),
+            "moe_w_out": jnp.asarray(RNG.normal(size=(X, Fe, E)) / 4,
+                                     jnp.float32)}
+    with jax.default_matmul_precision("highest"):
+        want, (want_chosen, _, want_loads) = reference_lfm2moe.routed_layer(
+            h.reshape(-1, E), full["w_router"], full["router_bias"],
+            full["moe_w_gate"], full["moe_w_in"], full["moe_w_out"],
+            top_k=4)
+    total, loads = 0.0, []
+    for share in range(8):
+        cfg = TransformerConfig(moe_held_offset=8 * share, **SHARE)
+        lp = dict(full, **{n: full[n][8 * share:8 * share + 8]
+                           for n in ("moe_w_gate", "moe_w_in", "moe_w_out")})
+        out, stats, chosen, _ = _layer_on_one_device(cfg, h, lp)
+        total = total + np.asarray(out)
+        loads += np.asarray(stats)[:moe.STAT_DROPPED].tolist()
+        assert np.asarray(stats)[moe.STAT_DROPPED] == 0
+        assert np.asarray(stats)[moe.STAT_ROUTED] == 2 * 24 * 4
+        assert (np.sort(np.asarray(chosen).reshape(-1, 4))
+                == np.sort(np.asarray(want_chosen))).all()
+    np.testing.assert_allclose(total.reshape(-1, E), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert loads == np.asarray(want_loads).tolist()
+    assert sum(loads) == 2 * 24 * 4
+
+
+# -- grouped-query attention --------------------------------------------------
+
+GQA = dict(vocab=64, embed=64, n_layers=1, n_heads=4, head_dim=64, ffn=64,
+           dtype=jnp.float32, rope_theta=1e4)
+GQA_TOKENS = RNG.integers(0, 64, size=(2, 65)).astype(np.int32)
+
+
+def _loss_and_grads(cfg, params):
+    mesh = make_mesh(devices=jax.devices()[:1])
+    f = jax.shard_map(
+        lambda p, x, y: loss_local(p, x, y, cfg, 1), mesh=mesh,
+        in_specs=({n: transformer_param_spec(n) for n in params},
+                  P(None, "data"), P(None, "data")), out_specs=P())
+    loss, grads = jax.jit(jax.value_and_grad(f))(
+        params, GQA_TOKENS[:, :-1], GQA_TOKENS[:, 1:])
+    return float(loss), {n: np.asarray(g) for n, g in grads.items()}
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["jnp", "flash"])
+def test_grouped_query_attention_is_attention_over_repeated_heads(flash):
+    """2 key/value heads under 4 query heads against the block's fused
+    multi-head attention whose K and V projections are the two heads'
+    repeated: the loss to rounding, and the gradient of a key/value head
+    the sum over the query heads it serves (head width 64, the kernels
+    interpreted)."""
+    grouped = TransformerConfig(n_kv_heads=2, flash=flash, **GQA)
+    fused = TransformerConfig(flash=flash, **GQA)
+    gp = init_transformer(jax.random.key(2), grouped)
+    E, H, Hkv, D = 64, 4, 2, 64
+    kv = gp["L0.wkv"].reshape(E, 2, Hkv, D)
+    repeated = jnp.repeat(kv, H // Hkv, axis=2).reshape(E, 2, H * D)
+    fp = {n: a for n, a in gp.items() if n not in ("L0.wq", "L0.wkv")}
+    fp["L0.wqkv"] = jnp.concatenate([gp["L0.wq"][:, None], repeated], axis=1)
+    loss_g, grads_g = _loss_and_grads(grouped, gp)
+    loss_f, grads_f = _loss_and_grads(fused, fp)
+    assert abs(loss_g - loss_f) < 1e-5 * abs(loss_f)
+    np.testing.assert_allclose(grads_g["L0.wq"], grads_f["L0.wqkv"][:, 0],
+                               rtol=1e-4, atol=1e-6)
+    summed = grads_f["L0.wqkv"][:, 1:].reshape(E, 2, Hkv, H // Hkv, D).sum(3)
+    np.testing.assert_allclose(grads_g["L0.wkv"].reshape(E, 2, Hkv, D),
+                               summed, rtol=1e-4, atol=1e-6)
+
+
+def test_flash_kernels_at_head_width_64_equal_plain_attention():
+    q, k, v = (jnp.asarray(RNG.normal(size=(1, 2, 128, 64)), jnp.float32)
+               for _ in range(3))
+
+    def plain(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 8.0
+        mask = jnp.tril(jnp.ones((128, 128), bool))
+        return jnp.einsum("bhqk,bhkd->bhqd",
+                          jax.nn.softmax(jnp.where(mask, s, -jnp.inf)), v)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64,
+                               block_kv=64, interpret=True)
+
+    w = jnp.asarray(RNG.normal(size=(1, 2, 128, 64)), jnp.float32)
+    for f, g in zip(
+            jax.value_and_grad(lambda *a: (kernel(*a) * w).sum(),
+                               argnums=(0, 1, 2))(q, k, v)[1],
+            jax.value_and_grad(lambda *a: (plain(*a) * w).sum(),
+                               argnums=(0, 1, 2))(q, k, v)[1]):
+        np.testing.assert_allclose(f, g, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(kernel(q, k, v), plain(q, k, v), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_the_head_norm_scales_each_head_before_the_rotation():
+    """``qk_norm``: q and k leave ``grouped_qkv`` with every head's 64
+    dims at unit mean square times the scale, rotated after."""
+    cfg = TransformerConfig(n_kv_heads=2, qk_norm=True, norm_eps=1e-5,
+                            flash=True, **{**GQA, "rope_theta": None})
+    lp = {n[3:]: a for n, a in init_transformer(
+        jax.random.key(1), cfg).items() if n.startswith("L0.")}
+    lp["q_norm_scale"] = jnp.full((64,), 2.0)
+    h = jnp.asarray(RNG.normal(size=(1, 16, 64)), jnp.float32)
+    q, k, v = operators.grouped_qkv(h, lp, cfg, 1, None)
+    assert q.shape == k.shape == v.shape == (1, 4, 16, 64)
+    np.testing.assert_allclose(np.square(q).mean(-1), 4.0, rtol=1e-3)
+    np.testing.assert_allclose(np.square(k).mean(-1), 1.0, rtol=1e-3)
+    assert (np.asarray(k[:, 0]) == np.asarray(k[:, 1])).all()  # one group
+
+
+# -- the gated short convolution ---------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _conv_setup():
+    cfg = TransformerConfig(vocab=32, embed=16, n_layers=1, n_heads=2,
+                            head_dim=8, ffn=16, dtype=jnp.float32,
+                            layer_ops=("conv",), conv_taps=3)
+    lp = {n[3:]: a for n, a in init_transformer(
+        jax.random.key(4), cfg).items() if n.startswith("L0.conv")}
+    mesh = make_mesh(n_model=2)                 # 2 x 4: channels x sequence
+    run = jax.jit(jax.shard_map(
+        lambda h, lp: jax.lax.psum(
+            operators.short_conv(h, lp, cfg, "data"), "model"),
+        mesh=mesh, in_specs=(P(None, "data"), {
+            n: transformer_param_spec("L0." + n) for n in lp}),
+        out_specs=P(None, "data")))
+    return lp, run
+
+
+def test_short_conv_equals_a_loop_over_its_taps():
+    """Channels split over 2 model ranks, the sequence over 4 shards (a
+    shard's first two positions read the previous shard's last two)."""
+    lp, run = _conv_setup()
+    h = jnp.asarray(RNG.normal(size=(2, 32, 16)), jnp.float32)
+    b, c, u = (np.asarray(h) @ np.asarray(lp["conv_in"][:, j])
+               for j in range(3))
+    v, w = b * u, np.asarray(lp["conv_w"])
+    y = np.zeros_like(v)
+    for t in range(32):
+        for j in range(3):
+            if t - 2 + j >= 0:
+                y[:, t] += w[:, j] * v[:, t - 2 + j]
+    want = (c * y) @ np.asarray(lp["conv_out"])
+    np.testing.assert_allclose(run(h, lp), want, rtol=1e-4, atol=1e-5)
+
+
+def test_short_conv_position_t_never_reads_t_plus_1():
+    lp, run = _conv_setup()
+    h = jnp.asarray(RNG.normal(size=(1, 32, 16)), jnp.float32)
+    for t in (0, 7, 8, 30):                     # 7 | 8: a shard boundary
+        moved = h.at[:, t + 1:].add(1.0)
+        a, b = np.asarray(run(h, lp)), np.asarray(run(moved, lp))
+        assert (a[:, :t + 1] == b[:, :t + 1]).all()
+        assert not (a[:, t + 1] == b[:, t + 1]).all()
+
+
+# -- compiled for the chip, at the cell's widths ------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Off the TPU the kernels default to the interpreter."""
+    from mapreduce_tpu.ops import flash_attention as fa, grouped_matmul as gm
+
+    for module in (fa, gm):
+        monkeypatch.setattr(module, "default_interpret", lambda i=None: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_grouped_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip,
+                                                               mosaic):
+    """2048 x 1536, 264 tiles of 512 rows, 8 experts: forward, the rows'
+    gradient and the weights' gradient; the grids' bound is a value."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def f(x, w, tile_group, n_tiles):
+        g = lambda x, w: grouped_matmul(
+            x, w, tile_group, n_tiles, block_m=512).astype(jnp.float32).sum()
+        return jax.grad(g, argnums=(0, 1))(x, w)
+
+    text = jax.jit(f).lower(
+        sds((264 * 512, 2048), jnp.bfloat16), sds((8, 2048, 1536),
+                                                  jnp.float32),
+        sds((264,), jnp.int32), sds((1,), jnp.int32)).compile().as_text()
+    assert text.count("moe_gmm") >= 2 and "moe_tgmm" in text
+
+
+def test_flash_kernels_compile_for_a_v5e_at_head_width_64(one_chip, mosaic):
+    x = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    f = lambda q, k, v: flash_attention(q, k, v, causal=True).astype(
+        jnp.float32).sum()
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert "flash_fwd" in text and "flash_dkv" in text

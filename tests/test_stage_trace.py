@@ -34,6 +34,10 @@ TF_SCOPES = ["tf.embed", "tf.attn_proj", "tf.flash", "tf.ffn", "tf.loss",
 #: tests/test_looplm.py finds each in that program's stage map)
 LOOP_SCOPES = ["tf.rope", "tf.exit_gate", "tf.pass_loop",
                "tf.final_norm"]
+#: scopes only a program with layers of several kinds and routed experts
+#: has (PR 33; tests/test_lfm2moe.py finds each in its stage map)
+MOE_SCOPES = ["tf.conv_op", "tf.qk_norm", "tf.moe_route", "tf.moe_dispatch",
+              "tf.moe_experts", "tf.moe_combine"]
 
 
 def load(*parts):
@@ -466,7 +470,8 @@ def test_metric_file_has_a_reader_and_waits_if_run_py_has_none(name):
     assert d["source"] == ("program_span" if mine == ["program_span"]
                            else "device_trace")
     if "stage" in d["read"] and d["read"]["stage"] != stages.UNSCOPED:
-        assert d["read"]["stage"] in WAVE_SCOPES + TF_SCOPES + LOOP_SCOPES
+        assert d["read"]["stage"] in (WAVE_SCOPES + TF_SCOPES + LOOP_SCOPES
+                                      + MOE_SCOPES)
     for cell in d["workloads"]:
         assert d in stage_report.metric_files(cell)
 
@@ -487,6 +492,12 @@ def test_the_waiting_metric_files_are_the_issues():
         "wc.exchange_share", "wc.fold_share", "wc.sort_share",
         "wc.segreduce_share", "wc.compact_share", "wc.unscoped_share",
         "wc.idle_split_share", "wc.idle_materialize_share"}
+    assert {n for n in waiting if n.startswith("moe.")} == {
+        "moe.route_share", "moe.dispatch_share", "moe.experts_share",
+        "moe.combine_share", "moe.conv_op_share", "moe.qk_norm_share",
+        "moe.attn_proj_share", "moe.ffn_share", "moe.rope_share",
+        "moe.loss_share", "moe.update_share", "moe.unscoped_share",
+        "moe.dispatch_ms", "moe.gmm_roofline"}
 
 
 # -- (h) the join, end to end, on the CPU's own trace ------------------------

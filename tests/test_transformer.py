@@ -209,54 +209,6 @@ def test_transformer_loss_block_matches_unchunked():
     assert np.allclose(results[None], results[2], rtol=1e-6), results
 
 
-def test_moe_single_expert_equals_dense():
-    """moe_experts=1 on a 1-rank model axis must reproduce the dense FFN
-    exactly: gate = softmax over one logit = 1, capacity covers every
-    token, and the single 'expert' IS the full dense FFN."""
-    from dataclasses import replace
-
-    mesh = make_mesh(n_model=1)
-    base = TransformerConfig(vocab=32, embed=32, n_layers=2, n_heads=4,
-                             head_dim=8, ffn=64, dtype=jnp.float32)
-    rng = np.random.default_rng(4)
-    toks = _batch(rng, base, B=2, T=16)
-
-    losses = {}
-    for n_exp in (0, 1):
-        cfg = replace(base, moe_experts=n_exp)
-        trainer = TransformerTrainer(mesh, cfg, learning_rate=1e-2)
-        params = trainer.init_params()
-        _, loss = trainer.step(params, toks)
-        losses[n_exp] = float(loss)
-    assert np.allclose(losses[0], losses[1], rtol=1e-6), losses
-
-
-def test_moe_expert_parallel_trains():
-    """2 experts over a 2-rank model axis x 4-way sequence parallelism:
-    the expert-parallel transformer must actually learn."""
-    mesh = make_mesh(n_model=2)
-    cfg = TransformerConfig(vocab=32, embed=64, n_layers=2, n_heads=4,
-                            head_dim=16, ffn=128, moe_experts=2)
-    trainer = TransformerTrainer(mesh, cfg, learning_rate=3e-2)
-    params = trainer.init_params()
-    assert "L0.w_router" in params
-    rng = np.random.default_rng(0)
-    losses = []
-    for it in range(80):
-        toks = _batch(rng, cfg, B=8, T=32)
-        params, loss = trainer.step(params, toks)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0] * 0.5, (losses[0], losses[-1])
-
-
-def test_moe_requires_expert_per_rank():
-    mesh = make_mesh(n_model=2)
-    cfg = TransformerConfig(vocab=32, embed=32, n_heads=2, head_dim=8,
-                            ffn=64, moe_experts=4)  # != n_model
-    with pytest.raises(AssertionError, match="expert"):
-        TransformerTrainer(mesh, cfg)
-
-
 def test_checkpoint_roundtrip_and_reshard(tmp_path):
     """Transformer checkpoints: save mid-training, reload, continue —
     losses must continue the saved trajectory exactly; and a checkpoint
