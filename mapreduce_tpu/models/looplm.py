@@ -54,15 +54,20 @@ def looped_loss(hs, targets, params, chunk_nll, cfg, data_axis: str):
         raise ValueError(f"loss_block {Tc} must divide T_local {T}")
     C = T // Tc
 
-    def trip(_, xt):
-        x_c, t_c = xt
-        nll = chunk_nll(x_c, t_c)
+    # checkpointed, so that a trip keeps its bfloat16 rows for the
+    # gate's backward pass and not their float32 copy; the loss keeps
+    # what its own rule says (transformer.chunk_nll)
+    @jax.checkpoint
+    def gate_logit(x_c):
         with jax.named_scope("tf.exit_gate"):
-            gate = jnp.einsum("bte,e->bt", x_c.astype(jnp.float32),
+            return jnp.einsum("bte,e->bt", x_c.astype(jnp.float32),
                               params["exit_w"],
                               precision=jax.lax.Precision.HIGHEST
                               ) + params["exit_b"][0]
-        return None, (nll, gate)
+
+    def trip(_, xt):
+        x_c, t_c = xt
+        return None, (chunk_nll(x_c, t_c), gate_logit(x_c))
 
     with jax.named_scope("tf.loss"):
         xs = jnp.moveaxis(hs.reshape(R, B, C, Tc, E), 2, 1)
@@ -70,7 +75,7 @@ def looped_loss(hs, targets, params, chunk_nll, cfg, data_axis: str):
             jnp.moveaxis(targets.reshape(B, C, Tc), 1, 0)[None],
             (R, C, B, Tc))
         _, (nll, gate) = jax.lax.scan(
-            jax.checkpoint(trip), None,
+            trip, None,
             (xs.reshape(R * C, B, Tc, E), ts.reshape(R * C, B, Tc)))
         nll, gate = (jnp.moveaxis(a.reshape(R, C, B, Tc), 1, 2)
                      .reshape(R, B, T) for a in (nll, gate))

@@ -19,6 +19,7 @@ Everything is bf16 matmuls on the MXU with f32 accumulators/params.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
@@ -50,6 +51,13 @@ _REMAT_KEPT = _metrics.gauge(
     "flash kernel's output and row statistics of every layer "
     "application; 0 with remat or the kernel off; set at each dispatch "
     "of a step (labels: program)")
+_LOSS_KEPT = _metrics.gauge(
+    "mrtpu_train_loss_kept_bytes",
+    "bytes a device holds from the forward to the backward pass of a "
+    "training step because the loss keeps them beside its inputs: each "
+    "position's float32 log-sum-exp over the vocabulary, after every "
+    "pass of a looped model; set at each dispatch of a step (labels: "
+    "program)")
 _PASS_LOSS = _metrics.gauge(
     "mrtpu_train_loop_pass_loss",
     "a looped model's mean next-token loss after each pass over the "
@@ -561,6 +569,90 @@ def forward_local(params: Params, tokens: jax.Array,
     return hs, None
 
 
+def _logits(x_c, w, dtype):
+    """The unembed matmul is ~20% of model FLOPs at vocab 32k: ``dtype``
+    operands on the MXU, float32 accumulation for the softmax stats."""
+    return jnp.einsum("bte,ev->btv", x_c.astype(dtype), w.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _local_targets(t_c, v_loc: int, model_axis: str):
+    """The GLOBAL targets as columns of this rank's vocabulary shard;
+    one outside ``[0, v_loc)`` is another rank's."""
+    return t_c - jax.lax.axis_index(model_axis) * v_loc
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def chunk_nll(x_c, t_c, w, dtype, model_axis: str):
+    """[B, Tc, E] hidden + [B, Tc] global targets + this rank's head
+    ``w`` [E, V_loc] -> [B, Tc] nll, INSIDE shard_map: the softmax
+    statistics combine with pmax/psum over the model axis.
+
+    It has a backward rule of its own (:func:`_chunk_nll_bwd`): the
+    forward pass keeps, beside the three inputs, each row's
+    log-sum-exp (4 bytes a position, ``mrtpu_train_loss_kept_bytes``),
+    and the backward pass makes the chunk's logits once more and reads
+    the softmax off them, with no maximum, no sum and no collective but
+    the one that completes ``dx``.  ``w`` varies over every mesh axis
+    ``x_c`` does (the caller casts it: the cast's transpose sums its
+    gradient over them)."""
+    return _chunk_nll_fwd(x_c, t_c, w, dtype, model_axis)[0]
+
+
+def _chunk_nll_fwd(x_c, t_c, w, dtype, model_axis):
+    logits = _logits(x_c, w, dtype)
+    # pmax also makes the max invariant over the model axis for vma
+    # inference
+    gmax = jax.lax.pmax(logits.max(axis=-1), model_axis)  # [B, Tc]
+    z = jnp.exp(logits - gmax[..., None])
+    lse = gmax + jnp.log(jax.lax.psum(z.sum(axis=-1), model_axis))
+    # my shard's slice of the one-hot target
+    V_loc = logits.shape[-1]
+    local_t = _local_targets(t_c, V_loc, model_axis)
+    in_shard = (local_t >= 0) & (local_t < V_loc)
+    t_logit = jnp.take_along_axis(
+        logits, jnp.clip(local_t, 0, V_loc - 1)[..., None],
+        axis=-1)[..., 0]
+    t_logit = jax.lax.psum(jnp.where(in_shard, t_logit, 0.0),
+                           model_axis)
+    return lse - t_logit, (x_c, t_c, w, lse)
+
+
+def _chunk_nll_bwd(dtype, model_axis, kept, g):
+    """``d nll / d logits = softmax - onehot``, the softmax as
+    ``exp(logits - lse)`` from the kept row statistics; then the two
+    products, ``dtype`` operands and float32 accumulation as forward.
+    ``dx`` is a partial sum over this rank's columns and ``x_c`` is the
+    same on every rank of the model axis: the psum is the rule's to do
+    (autodiff's was the transpose of the cast it put on ``x_c``)."""
+    x_c, t_c, w, lse = kept
+    logits = _logits(x_c, w, dtype)
+    V_loc = logits.shape[-1]
+    # a compare, not a gather: no scatter in the backward pass; a
+    # target of another rank's equals no column here
+    hot = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+           == _local_targets(t_c, V_loc, model_axis)[..., None])
+    d = (g[..., None] * (jnp.exp(logits - lse[..., None]) - hot)
+         ).astype(dtype)
+    dx = jnp.einsum("btv,ev->bte", d, w.astype(dtype),
+                    preferred_element_type=jnp.float32)
+    dw = jnp.einsum("bte,btv->ev", x_c.astype(dtype), d,
+                    preferred_element_type=jnp.float32)
+    return (jax.lax.psum(dx, model_axis).astype(x_c.dtype), None,
+            dw.astype(w.dtype))
+
+
+chunk_nll.defvjp(_chunk_nll_fwd, _chunk_nll_bwd)
+
+
+def loss_kept_bytes(cfg: TransformerConfig, batch: int,
+                    t_local: int) -> int:
+    """Bytes of row statistics one step's loss keeps on a device for its
+    backward pass: :func:`chunk_nll`'s float32 log-sum-exp of every
+    position, after each of a looped model's passes."""
+    return cfg.loop_steps * batch * t_local * 4
+
+
 def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
                cfg: TransformerConfig, n_model: int,
                data_axis: str = "data", model_axis: str = "model"):
@@ -590,43 +682,25 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
             V_loc).T
     else:
         w = params["unembed"]  # [E, V_loc]
+    # the head is the same on every sequence shard and the hidden state
+    # is not: its gradient sums over them (the cast's transpose)
+    missing = tuple(jax.typeof(x).vma - jax.typeof(w).vma)
+    if missing:
+        w = jax.lax.pcast(w, missing, to="varying")
 
-    def chunk_nll(x_c, t_c):
-        """[B, Tc, E] hidden + [B, Tc] global targets -> [B, Tc] nll.
-        The unembed matmul is ~20% of model FLOPs at vocab 32k: bf16
-        operands on the MXU, f32 accumulation for the softmax stats."""
-        logits = jnp.einsum("bte,ev->btv", x_c.astype(cfg.dtype),
-                            w.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        # stop_gradient BEFORE pmax: the shift is gradient-neutral
-        # (logsumexp identity), pmax has no JVP rule, and as a reduction
-        # it also makes the max invariant over the model axis for vma
-        # inference
-        local_max = jax.lax.stop_gradient(logits.max(axis=-1))  # [B, Tc]
-        gmax = jax.lax.pmax(local_max, model_axis)
-        z = jnp.exp(logits - gmax[..., None])
-        denom = jax.lax.psum(z.sum(axis=-1), model_axis)
-        # my shard's slice of the one-hot target
-        V_loc = logits.shape[-1]
-        shard = jax.lax.axis_index(model_axis)
-        local_t = t_c - shard * V_loc
-        in_shard = (local_t >= 0) & (local_t < V_loc)
-        t_logit = jnp.take_along_axis(
-            logits, jnp.clip(local_t, 0, V_loc - 1)[..., None],
-            axis=-1)[..., 0]
-        t_logit = jax.lax.psum(jnp.where(in_shard, t_logit, 0.0),
-                               model_axis)
-        return (gmax + jnp.log(denom)) - t_logit
+    def nll_of(x_c, t_c):
+        return chunk_nll(x_c, t_c, w, cfg.dtype, model_axis)
 
     if cfg.loop_steps > 1:
-        return looped_loss(x, targets, params, chunk_nll, cfg, data_axis)
+        return looped_loss(x, targets, params, nll_of, cfg, data_axis)
 
     # everything from the unembedding on is the loss stage: chunk_nll
-    # is traced where it is called, inside the scope
+    # is traced where it is called, inside the scope, and its backward
+    # rule under the call's
     with jax.named_scope("tf.loss"):
         Tc = cfg.loss_block
         if Tc is None:
-            nll = chunk_nll(x, targets)
+            nll = nll_of(x, targets)
         else:
             B, T, E = x.shape
             if T % Tc != 0:
@@ -634,11 +708,13 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
             C = T // Tc
             xs = jnp.moveaxis(x.reshape(B, C, Tc, E), 1, 0)
             ts = jnp.moveaxis(targets.reshape(B, C, Tc), 1, 0)
-            # recompute each chunk's logits in the backward pass — full
-            # logits never exist in memory, forward or backward
-            body = jax.checkpoint(
-                lambda _, xt: (None, chunk_nll(*xt)))
-            _, nll_chunks = jax.lax.scan(body, None, (xs, ts))
+            # no jax.checkpoint here: chunk_nll's own rule keeps a
+            # chunk's inputs and row statistics and makes its logits
+            # again: full logits never exist in memory, forward or
+            # backward (a checkpoint would run the forward rule again
+            # in the backward pass, a fifth product a chunk)
+            _, nll_chunks = jax.lax.scan(
+                lambda _, xt: (None, nll_of(*xt)), None, (xs, ts))
             nll = jnp.moveaxis(nll_chunks, 0, 1).reshape(B, T)
         total = nll.mean()
     loss = jax.lax.pmean(total, data_axis)
@@ -831,11 +907,14 @@ class TransformerTrainer:
 
     def _count_step(self, program: str, x: jax.Array) -> None:
         """The step about to be dispatched on inputs *x* [B, T]: its
-        layer applications, and what ``remat`` makes it keep."""
+        layer applications, and what ``remat`` and the loss make it
+        keep."""
         _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers)
+        B, t_local = x.shape[0], x.shape[1] // self.n_data
         _REMAT_KEPT.set(remat_kept_bytes(
-            self.cfg, self.mesh.shape["model"], x.shape[0],
-            x.shape[1] // self.n_data), program=program)
+            self.cfg, self.mesh.shape["model"], B, t_local), program=program)
+        _LOSS_KEPT.set(loss_kept_bytes(self.cfg, B, t_local),
+                       program=program)
 
     def observe_passes(self, stats) -> np.ndarray:
         """Read a looped step's ``stats`` ([2, R]: each pass's mean loss,

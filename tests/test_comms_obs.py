@@ -428,6 +428,12 @@ def test_diagnose_end_to_end_from_live_engine_run(mesh, tmp_path,
         valid = jnp.ones(chunk.shape[0], dtype=bool)
         return keys, vals, pay, valid, jnp.int32(0)
 
+    # a label no other test's run carries: diagnose reads this process's
+    # whole span ring, and a repartition decision another file recorded
+    # on a task of the same name (the bench smoke's "skewed", when
+    # tests/test_profile.py ran on this worker first) reads as "already
+    # acted on"
+    task = "comms-obs-skewed"
     n_dev = mesh.shape["data"]
     rng = np.random.default_rng(5)
     chunks = rng.integers(0, 1 << 10, size=(2 * n_dev, 16)) \
@@ -435,7 +441,7 @@ def test_diagnose_end_to_end_from_live_engine_run(mesh, tmp_path,
     cfg = EngineConfig(local_capacity=256, exchange_capacity=64,
                        out_capacity=256, reduce_op="sum")
     tm = {}
-    DeviceEngine(mesh, hot_map_fn, cfg, task="skewed").run(
+    DeviceEngine(mesh, hot_map_fn, cfg, task=task).run(
         chunks, timings=tm, waves=2, max_retries=0)
     assert tm["exchange_hot_dst"] == 0
     assert tm["exchange_imbalance"] > 2.0
@@ -446,7 +452,7 @@ def test_diagnose_end_to_end_from_live_engine_run(mesh, tmp_path,
                     "t_mono": 0.0})
     doc = collector.cluster_doc()
     report = diagnose(doc)
-    ex = report["comms"]["exchange"]["skewed"]
+    ex = report["comms"]["exchange"][task]
     assert ex["hot_dst"] == "D000"
     assert ex["imbalance_recv"] > 2.0
     assert any("exchange imbalance" in n and "device 0" in n
@@ -460,7 +466,7 @@ def test_diagnose_end_to_end_from_live_engine_run(mesh, tmp_path,
     assert "exchange imbalance" in out and "device 0 receives" in out
     assert cmd_diagnose([str(path), "--json"]) == 0
     parsed = json.loads(capsys.readouterr().out)
-    assert parsed["comms"]["exchange"]["skewed"]["hot_dst"] == "D000"
+    assert parsed["comms"]["exchange"][task]["hot_dst"] == "D000"
 
 
 # -- bundles -----------------------------------------------------------------

@@ -361,3 +361,46 @@ def test_flash_kernels_compile_for_a_v5e_at_head_width_64(one_chip, mosaic):
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
     assert "flash_fwd" in text and "flash_dkv" in text
+
+
+def test_the_chunked_loss_compiles_to_three_products_a_chunk_backward(
+        one_chip, mosaic):
+    """The loss stage of ``train-lfm2moe-8k`` alone (no layer: B 4, T
+    8192, ``loss_block`` 2048, E 2048, vocabulary 8192, tied head).
+    Under ``jax.checkpoint`` the backward pass's recomputed row maximum
+    compiled to a ``reduce-window`` of 16,383 columns over ``[4, 2048,
+    8192]``, 94 ms of that cell's step (PERF.md section 6, PR 34); the
+    rule of ``chunk_nll`` has no maximum to recompute, and a chunk's
+    backward pass is its logits, ``dx`` and ``dw``."""
+    import re
+
+    from jax.sharding import NamedSharding
+
+    cfg = TransformerConfig(vocab=8192, embed=2048, n_layers=0, n_heads=32,
+                            head_dim=64, ffn=2048, loss_block=2048,
+                            tied_embeddings=True)
+    mesh = make_mesh(devices=[next(iter(one_chip.device_set))])
+    shapes = jax.eval_shape(lambda: init_transformer(jax.random.key(0), cfg))
+    specs = {n: transformer_param_spec(n) for n in shapes}
+    tokens = jax.ShapeDtypeStruct(
+        (4, 8192), jnp.int32, sharding=NamedSharding(mesh, P(None, "data")))
+    loss = jax.shard_map(lambda p, x, y: loss_local(p, x, y, cfg, 1),
+                         mesh=mesh,
+                         in_specs=(specs, P(None, "data"), P(None, "data")),
+                         out_specs=P())
+    text = jax.jit(jax.grad(loss)).lower(
+        {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                 sharding=NamedSharding(mesh, specs[n]))
+         for n, a in shapes.items()}, tokens, tokens).compile().as_text()
+    assert "reduce-window(" not in text
+    # the matrix products (the chip's compiler writes them as
+    # convolutions, each the root of a fusion) of each loop body: the
+    # forward scan's, then the backward scan's
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.M | re.S))
+    products = sorted(
+        sum(" convolution(" in computations[called]
+            for called in re.findall(r"calls=%([\w.\-]+)",
+                                     computations[body]))
+        for body in set(re.findall(r"body=%([\w.\-]+)", text)))
+    assert products == [1, 3]
