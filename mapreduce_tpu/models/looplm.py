@@ -13,19 +13,62 @@ here runs INSIDE the trainer's ``shard_map``; ``cfg`` is the
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
-def rope_tables(cfg, t_local: int, data_axis: str):
+def yarn_ramp(cfg) -> np.ndarray:
+    """YaRN's blend of each rotary pair ``i = 0 .. D/2 - 1``, float32 in
+    [0, 1]: 0 keeps the pair's plain frequency, 1 divides it by
+    ``cfg.yarn_factor``.  A pair that turns ``r`` times over the
+    ``cfg.yarn_original_positions`` the model was trained on has index
+    ``c(r) = D ln(original / (2 pi r)) / (2 ln theta)``; the ramp rises
+    linearly from ``floor(c(beta_fast))`` to ``ceil(c(beta_slow))``,
+    clipped to the pairs there are (pairs 18 to 35 of 64 at theta 5e5,
+    8,192 positions, betas 32 and 1)."""
+    D = cfg.head_dim
+
+    def pair_of(turns):
+        return (D * math.log(cfg.yarn_original_positions
+                             / (2 * math.pi * turns))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(pair_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(pair_of(cfg.yarn_beta_slow)), D - 1)
+    if low == high:
+        high += 0.001           # no division by zero: a step, not a ramp
+    return np.clip((np.arange(D // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+
+
+def rope_tables(cfg, t_local: int, data_axis: str, yarn: bool = False):
     """``(cos, sin)``, each [T_local, D/2] float32, of the rotary angles
-    ``position * theta**(-2i/D)`` at this shard's GLOBAL positions."""
+    ``position * inv_freq_i`` at this shard's GLOBAL positions.  Plain:
+    ``inv_freq_i = theta**(-2i/D)``.  With *yarn* (``cfg.yarn_factor`` s
+    over ``cfg.yarn_original_positions``): ``inv_freq_i = (plain_i / s)
+    ramp_i + plain_i (1 - ramp_i)`` with :func:`yarn_ramp`'s blend, and
+    cos and sin both times the attention factor
+    (``cfg.yarn_attention_factor``, else ``0.1 ln s + 1``), which so
+    multiplies q and k alike and the scores by its square."""
     pos = (jax.lax.axis_index(data_axis) * t_local
            + jnp.arange(t_local)).astype(jnp.float32)
     inv_freq = 1.0 / (jnp.float32(cfg.rope_theta) ** (
         jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim))
+    if yarn:
+        ramp = jnp.asarray(yarn_ramp(cfg))
+        inv_freq = (inv_freq / jnp.float32(cfg.yarn_factor)) * ramp \
+            + inv_freq * (1.0 - ramp)
     angle = pos[:, None] * inv_freq[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if not yarn:
+        return cos, sin
+    factor = cfg.yarn_attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(cfg.yarn_factor) + 1.0
+    return cos * jnp.float32(factor), sin * jnp.float32(factor)
 
 
 def apply_rope(x: jax.Array, tables, seq_axis: int) -> jax.Array:

@@ -9,6 +9,8 @@ of the result its own experts give:
     S = the moe_top_k experts with the largest s + b   (b: a buffer, not
                                                         trained; 0 without)
     g_e = s_e / (sum_{e' in S} s_e' + 1e-6)
+    (``moe_router_score="softmax"``: s = softmax(h W_g) over all
+     moe_experts, g_e = s_e / sum_{e' in S} s_e')
     out = sum_{e in S and held here} g_e W2_e (silu(W1_e h) * W3_e h)
 
 ``g`` is normalised over all of ``S``, held or not; what the absent
@@ -40,19 +42,28 @@ BLOCK_M = 512
 STAT_DROPPED, STAT_ROUTED = -2, -1
 
 
-def route(flat: jax.Array, w_router: jax.Array, bias, top_k: int):
+def route(flat: jax.Array, w_router: jax.Array, bias, top_k: int,
+          score: str = "sigmoid"):
     """``(chosen [N, k] int32, weights [N, k] float32)`` of tokens ``flat
-    [N, E]``: the module's ``S`` and ``g``.  The choice carries no
-    gradient; the weights carry the router's."""
-    s = jax.nn.sigmoid(jnp.einsum(
+    [N, E]``: the module's ``S`` and ``g``, the scores ``s`` by *score*:
+    ``"sigmoid"`` of each expert's logit, the weights' denominator with
+    its 1e-6, or ``"softmax"`` over all the experts' logits, the weights
+    the chosen probabilities over their plain sum.  Product and score in
+    float32.  The choice carries no gradient; the weights carry the
+    router's."""
+    logits = jnp.einsum(
         "ne,ex->nx", flat.astype(jnp.float32), w_router,
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        s, eps = jax.nn.softmax(logits, axis=-1), 0.0
+    else:
+        s, eps = jax.nn.sigmoid(logits), 1e-6
     biased = jax.lax.stop_gradient(s)
     if bias is not None:
         biased = biased + jax.lax.stop_gradient(bias)
     _, chosen = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(s, chosen, axis=1)
-    weights = picked / (picked.sum(axis=-1, keepdims=True) + 1e-6)
+    weights = picked / (picked.sum(axis=-1, keepdims=True) + eps)
     return chosen, weights
 
 
@@ -165,7 +176,7 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
     me = jax.lax.axis_index(model_axis)
     with jax.named_scope("tf.moe_route"):
         chosen, weights = route(flat, lp["w_router"], lp.get("router_bias"),
-                                k)
+                                k, cfg.moe_router_score)
         routed = (chosen.reshape(B, T, k), weights.reshape(B, T, k))
         local = chosen - (cfg.moe_held_offset + me * n_loc)
         dest = jnp.where((local >= 0) & (local < n_loc), local,

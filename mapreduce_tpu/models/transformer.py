@@ -44,6 +44,12 @@ _LAYER_APPS = _metrics.counter(
     "mrtpu_train_layer_applications_total",
     "transformer layer applications dispatched: loop_steps x n_layers a "
     "training step")
+_OPERATOR_APPS = _metrics.counter(
+    "mrtpu_train_operator_applications_total",
+    "layer applications dispatched, by the layer's token-mixing operator "
+    "(labels: operator = attn, causal attention over all earlier "
+    "positions; window, over the last attn_window; conv): loop_steps x "
+    "the layers of that kind a training step")
 _REMAT_KEPT = _metrics.gauge(
     "mrtpu_train_remat_kept_bytes",
     "bytes a device holds from the forward to the backward pass of a "
@@ -119,9 +125,10 @@ class TransformerConfig:
     #: None = unchunked; must divide T_local
     loss_block: Any = None
     #: ROUTED EXPERTS (models/moe.py) in the layers ``layer_ffns`` marks
-    #: "moe": a router over ``moe_experts`` sigmoid scores (0 = no expert
-    #: layer) picks ``moe_top_k`` a token, weights renormalised over the
-    #: chosen, each expert a gated FFN of width ``moe_ffn``.  This mesh
+    #: "moe": a router over ``moe_experts`` scores (0 = no expert layer;
+    #: ``moe_router_score``) picks ``moe_top_k`` a token, weights
+    #: renormalised over the chosen, each expert a gated FFN of width
+    #: ``moe_ffn``.  This mesh
     #: HOLDS ``moe_held`` of them (0 = all) from ``moe_held_offset`` on,
     #: split evenly over the model axis, and computes their part of the
     #: result; one held expert a rank is expert parallelism
@@ -133,6 +140,9 @@ class TransformerConfig:
     #: select by score + a per-expert bias (an untrained buffer,
     #: ``L<i>.router_bias``) while weighting by the score alone
     moe_router_bias: bool = False
+    #: the router's score of an expert: "sigmoid" of its logit, or
+    #: "softmax" over all the experts' logits (models/moe.route)
+    moe_router_score: str = "sigmoid"
     #: use the in-tree Pallas flash-attention kernel
     #: (ops/flash_attention.py).  None = auto: the unsharded case
     #: (data axis 1) calls the kernel directly on TPU; the multi-device
@@ -149,8 +159,21 @@ class TransformerConfig:
     #: every pass (loss_local) and the step returns one more array
     loop_steps: int = 1
     #: rotary position embedding on q and k (rotate-half over all of
-    #: head_dim, no scaling) with this base; None = no position encoding
+    #: head_dim) with this base; None = no position encoding
     rope_theta: Any = None
+    #: YaRN scaling of the rotary tables of the "attn" layers (causal
+    #: attention over ALL earlier positions; "window" layers keep the
+    #: plain tables): positions stretched ``yarn_factor`` times (0 = no
+    #: scaling) over the ``yarn_original_positions`` the model was
+    #: trained on, pair by pair between ``yarn_beta_fast`` and
+    #: ``yarn_beta_slow`` turns, cos and sin times
+    #: ``yarn_attention_factor`` (None = 0.1 ln factor + 1)
+    #: (models/looplm.rope_tables)
+    yarn_factor: float = 0.0
+    yarn_original_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: Any = None
     #: gated three-matrix FFN, silu(h w_gate) * (h w_in) then w_out;
     #: False = the two-matrix GELU FFN
     ffn_gated: bool = False
@@ -162,13 +185,17 @@ class TransformerConfig:
     #: weight of the exit distribution's negative entropy in the looped
     #: objective (read only when loop_steps > 1)
     exit_entropy_weight: float = 0.1
-    #: each layer's token-mixing operator, "attn" (causal attention) or
-    #: "conv" (models/operators.short_conv, ``conv_taps`` taps a
-    #: channel), and each layer's FFN, "dense" or "moe"; () = "attn" and
-    #: "dense" in every layer
+    #: each layer's token-mixing operator, "attn" (causal attention
+    #: over all earlier positions), "window" (the same projections,
+    #: causal attention over the last ``attn_window`` positions, a
+    #: query's own included: ``0 <= q - k < attn_window``) or "conv"
+    #: (models/operators.short_conv, ``conv_taps`` taps a channel), and
+    #: each layer's FFN, "dense" or "moe"; () = "attn" and "dense" in
+    #: every layer
     layer_ops: Tuple[str, ...] = ()
     layer_ffns: Tuple[str, ...] = ()
     conv_taps: int = 3
+    attn_window: int = 0
     #: key/value heads, each serving n_heads / n_kv_heads consecutive
     #: query heads (0 = n_heads, and one fused ``wqkv``)
     n_kv_heads: int = 0
@@ -208,10 +235,18 @@ class TransformerConfig:
         assert self.vocab % n_model == 0
         assert self.loop_steps >= 1
         assert self.rope_theta is None or self.head_dim % 2 == 0
-        for kinds, known in ((self.layer_ops, ("attn", "conv")),
+        for kinds, known in ((self.layer_ops, ("attn", "window", "conv")),
                              (self.layer_ffns, ("dense", "moe"))):
             assert len(kinds) in (0, self.n_layers) and set(kinds) <= set(
                 known), f"one of {known} a layer, {self.n_layers} layers"
+        assert self.attn_window >= 1 or "window" not in self.layer_ops, \
+            "a window layer needs attn_window, its width in positions"
+        if self.yarn_factor:
+            assert self.rope_theta is not None and self.yarn_factor > 1 \
+                and self.yarn_original_positions > 0, (
+                    "YaRN scales rotary tables: rope_theta, a factor over "
+                    "1 and the positions the model was trained on")
+        assert self.moe_router_score in ("sigmoid", "softmax")
         assert self.n_heads % self.kv_heads == 0 \
             and self.kv_heads % n_model == 0, "key/value heads must split"
         assert self.n_kv_heads or not self.qk_norm, \
@@ -346,7 +381,7 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
             x = x + o.astype(cfg.dtype)
     else:
         x = _attention_local(x, lp, cfg, n_model, data_axis, model_axis,
-                             rope)
+                             rope, cfg.attn_window if op == "window" else None)
 
     if ffn == "moe":
         # its stages carry scopes of their own (tf.moe_*)
@@ -378,11 +413,14 @@ def _layer_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
 
 def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                      n_model: int, data_axis: str, model_axis: str,
-                     rope):
+                     rope, window=None):
     """The block's attention sublayer, residual included: causal
     multi-head attention from one fused ``wqkv``, or grouped-query
     attention from ``wq`` and ``wkv`` (``cfg.n_kv_heads``,
-    models/operators.grouped_qkv)."""
+    models/operators.grouped_qkv); over all earlier positions, or with
+    *window* over the last *window* of them, the query's own included
+    (the flash kernels' windowed programs under ``tf.flash``; the jnp
+    ring masks the same way)."""
     H_loc = cfg.n_heads // n_model
     D = cfg.head_dim
     E = x.shape[-1]
@@ -429,13 +467,13 @@ def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
         bk = dict(block_q=cfg.attn_block, block_kv=cfg.attn_block) \
             if cfg.attn_block else {}
         with jax.named_scope("tf.flash"):
-            attn = flash_attention(q, k, v, causal=True,
+            attn = flash_attention(q, k, v, causal=True, window=window,
                                    **bk).astype(cfg.dtype)
     else:
         # bf16 operands on the MXU with f32 softmax/accumulation inside
         with jax.named_scope("tf.ring"):
             attn = ring_attention(q, k, v, data_axis, causal=True,
-                                  block_size=cfg.attn_block
+                                  block_size=cfg.attn_block, window=window
                                   ).astype(cfg.dtype)
     with jax.named_scope("tf.attn_proj"):
         if cfg.flash:
@@ -478,12 +516,12 @@ def remat_kept_bytes(cfg: TransformerConfig, n_model: int, batch: int,
     ``forward_local``'s checkpoint policy keeps beyond each layer's
     input.  The kernel's output ``[B, H_loc, T, D]`` in ``cfg.dtype`` and
     its float32 row statistics ``[B, H_loc, T]``, an application of an
-    attention layer (grouped-query attention reaches the kernel with K
-    and V repeated to ``H_loc`` heads)."""
+    attention layer, windowed or not (grouped-query attention reaches
+    the kernel with K and V repeated to ``H_loc`` heads)."""
     if not (cfg.remat and cfg.flash):
         return 0
     rows = batch * (cfg.n_heads // n_model) * t_local
-    attention_layers = sum(cfg.layer_kind(i)[0] == "attn"
+    attention_layers = sum(cfg.layer_kind(i)[0] != "conv"
                            for i in range(cfg.n_layers))
     return cfg.loop_steps * attention_layers * rows * (
         cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
@@ -503,10 +541,15 @@ def forward_local(params: Params, tokens: jax.Array,
     Params arrive already sliced by transformer_param_spec."""
     with jax.named_scope("tf.embed"):
         x = params["embed"][tokens].astype(cfg.dtype)  # [B, T, E]
-    rope = None
+    # rotary tables once a step for each kind of layer that needs its
+    # own: the plain ones, and YaRN's for the "attn" layers
+    rope = scaled = None
     if cfg.rope_theta is not None:
         with jax.named_scope("tf.rope"):
-            rope = rope_tables(cfg, tokens.shape[1], data_axis)
+            rope = scaled = rope_tables(cfg, tokens.shape[1], data_axis)
+            if cfg.yarn_factor:
+                scaled = rope_tables(cfg, tokens.shape[1], data_axis,
+                                     yarn=True)
 
     def layer(x, lp, rope, kind):
         return _layer_local(x, lp, cfg, n_model, data_axis, model_axis,
@@ -537,7 +580,9 @@ def forward_local(params: Params, tokens: jax.Array,
             prefix = f"L{i}."
             lp = {k[len(prefix):]: v for k, v in params.items()
                   if k.startswith(prefix)}
-            x, layer_stats = layer(x, lp, rope, cfg.layer_kind(i))
+            kind = cfg.layer_kind(i)
+            x, layer_stats = layer(x, lp, scaled if kind[0] == "attn"
+                                   else rope, kind)
             if layer_stats is not None:
                 stats.append(layer_stats)
         if cfg.final_norm:
@@ -910,6 +955,9 @@ class TransformerTrainer:
         layer applications, and what ``remat`` and the loss make it
         keep."""
         _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers)
+        for i in range(self.cfg.n_layers):
+            _OPERATOR_APPS.inc(self.cfg.loop_steps,
+                               operator=self.cfg.layer_kind(i)[0])
         B, t_local = x.shape[0], x.shape[1] // self.n_data
         _REMAT_KEPT.set(remat_kept_bytes(
             self.cfg, self.mesh.shape["model"], B, t_local), program=program)
@@ -1013,6 +1061,14 @@ class TransformerTrainer:
                     f"tied{int(c.tied_embeddings)}.top{c.moe_top_k}."
                     f"xf{c.moe_ffn}.held{c.experts_held}at"
                     f"{c.moe_held_offset}.bias{int(c.moe_router_bias)}")
+        yarn = (c.yarn_factor, c.yarn_original_positions, c.yarn_beta_fast,
+                c.yarn_beta_slow, c.yarn_attention_factor)
+        if (c.attn_window, c.moe_router_score, yarn[0]) != (0, "sigmoid", 0):
+            # a window's width, the router's score and the rotary scaling
+            # change no shape either; a model without them keeps the tag
+            # its checkpoints were written under
+            tag += (f".win{c.attn_window}.score{c.moe_router_score}.yarn"
+                    + "x".join(map(str, yarn if yarn[0] else (0,))))
         return tag
 
     def save(self, path: str, params: Params, step: int = 0,

@@ -24,10 +24,19 @@ by scalar prefetch: index maps and body read ``qt[t]``, ``kt[t]`` and
 the step's flags.  Causal attention needs 528 of a 32K head's 1,024
 tile pairs, and the grid has 528 steps; non-causal calls (the ring
 path's below-diagonal steps, ``Tq != Tk``) run the same kernels on a
-table that lists every pair.  A mask of another shape (documents,
-windows) is another table.  Score memory is O(block_q x block_kv)
-whatever T is, so the same kernel serves the 2048-token bench and the
-32K long-context config.
+table that lists every pair.  A SLIDING WINDOW (``window=W``: a query
+attends to itself and the ``W - 1`` positions before it, ``0 <= q - k <
+W``) is another table: it lists the pairs that hold an allowed (q, k)
+and no other, 63 of a 32K head's pairs at ``W`` = 1024, and a tile that
+crosses the window's lower edge is masked in the body as a tile on the
+diagonal is (``_crosses_edge``, ``_mask_scores``).  The windowed calls'
+programs carry names of their own (``flash_fwd_win``, ``flash_dkv_win``,
+``flash_dq_win``), so that a trace tells a model's two kinds of layer
+apart; with ``window=None`` tables, grids and programs are the causal
+ones unchanged.  A mask of yet another shape (documents) is another
+table.  Score memory is O(block_q x block_kv) whatever T is, so the
+same kernel serves the 2048-token bench and the 32K long-context
+config.
 
 Backward is ONE pass over the needed (q tile, kv tile) pairs, wired
 through ``jax.custom_vjp`` with (q, k, v, out, lse) residuals —
@@ -95,6 +104,20 @@ def _on_diag(iq, j, block_q, block_kv):
     return j * block_kv <= iq * block_q + block_q - 1
 
 
+def _in_window(iq, j, block_q, block_kv, window):
+    """Does KV tile j hold a key fewer than *window* positions before
+    Q tile iq's FIRST query (the query that reaches back least far)?"""
+    return j * block_kv + block_kv - 1 > iq * block_q - window
+
+
+def _needed(iq, j, block_q, block_kv, causal, window):
+    """Does the pair (Q tile iq, KV tile j) hold an allowed (q, k)?"""
+    if not causal:
+        return True
+    return bool(_on_diag(iq, j, block_q, block_kv)) and (
+        window is None or bool(_in_window(iq, j, block_q, block_kv, window)))
+
+
 # -- the grid of needed tiles ------------------------------------------------
 # A kernel's innermost grid axis counts (Q tile, KV tile) pairs, and
 # which pair a step is stands in int32 tables made here, at trace time,
@@ -106,6 +129,7 @@ _WORK = 1       # the pair is needed: run the tile's products
 _OPENS = 2      # first step of its row: zero the row's scratch
 _CLOSES = 4     # last step of its row: emit the row's output blocks
 _DQ_DONE = 8    # flash_dkv: the Q tile's last contribution, emit its dQ
+_DQ_OPENS = 16  # flash_dkv, windowed: the Q tile's first, zero its dQ
 
 _GRID_STEPS = _obs.gauge(
     "mrtpu_flash_grid_steps",
@@ -113,7 +137,9 @@ _GRID_STEPS = _obs.gauge(
     "(labels: kernel, kind): kind=steps is the grid axis' length, "
     "kind=needed how many of them are needed (Q tile, KV tile) pairs; "
     "the two differ only by the rows no Q tile needs (causal, Tk > Tq, "
-    "or a several-pass backward), which keep one step that writes zeros")
+    "or a several-pass backward), which keep one step that writes "
+    "zeros; a sliding-window call sets kernel=flash_fwd_win, "
+    "flash_dkv_win")
 
 
 def _row_flags(k, n):
@@ -138,15 +164,16 @@ def _count_steps(kernel, flags):
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_tables(n_q, n_kv, block_q, block_kv, causal):
+def _fwd_tables(n_q, n_kv, block_q, block_kv, causal, window=None):
     """``(qt, kt, flags)`` of flash_fwd's grid: the needed pairs, Q tile
     by Q tile with KV ascending.  A row is a Q tile's: its online-softmax
-    state opens at KV tile 0, which every Q tile needs, and o, lse leave
-    at its last pair (the diagonal tile, or the last KV tile)."""
+    state opens at its first needed KV tile (tile 0 without a window)
+    and o, lse leave at its last pair (the diagonal tile, or the last KV
+    tile)."""
     qt, kt, flags = [], [], []
     for iq in range(n_q):
         row = [j for j in range(n_kv)
-               if not causal or _on_diag(iq, j, block_q, block_kv)]
+               if _needed(iq, j, block_q, block_kv, causal, window)]
         for n, j in enumerate(row):
             qt.append(iq)
             kt.append(j)
@@ -155,7 +182,7 @@ def _fwd_tables(n_q, n_kv, block_q, block_kv, causal):
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_tables(n_q, n_kv, block_q, block_kv, causal, q_tiles):
+def _bwd_tables(n_q, n_kv, block_q, block_kv, causal, q_tiles, window=None):
     """``(qt, kt, pt, dqt, flags)`` of flash_dkv's grid: the needed
     pairs, KV tile by KV tile with Q ascending from the row's first
     needed Q tile; with several passes (``q_tiles < n_q``) one pass's
@@ -171,13 +198,18 @@ def _bwd_tables(n_q, n_kv, block_q, block_kv, causal, q_tiles):
     done next, at or after *t* (past the last one, still that).  So the
     block index moves only after a step that wrote the block, and every
     block is held for one run of steps, written once at the run's end,
-    and never leaves before it is complete."""
+    and never leaves before it is complete.
+
+    Without a window every Q tile needs KV tile 0, whose row opens a
+    pass, and the kernel zeroes a Q tile's accumulator there.  With one
+    the tables say where: ``_DQ_OPENS`` at a Q tile's first needed
+    pair."""
     qt, kt, pt, flags = [], [], [], []
     for c in range(n_q // q_tiles):
         tiles = range(c * q_tiles, (c + 1) * q_tiles)
         for j in range(n_kv):
             row = [iq for iq in tiles
-                   if not causal or _on_diag(iq, j, block_q, block_kv)]
+                   if _needed(iq, j, block_q, block_kv, causal, window)]
             work = _WORK if row else 0
             row = row or [tiles[-1]]
             for n, iq in enumerate(row):
@@ -188,6 +220,11 @@ def _bwd_tables(n_q, n_kv, block_q, block_kv, causal, q_tiles):
     done = {iq: t for t, iq in enumerate(qt) if flags[t] & _WORK}
     for t in done.values():
         flags[t] |= _DQ_DONE
+    if window is not None:
+        opens = {iq: t for t, iq in reversed(list(enumerate(qt)))
+                 if flags[t] & _WORK}
+        for t in opens.values():
+            flags[t] |= _DQ_OPENS
     dqt, held = [], qt[max(done.values())]
     for t in reversed(range(len(qt))):
         if flags[t] & _DQ_DONE:
@@ -212,29 +249,57 @@ def _crosses_diag(iq, j, block_q, block_kv):
     return j * block_kv + block_kv - 1 > iq * block_q
 
 
-def _dispatch_tile(accum, work, causal, iq, j, block_q, block_kv):
+def _crosses_edge(iq, j, block_q, block_kv, window):
+    """Does KV tile j contain any element below the window's lower edge
+    for Q tile iq: a key *window* or more positions before one of its
+    queries (the tile's LAST query against its first key)?"""
+    return iq * block_q + block_q - 1 - j * block_kv >= window
+
+
+def _mask_scores(s, mask, q_start, k_start, window):
+    """Scores ``s [block_q, block_kv]`` with what *mask* = ``(above the
+    diagonal, below the window's edge)`` names set to ``NEG_INF``."""
+    above, below = mask
+    qp = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kp = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if above and below:
+        keep = (kp <= qp) & (qp - kp < window)
+    else:
+        keep = kp <= qp if above else qp - kp < window
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _dispatch_tile(accum, work, causal, iq, j, block_q, block_kv,
+                   window=None):
     """Run *accum(mask)* under the masked/full split the kernels share:
     diagonal-crossing tiles take the masked body, strictly-below tiles
-    the unmasked one, non-causal always unmasked.  *work* is the step's
-    ``_WORK`` flag; every non-causal step has it."""
+    the unmasked one, non-causal always unmasked; with a *window* a tile
+    that crosses its lower edge is masked there too (at a window no
+    wider than a tile, one tile may cross both).  *mask* is
+    :func:`_mask_scores`'s pair, ``None`` for the unmasked body; *work*
+    is the step's ``_WORK`` flag, which every non-causal step has."""
     if not causal:
-        accum(False)
+        accum(None)
         return
+    def either(crosses, wanted):
+        return crosses if wanted else jnp.logical_not(crosses)
+
     diag = _crosses_diag(iq, j, block_q, block_kv)
-
-    @pl.when(work & diag)
-    def _tile_masked():
-        accum(True)
-
-    @pl.when(work & jnp.logical_not(diag))
-    def _tile_full():
-        accum(False)
+    if window is not None:
+        edge = _crosses_edge(iq, j, block_q, block_kv, window)
+    for above in (True, False):
+        for below in (True, False) if window is not None else (False,):
+            case = either(diag, above)
+            if window is not None:
+                case = case & either(edge, below)
+            mask = (above, below) if above or below else None
+            pl.when(work & case)(functools.partial(accum, mask))
 
 
 def _fwd_kernel(pids, qt_ref, kt_ref, ft_ref,
                 q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, den_scr, acc_scr,
-                *, causal, block_q, block_kv):
+                *, causal, block_q, block_kv, window):
     # q arrives PRE-SCALED by 1/sqrt(D) (see _fwd_call): one elementwise
     # pass over [B,H,T,D] outside replaces a [block_q,block_kv] scale
     # pass in every tile
@@ -258,11 +323,11 @@ def _fwd_kernel(pids, qt_ref, kt_ref, ft_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if mask:
-            qp = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0)
-            kp = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1)
-            s = jnp.where(kp <= qp, s, NEG_INF)
+            # a row the window masks whole in this tile leaves p = 1
+            # behind (exp(NEG_INF - NEG_INF)); the row's diagonal tile,
+            # which comes later and holds its own position, wipes it
+            # (corr = exp(NEG_INF - m) = 0)
+            s = _mask_scores(s, mask, q_start, k_start, window)
         m_prev = m_scr[:, 0:1]                      # [block_q, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                      # masked cols -> 0
@@ -276,7 +341,7 @@ def _fwd_kernel(pids, qt_ref, kt_ref, ft_ref,
         den_scr[:, 0:1] = den
 
     # every step of this grid is a needed pair
-    _dispatch_tile(_accum, True, causal, iq, j, block_q, block_kv)
+    _dispatch_tile(_accum, True, causal, iq, j, block_q, block_kv, window)
 
     # emit once, at the row's last pair (the row keeps (m, den, acc) in
     # VMEM scratch; dividing every step cost a [block_q, D] divide + log
@@ -294,7 +359,7 @@ def _fwd_kernel(pids, qt_ref, kt_ref, ft_ref,
 def _dkv_kernel(pids, qt_ref, kt_ref, pt_ref, dqt_ref, ft_ref,
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dqa_ref, dk_scr, dv_scr, dq_scr,
-                *, causal, block_q, block_kv, q_tiles):
+                *, causal, block_q, block_kv, q_tiles, window):
     # q is pre-scaled, so dK = dS^T . q^ needs NO scale factor at all
     # (dk = dS^T . scale*q exactly); dq^ = dS . k picks scale up in the
     # flash_dq epilogue (chain rule through q^ = scale*q)
@@ -309,8 +374,8 @@ def _dkv_kernel(pids, qt_ref, kt_ref, pt_ref, dqt_ref, ft_ref,
 
     # KV tile 0 is needed by every Q tile, causal or not, and its row
     # opens a pass: the whole accumulator is zeroed before anything adds
-    # to it
-    @pl.when(jk == 0)
+    # to it.  Under a window a Q tile's first pair is the tables' to say
+    @pl.when(jk == 0 if window is None else _flag(flags, _DQ_OPENS))
     def _init_dq():
         dq_scr[i] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
 
@@ -328,11 +393,7 @@ def _dkv_kernel(pids, qt_ref, kt_ref, pt_ref, dqt_ref, ft_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if mask:
-            qp = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0)
-            kp = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1)
-            s = jnp.where(kp <= qp, s, NEG_INF)
+            s = _mask_scores(s, mask, q_start, k_start, window)
         # the tile's softmax and dS, computed ONCE and cast to the
         # operand dtype once; each feeds two products
         p = jnp.exp(s - lse)            # [block_q, block_kv] f32
@@ -356,7 +417,7 @@ def _dkv_kernel(pids, qt_ref, kt_ref, pt_ref, dqt_ref, ft_ref,
             preferred_element_type=jnp.float32)
 
     _dispatch_tile(_accum, _flag(flags, _WORK), causal, iq, jk,
-                   block_q, block_kv)
+                   block_q, block_kv, window)
 
     @pl.when(_flag(flags, _CLOSES))
     def _emit():
@@ -405,22 +466,29 @@ def _dqa_index(b, h, t, qt, kt, pt, dqt, *_):
     return (b, h, dqt[t], 0)
 
 
+def _kernel_name(name, window):
+    """The windowed calls are programs of their own names."""
+    return name if window is None else name + "_win"
+
+
 def _fwd_call(q, k, v, cfgt):
-    causal, scale, block_q, block_kv, interpret = cfgt
+    causal, scale, block_q, block_kv, interpret, window = cfgt
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     n_q, n_kv = Tq // block_q, Tk // block_kv
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)  # q^ = q/sqrt(D)
-    tables, prefetched = _fwd_tables(n_q, n_kv, block_q, block_kv, causal)
-    _count_steps("flash_fwd", tables[-1])
+    tables, prefetched = _fwd_tables(n_q, n_kv, block_q, block_kv, causal,
+                                     window)
+    _count_steps(_kernel_name("flash_fwd", window), tables[-1])
     q_spec = pl.BlockSpec((1, 1, block_q, D), _q_index)
     kv_spec = pl.BlockSpec((1, 1, block_kv, D), _kv_index)
     row_spec = pl.BlockSpec((1, 1, block_q, 1), _q_index)
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv)
+        _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
+        window=window)
     out, lse = pallas_call(
         kernel,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
         grid=(B, H, len(tables[0])),
         num_scalar_prefetch=len(tables),
         in_specs=[q_spec, kv_spec, kv_spec],
@@ -446,7 +514,7 @@ _BWD_TILE_BYTES = 40 << 20
 
 
 def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
-    causal, scale, block_q, block_kv, interpret = cfgt
+    causal, scale, block_q, block_kv, interpret, window = cfgt
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     n_q, n_kv = Tq // block_q, Tk // block_kv
@@ -475,8 +543,8 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
     q_tiles = max(t for t in range(1, min(fits, n_q) + 1) if n_q % t == 0)
     n_pass = n_q // q_tiles
     tables, prefetched = _bwd_tables(n_q, n_kv, block_q, block_kv, causal,
-                                     q_tiles)
-    _count_steps("flash_dkv", tables[-1])
+                                     q_tiles, window)
+    _count_steps(_kernel_name("flash_dkv", window), tables[-1])
 
     q_spec = pl.BlockSpec((1, 1, block_q, D), _q_index)
     kv_spec = pl.BlockSpec((1, 1, block_kv, D), _kv_index)
@@ -486,8 +554,8 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
     vmem = q_tiles * block_q * D * 4 + _BWD_TILE_BYTES
     dk, dv, dqa = pallas_call(
         functools.partial(_dkv_kernel, causal=causal, block_q=block_q,
-                          block_kv=block_kv, q_tiles=q_tiles),
-        name="flash_dkv",
+                          block_kv=block_kv, q_tiles=q_tiles, window=window),
+        name=_kernel_name("flash_dkv", window),
         grid=(B, H, len(tables[0])),
         num_scalar_prefetch=len(tables),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
@@ -511,7 +579,7 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
     tile_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
     dq = pallas_call(
         functools.partial(_dq_kernel, scale=scale),
-        name="flash_dq",
+        name=_kernel_name("flash_dq", window),
         grid=(B, H, n_q),
         in_specs=[tile_spec],
         out_specs=tile_spec,
@@ -563,21 +631,29 @@ def _flash_lse_bwd(cfgt, res, cots):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret):
+def _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret,
+               window=None):
     D = q.shape[3]
     if scale is None:
         scale = D ** -0.5
+    if window is not None and not (causal and int(window) >= 1):
+        raise ValueError(
+            f"window={window!r}: a sliding window is the last `window` "
+            "positions of causal attention, itself included; it needs "
+            "causal=True and a width of at least 1")
     block_q = pick_block(q.shape[2], block_q)
     block_kv = pick_block(k.shape[2], block_kv)
     return (bool(causal), float(scale), int(block_q), int(block_kv),
-            default_interpret(interpret))
+            default_interpret(interpret),
+            None if window is None else int(window))
 
 
 def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True,
                         scale: Optional[float] = None,
                         block_q: int = 1024, block_kv: int = 1024,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None,
+                        window: Optional[int] = None):
     """Kernel-layout (``[B, H, T, D]``) attention returning
     ``(out, lse [B, H, T, 1] f32)`` — the partial-softmax form ring
     attention needs to combine per-ring-step results across devices
@@ -586,7 +662,8 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     the same names on its residuals: a caller that keeps them by name
     keeps ``n_data`` partial outputs a layer, so the trainer lists the
     names only on the local path (models/transformer.py)."""
-    cfgt = _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret)
+    cfgt = _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret,
+                      window)
     return _flash_lse(q, k, v, cfgt)
 
 
@@ -594,8 +671,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 1024, block_kv: int = 1024,
                     layout: str = "bhtd",
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Tiled attention, differentiable; O(block²) score memory.
+
+    ``window=W`` (with ``causal``) is sliding-window attention: query
+    ``q`` attends to keys ``0 <= q - k < W``, itself and the ``W - 1``
+    positions before it.  The grids then hold only the tile pairs with
+    an allowed (q, k), forward and backward, and the three programs are
+    ``flash_fwd_win``, ``flash_dkv_win``, ``flash_dq_win``; ``None`` is
+    causal attention over all earlier positions, the programs and tables
+    it always had.
 
     ``layout="bhtd"`` (kernel-native) or ``"bthd"`` (the ring path's
     convention; transposed in and out).  ``interpret=None`` auto-selects
@@ -612,7 +698,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
     elif layout != "bhtd":
         raise ValueError(f"unknown layout {layout!r}")
-    cfgt = _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret)
+    cfgt = _make_cfgt(q, k, causal, scale, block_q, block_kv, interpret,
+                      window)
     out, _ = _flash_lse(q, k, v, cfgt)
     if layout == "bthd":
         out = jnp.swapaxes(out, 1, 2)

@@ -40,11 +40,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import default_interpret, pallas_call, pick_block, sds
+from .pallas_compat import (default_interpret, pallas_call, pick_lane_block,
+                            sds)
 
 #: columns of ``rhs`` a grid step of ``moe_gmm`` produces, and the
 #: (contraction, column) block of the weight gradient ``moe_tgmm`` holds
-#: across a group's tiles.  Not swept: PERF.md section 7
+#: across a group's tiles: requests, met by the nearest block a lane
+#: dimension takes (``pick_lane_block``: 2048 x 1536 run at 512 and
+#: 1024 / 768, 2304 x 896 at 384 / 896 and 1152).  Not swept: PERF.md
+#: section 7
 BLOCK_N = 512
 BLOCK_TK = 1024
 #: VMEM the kernels may use: a (512 x 2048) operand tile, a (2048 x 512)
@@ -66,7 +70,7 @@ def _gmm_kernel(pids, group_ref, n_ref, lhs_ref, rhs_ref, out_ref, *,
 def _gmm(lhs, rhs, tile_group, n_tiles, block_m, transpose_rhs, interpret):
     M, K = lhs.shape
     N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    block_n = pick_block(N, BLOCK_N)
+    block_n = pick_lane_block(N, BLOCK_N)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec((1, block_n, K),
                                 lambda n, i, grp, _n: (grp[i], n, 0))
@@ -108,7 +112,8 @@ def _tgmm_kernel(pids, group_ref, n_ref, lhs_ref, dout_ref, out_ref):
 def _tgmm(lhs, dout, tile_group, n_tiles, n_groups, block_m, interpret):
     M, K = lhs.shape
     N = dout.shape[1]
-    block_k, block_n = pick_block(K, BLOCK_TK), pick_block(N, BLOCK_N)
+    block_k = pick_lane_block(K, BLOCK_TK)
+    block_n = pick_lane_block(N, BLOCK_N)
     return pallas_call(
         _tgmm_kernel,
         name="moe_tgmm",

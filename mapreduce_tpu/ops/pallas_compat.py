@@ -86,6 +86,21 @@ def pick_block(t: int, want: int) -> int:
     return t
 
 
+def pick_lane_block(t: int, want: int) -> int:
+    """The block nearest *want*, by ratio, of those Mosaic takes on a
+    LANE (last) dimension of length *t*: a multiple of 128 that divides
+    *t*, or *t* whole; the smaller of two equally near.  Where *want*
+    itself is such a block it is the answer, as with :func:`pick_block`
+    (1536 or 2048 columns at 512: 512); where it is not, the nearest may
+    lie above it (896 columns at 512: 128 is four times under, the whole
+    896 not twice over) — :func:`pick_block` knows the sublane rule
+    alone and would halve 896 to 448, which no lane dimension takes."""
+    if t <= want:
+        return t
+    valid = [b for b in range(128, t, 128) if t % b == 0] + [t]
+    return min(valid, key=lambda b: (max(b, want) / min(b, want), b))
+
+
 def sds(shape: Sequence[int], dtype: Any, like: Any) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct inheriting *like*'s varying-mesh-axes set, so the
     kernel composes with shard_map's vma checking (the kernel is purely

@@ -128,7 +128,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str, causal: bool = True,
                    scale: Optional[float] = None,
                    block_size: Optional[int] = None,
-                   use_flash: Optional[bool] = None) -> jax.Array:
+                   use_flash: Optional[bool] = None,
+                   window: Optional[int] = None) -> jax.Array:
     """Exact multi-head attention over a sequence sharded on *axis_name*.
 
     ``q/k/v``: [B, T_local, H, D] local blocks (must run inside
@@ -147,10 +148,24 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``use_flash`` (None = auto: on TPU) runs each ring step's local
     attention through the Pallas kernel instead of the jnp path — the
     kernel already tiles, so ``block_size`` is ignored there.
+
+    ``window=W`` (with ``causal``) is sliding-window attention over
+    GLOBAL positions, ``0 <= q - k < W``: the jnp path masks it as it
+    masks the diagonal, on any number of shards.  The kernel ring
+    refuses it: a ring step's block lies ``s`` shards back and the
+    kernel's window counts from position 0 of what it is handed.
     """
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"window={window!r} needs causal=True and a "
+                         "width of at least 1")
     if use_flash is None:
         use_flash = jax.default_backend() == "tpu"
     if use_flash:
+        if window is not None:
+            raise ValueError(
+                "a sliding window over a sequence sharded on the ring has "
+                "no kernel path: run it unsharded (the flash kernels' "
+                "window) or with use_flash=False")
         return _ring_attention_flash(q, k, v, axis_name, causal, scale)
     P = jax.lax.psum(1, axis_name)
     rank = jax.lax.axis_index(axis_name)
@@ -169,6 +184,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kb, vb, kp = xs  # [B, block, H, D] x2, [block]
         if causal:
             mask = kp[None, :] <= qp_c[:, None]  # [Tq_c, Tk_c]
+            if window is not None:
+                mask &= qp_c[:, None] - kp[None, :] < window
         else:
             mask = jnp.ones((qp_c.shape[0], kp.shape[0]), bool)
         bm, bden, bnum = _block_attn(q_c, kb, vb, mask[None, None], scale)

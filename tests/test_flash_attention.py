@@ -339,8 +339,14 @@ def _tile_counts(grid):
     return Tq // block_q, Tk // block_kv, block_q, block_kv
 
 
-def _allowed(iq, j, block_q, block_kv, causal):
-    return not causal or bool(fa._on_diag(iq, j, block_q, block_kv))
+def _allowed(iq, j, block_q, block_kv, causal, window=None):
+    """Does the tile pair hold an allowed (q, k)?  Position by position,
+    apart from the module's own tile arithmetic."""
+    if not causal:
+        return True
+    back = ((iq * block_q + np.arange(block_q))[:, None]
+            - (j * block_kv + np.arange(block_kv))[None, :])
+    return bool(((back >= 0) & ((back < window) if window else True)).any())
 
 
 def _written_back(blocks, writes, adds_to):
@@ -365,24 +371,34 @@ def _written_back(blocks, writes, adds_to):
     return runs
 
 
-_TABLE_CASES = ([(g, c, None) for g in _BWD_GRIDS for c in (True, False)]
-                + [("q_finer", True, 2), ("kv_finer", False, 1),
-                   ("kv_finer", True, 1), ("tq_ne_tk", True, 1),
-                   ("one_kv_tile", True, 2)])
+#: windows (positions) the tables are also held to: a tile's width, a
+#: multiple of it, neither, narrower than a tile, one position, wider
+#: than the sequence (then the tables are the causal ones)
+_TABLE_CASES = ([(g, c, None, None) for g in _BWD_GRIDS
+                 for c in (True, False)]
+                + [("q_finer", True, 2, None), ("kv_finer", False, 1, None),
+                   ("kv_finer", True, 1, None), ("tq_ne_tk", True, 1, None),
+                   ("one_kv_tile", True, 2, None)]
+                + [(g, True, None, w)
+                   for g in ("q_finer", "kv_finer", "one_tile", "tq_ne_tk")
+                   for w in (64, 80, 20, 1, 500)]
+                + [("q_finer", True, 2, 80), ("kv_finer", True, 1, 64),
+                   ("tq_ne_tk", True, 1, 40)])
 
 
 @pytest.mark.parametrize(
-    "grid,causal,acc_tiles", _TABLE_CASES,
+    "grid,causal,acc_tiles,window", _TABLE_CASES,
     ids=[f"{g}-{'causal' if c else 'full'}{f'-acc{a}' if a else ''}"
-         for g, c, a in _TABLE_CASES])
-def test_needed_tile_tables(grid, causal, acc_tiles):
+         f"{f'-win{w}' if w else ''}" for g, c, a, w in _TABLE_CASES])
+def test_needed_tile_tables(grid, causal, acc_tiles, window):
     n_q, n_kv, block_q, block_kv = _tile_counts(grid)
     flag = lambda flags, bit: [bool(f & bit) for f in flags]
 
     # forward: Q-major, KV ascending; o and lse leave once a Q tile
-    (qt, kt, flags), _ = fa._fwd_tables(n_q, n_kv, block_q, block_kv, causal)
+    (qt, kt, flags), _ = fa._fwd_tables(n_q, n_kv, block_q, block_kv, causal,
+                                        window)
     want = [(iq, j) for iq in range(n_q) for j in range(n_kv)
-            if _allowed(iq, j, block_q, block_kv, causal)]
+            if _allowed(iq, j, block_q, block_kv, causal, window)]
     assert list(zip(qt.tolist(), kt.tolist())) == want
     assert all(flag(flags, fa._WORK))
     # the softmax state opens at each row's first pair and nowhere else
@@ -396,13 +412,13 @@ def test_needed_tile_tables(grid, causal, acc_tiles):
     q_tiles = acc_tiles or n_q
     assert n_q % q_tiles == 0
     (qt, kt, pt, dqt, flags), _ = fa._bwd_tables(
-        n_q, n_kv, block_q, block_kv, causal, q_tiles)
+        n_q, n_kv, block_q, block_kv, causal, q_tiles, window)
     want, rows = [], []
     for c in range(n_q // q_tiles):
         for j in range(n_kv):
             row = [(iq, j, True) for iq in range(c * q_tiles,
                                                  (c + 1) * q_tiles)
-                   if _allowed(iq, j, block_q, block_kv, causal)]
+                   if _allowed(iq, j, block_q, block_kv, causal, window)]
             row = row or [((c + 1) * q_tiles - 1, j, False)]
             want += row
             rows += [(c, j)] * len(row)
@@ -428,25 +444,96 @@ def test_needed_tile_tables(grid, causal, acc_tiles):
         dqt.tolist(), done,
         [q if w else None for q, w in zip(qt.tolist(), work)]) == {
             iq: 1 for iq in range(n_q)}
-    # the accumulator is zeroed on KV tile 0's row: every Q tile is there
-    for c in range(n_q // q_tiles):
-        assert [q for q, r, w in zip(qt.tolist(), rows, work)
-                if r == (c, 0) and w] == list(range(c * q_tiles,
-                                                    (c + 1) * q_tiles))
+    opens = flag(flags, fa._DQ_OPENS)
+    if window is None:
+        # the accumulator is zeroed on KV tile 0's row: every Q tile is
+        # there, and no table says so
+        assert not any(opens)
+        for c in range(n_q // q_tiles):
+            assert [q for q, r, w in zip(qt.tolist(), rows, work)
+                    if r == (c, 0) and w] == list(range(c * q_tiles,
+                                                        (c + 1) * q_tiles))
+    else:
+        # under a window the tables say where: a Q tile's first needed
+        # pair, once, before anything adds to it
+        for iq in range(n_q):
+            mine = [t for t in range(len(qt)) if qt[t] == iq and work[t]]
+            assert [t for t in mine if opens[t]] == [mine[0]]
+        assert not any(o and not w for o, w in zip(opens, work))
 
 
-_GRID_CASES = [("q_finer", True), ("q_finer", False), ("kv_finer", True),
-               ("tq_ne_tk", False), ("tq_ne_tk", True), ("one_tile", True)]
+def test_no_window_builds_the_parents_tables():
+    """``window=None`` is the causal table as it always was: the needed
+    pairs Q tile by Q tile, the flag words of PR 32 (no ``_DQ_OPENS``),
+    and 528 / 528 at the dense cell's 32 tiles, 10 / 10 at the looped
+    cell's 4, on the causal kernels' gauge."""
+    from mapreduce_tpu.obs.metrics import REGISTRY
+
+    read = lambda kernel, kind: REGISTRY.value(
+        "mrtpu_flash_grid_steps", kernel=kernel, kind=kind)
+    for n, needed in ((32, 528), (4, 10)):
+        for args in ((n, n, 1024, 1024, True), (n, n, 1024, 1024, True,
+                                                None)):
+            (qt, kt, flags), _ = fa._fwd_tables(*args)
+            assert list(zip(qt.tolist(), kt.tolist())) == [
+                (iq, j) for iq in range(n) for j in range(iq + 1)]
+            assert flags.tolist() == [
+                fa._WORK | (fa._OPENS if j == 0 else 0)
+                | (fa._CLOSES if j == iq else 0)
+                for iq in range(n) for j in range(iq + 1)]
+        (qt, kt, pt, dqt, flags), _ = fa._bwd_tables(n, n, 1024, 1024, True,
+                                                     n)
+        assert list(zip(kt.tolist(), qt.tolist())) == [
+            (j, iq) for j in range(n) for iq in range(j, n)]
+        # a Q tile is done at its diagonal step, which opens row j:
+        # the block held after it is the next row's
+        assert dqt.tolist() == [
+            j if iq == j else min(j + 1, n - 1)
+            for j in range(n) for iq in range(j, n)]
+        assert not pt.any()
+        assert flags.tolist() == [
+            fa._WORK | (fa._OPENS | fa._DQ_DONE if iq == j else 0)
+            | (fa._CLOSES if iq == n - 1 else 0)
+            for j in range(n) for iq in range(j, n)]
+        q = jnp.zeros((1, 1, n * 1024, 128), jnp.bfloat16)
+        jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, q, q).astype(jnp.float32).sum()))(q)
+        for kernel in ("flash_fwd", "flash_dkv"):
+            assert (read(kernel, "steps"), read(kernel, "needed")) \
+                == (needed, needed)
 
 
-@pytest.mark.parametrize("grid,causal", _GRID_CASES,
+def test_window_tables_at_the_cells_lengths():
+    """A 1024-position window on 1024 x 1024 tiles needs the diagonal
+    tile and the one before it: 63 of a 32K head's 528 pairs, 47 of a
+    24K head's 300, 31 of a 16K head's 136."""
+    for n, full, windowed in ((32, 528, 63), (24, 300, 47), (16, 136, 31)):
+        assert len(fa._fwd_tables(n, n, 1024, 1024, True)[0][0]) == full
+        (qt, kt, _), _ = fa._fwd_tables(n, n, 1024, 1024, True, 1024)
+        assert list(zip(qt.tolist(), kt.tolist())) == [
+            (iq, j) for iq in range(n) for j in (iq - 1, iq) if j >= 0]
+        assert len(qt) == windowed
+        assert len(fa._bwd_tables(n, n, 1024, 1024, True, n,
+                                  1024)[0][0]) == windowed
+
+
+_GRID_CASES = [("q_finer", True, None), ("q_finer", False, None),
+               ("kv_finer", True, None), ("tq_ne_tk", False, None),
+               ("tq_ne_tk", True, None), ("one_tile", True, None),
+               ("q_finer", True, 64), ("kv_finer", True, 80),
+               ("tq_ne_tk", True, 40), ("q_finer", True, 500)]
+
+
+@pytest.mark.parametrize("grid,causal,window", _GRID_CASES,
                          ids=[f"{g}-{'causal' if c else 'full'}"
-                              for g, c in _GRID_CASES])
-def test_built_kernels_run_the_needed_steps_alone(grid, causal):
+                              f"{f'-win{w}' if w else ''}"
+                              for g, c, w in _GRID_CASES])
+def test_built_kernels_run_the_needed_steps_alone(grid, causal, window):
     """The kernels as built: the innermost grid axis of flash_fwd and of
     flash_dkv is as long as there are needed tiles (plus, backward, one
-    step a KV tile that no Q tile needs), causal or not, and the gauge
-    says the same."""
+    step a KV tile that no Q tile needs), causal or not, windowed or
+    not, and the gauge says the same; the windowed programs carry their
+    own names, there and on the gauge."""
     from mapreduce_tpu.obs.metrics import REGISTRY
 
     Tq, Tk, block_q, block_kv = _BWD_GRIDS[grid]
@@ -455,26 +542,117 @@ def test_built_kernels_run_the_needed_steps_alone(grid, causal):
     q, k, v = (jnp.zeros((B, H, T, D), jnp.float32) for T in (Tq, Tk, Tk))
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: flash_attention_lse(
-            q, k, v, causal=causal, block_q=block_q,
-            block_kv=block_kv)[0].sum(), argnums=(0, 1, 2)))(q, k, v)
+            q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
+            window=window)[0].sum(), argnums=(0, 1, 2)))(q, k, v)
     grids = {e.params["name"]: tuple(e.params["grid_mapping"].grid)
              for e, _ in eqns(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"}
-    needed = sum(_allowed(iq, j, block_q, block_kv, causal)
+    needed = sum(_allowed(iq, j, block_q, block_kv, causal, window)
                  for iq in range(n_q) for j in range(n_kv))
-    unseen = sum(not any(_allowed(iq, j, block_q, block_kv, causal)
+    unseen = sum(not any(_allowed(iq, j, block_q, block_kv, causal, window)
                          for iq in range(n_q)) for j in range(n_kv))
-    assert grids == {"flash_fwd": (B, H, needed),
-                     "flash_dkv": (B, H, needed + unseen),
-                     "flash_dq": (B, H, n_q)}
+    fwd, dkv, dq = (name + ("_win" if window else "")
+                    for name in ("flash_fwd", "flash_dkv", "flash_dq"))
+    assert grids == {fwd: (B, H, needed), dkv: (B, H, needed + unseen),
+                     dq: (B, H, n_q)}
     read = lambda kernel, kind: REGISTRY.value(
         "mrtpu_flash_grid_steps", kernel=kernel, kind=kind)
-    assert (read("flash_fwd", "steps"), read("flash_fwd", "needed")) \
-        == (needed, needed)
-    assert (read("flash_dkv", "steps"), read("flash_dkv", "needed")) \
+    assert (read(fwd, "steps"), read(fwd, "needed")) == (needed, needed)
+    assert (read(dkv, "steps"), read(dkv, "needed")) \
         == (needed + unseen, needed)
     if not causal:
         assert needed == n_q * n_kv and unseen == 0
+    if window == 500:                    # wider than the sequence: causal
+        assert needed == sum(_allowed(iq, j, block_q, block_kv, True)
+                             for iq in range(n_q) for j in range(n_kv))
+
+
+# -- a sliding window (PR 35) -------------------------------------------------
+
+
+def _window_oracle(q, k, v, window):
+    """Masked jnp softmax over ``0 <= q - k < window``, [B, H, T, D]."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    back = jnp.arange(q.shape[2])[:, None] - jnp.arange(k.shape[2])[None, :]
+    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+#: (T, block_q, block_kv, window): a multiple of the tile; the tile
+#: itself (every tile of the layer is then masked, half by the diagonal
+#: and half by the edge); not a multiple; narrower than a tile (one tile
+#: crosses both); one position; as wide as the sequence and wider
+#: (equals causal); Q and KV tiles of unequal count, both ways
+_WINDOW_CASES = [(256, 64, 64, 128), (256, 64, 64, 64), (256, 64, 64, 100),
+                 (256, 64, 64, 40), (256, 64, 64, 1), (256, 64, 64, 256),
+                 (256, 64, 64, 300), (192, 32, 64, 48), (192, 64, 32, 80),
+                 (192, 32, 64, 64)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T,block_q,block_kv,window", _WINDOW_CASES,
+                         ids=[f"T{t}-q{bq}-kv{bk}-win{w}"
+                              for t, bq, bk, w in _WINDOW_CASES])
+def test_window_matches_a_masked_softmax(T, block_q, block_kv, window,
+                                         dtype):
+    """Forward and all three gradients of the windowed kernels against
+    a masked jnp softmax."""
+    B, H, D = 1, 2, 16
+    q, k, v, w = (jax.random.normal(jax.random.key(i), (B, H, T, D), dtype)
+                  for i in range(4))
+    weigh = lambda out: jnp.sum(out.astype(jnp.float32)
+                                * w.astype(jnp.float32))
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, window=window, block_q=block_q, block_kv=block_kv)
+    with jax.default_matmul_precision("float32"):
+        out, want = flash(q, k, v), _window_oracle(q, k, v, window)
+        got = jax.grad(lambda *a: weigh(flash(*a)), argnums=(0, 1, 2))(
+            q, k, v)
+        ref = jax.grad(lambda *a: weigh(_window_oracle(*a, window)),
+                       argnums=(0, 1, 2))(q, k, v)
+    tol = dict(atol=5e-5, rtol=1e-4) if dtype == jnp.float32 \
+        else dict(atol=5e-2, rtol=5e-2)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), **tol)
+    for name, a, b in zip("qkv", got, ref):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol,
+            err_msg=f"d{name} mismatch (window {window})")
+    if window >= T and dtype == jnp.float32:
+        causal = flash_attention(q, k, v, block_q=block_q,
+                                 block_kv=block_kv)
+        assert np.array_equal(np.asarray(out), np.asarray(causal))
+
+
+def test_window_with_several_backward_passes(monkeypatch):
+    """An accumulator budget of two Q tiles: three passes over Q-row
+    ranges, each Q tile's accumulator zeroed at its first windowed
+    pair."""
+    T, block, window = 192, 32, 80
+    monkeypatch.setattr(fa, "_DQ_ACC_BYTES", 2 * block * 16 * 4)
+    q, k, v = (jax.random.normal(jax.random.key(i), (1, 2, T, 16))
+               for i in range(3))
+    with jax.default_matmul_precision("float32"):
+        got = jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, window=window, block_q=block, block_kv=block) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+        ref = jax.grad(lambda *a: jnp.sum(_window_oracle(*a, window) ** 2),
+                       argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-4)
+
+
+def test_window_is_refused_without_causal():
+    q = jnp.zeros((1, 1, 64, 16))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, causal=False, window=16)
+    with pytest.raises(ValueError, match="at least 1"):
+        flash_attention(q, q, q, window=0)
 
 
 @pytest.mark.parametrize("acc_tiles", [None, 1], ids=["one_pass", "acc1"])

@@ -353,6 +353,40 @@ def test_grouped_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip,
     assert text.count("moe_gmm") >= 2 and "moe_tgmm" in text
 
 
+def test_grouped_kernels_compile_for_a_v5e_at_2304_by_896(one_chip, mosaic):
+    """The widths of `train-mellum2-long`: a lane block of 896 columns
+    whole (`pick_block` would halve it to 448, which Mosaic refuses) and
+    of 1152 rows of the weight gradient; 392 tiles of 512 rows."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def f(x, w, tile_group, n_tiles):
+        g = lambda x, w: grouped_matmul(
+            x, w, tile_group, n_tiles, block_m=512).astype(jnp.float32).sum()
+        return jax.grad(g, argnums=(0, 1))(x, w)
+
+    for K, N in ((2304, 896), (896, 2304)):
+        text = jax.jit(f).lower(
+            sds((392 * 512, K), jnp.bfloat16), sds((8, K, N), jnp.float32),
+            sds((392,), jnp.int32), sds((1,), jnp.int32)
+        ).compile().as_text()
+        assert text.count("moe_gmm") >= 2 and "moe_tgmm" in text
+
+
+def test_windowed_flash_kernels_compile_for_a_v5e_at_the_cells_shape(
+        one_chip, mosaic):
+    """One sequence of 24,576 positions, 32 heads of 128, a window of
+    1,024: the three windowed programs, by their own names."""
+    x = jax.ShapeDtypeStruct((1, 32, 24576, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    f = lambda q, k, v: flash_attention(q, k, v, window=1024).astype(
+        jnp.float32).sum()
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    for name in ("flash_fwd_win", "flash_dkv_win", "flash_dq_win"):
+        assert name in text
+
+
 def test_flash_kernels_compile_for_a_v5e_at_head_width_64(one_chip, mosaic):
     x = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16,
                              sharding=one_chip)
