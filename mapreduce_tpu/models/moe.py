@@ -19,15 +19,23 @@ the ranks' parts (one held expert a rank is classic expert parallelism;
 tokens are replicated over that axis, so there is no exchange).
 
 No (token, expert) pair is dropped and none is cut at a capacity: the
-buffers have room for every pair landing here, and the arithmetic
-follows the pairs that did — the products are grouped over the held
-experts (``ops/grouped_matmul.py``), whose grids end at the tiles in
-use.  The plan that puts pairs into expert order (rank within
+buffers have room for every pair landing here, and the work follows the
+pairs that did.  The products are grouped over the held experts
+(``ops/grouped_matmul.py``), whose grids end at the tiles in use, and
+the rows are moved into expert order and back (``_dispatch``,
+``_combine`` and their transposes) by loops that end at the same bound,
+a tile a trip, from the row side: no operation of theirs touches the
+buffers whole.  What does not follow the tiles in use yet: the gate
+``silu(a) * u`` between the products, an elementwise pass over every
+row of the buffers, and the plan over all ``N k`` pairs (PERF.md
+section 5).  The plan that puts pairs into expert order (rank within
 destination, count a destination) is the exchange's,
 ``parallel/shuffle.routing_plan``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -96,57 +104,145 @@ def expert_order(dest: jax.Array, plan, n_held: int, block_m: int,
             tile_end[-1:].astype(jnp.int32))
 
 
+def block_rows(pairs: int) -> int:
+    """Rows of a tile where a device routes *pairs* pairs: ``BLOCK_M``,
+    16 at toy sizes."""
+    return BLOCK_M if pairs >= 16 * BLOCK_M else 16
+
+
 def tiles_for(pairs: int, n_held: int, block_m: int) -> int:
     """Tiles that hold *pairs* pairs however they fall on *n_held*
     experts, each expert's rows starting at a tile."""
     return -(-pairs // block_m) + n_held
 
 
-# Tokens into expert order and back are permutations of the pairs held
-# here, so each one's transpose is the other's gather: no scatter-add.
+# Tokens into expert order and back: every row is moved from the ROW
+# side, by a loop over the tiles in use (the grouped kernels' own bound,
+# ``n_tiles``, a value of the run), so what a call costs follows the
+# pairs that landed here as the arithmetic does, not the buffers' size.
+# Rows past the tiles in use are never gathered and never read.  A tile
+# a trip: two or four read the same on the chip (PERF.md section 6).
 
-@jax.custom_vjp
-def _dispatch(flat, tok_of_row, pos):
-    """``xs [M, E]``: row r holds token ``tok_of_row[r]`` (zeros where
-    that is out of range: padding rows); ``pos [N, k]`` is only for the
-    transpose."""
-    return flat.at[tok_of_row].get(mode="fill", fill_value=0)
+def _over_tiles(tile, n_tiles, carry, like):
+    """``tile(t, carry) -> carry`` for the tiles in use, ``t <
+    n_tiles[0]``, in order, as a ``lax.fori_loop``.  The carry takes the
+    varying mesh axes of the arrays *like* (a loop's carry keeps its
+    type)."""
+    axes = tuple(set().union(*(jax.typeof(a).vma for a in like)))
+    if axes:
+        carry = jax.tree.map(
+            lambda c: jax.lax.pcast(c, axes, to="varying"), carry)
+    return jax.lax.fori_loop(0, n_tiles[0], tile, carry)
 
 
-def _dispatch_fwd(flat, tok_of_row, pos):
-    return _dispatch(flat, tok_of_row, pos), pos
+def _tile_of(table, t, block_m: int):
+    """Rows ``[t block_m, (t + 1) block_m)`` of *table* ``[M]`` or
+    ``[M, E]``."""
+    return jax.lax.dynamic_slice_in_dim(table, t * block_m, block_m)
 
 
-def _dispatch_bwd(pos, d_xs):
-    d_flat = d_xs.at[pos].get(mode="fill", fill_value=0)       # [N, k, E]
-    return (d_flat.astype(jnp.float32).sum(axis=1).astype(d_xs.dtype),
-            None, None)
+def _rows_in(src, tok_of_row, n_tiles, block_m: int):
+    """``[M, E]`` in *src*'s type: row r of a tile in use holds
+    ``src[tok_of_row[r]]``, zeros where that is out of range (a padding
+    row, as ``moe_tgmm`` needs); rows past the tiles in use are not
+    gathered."""
+    def tile(t, xs):
+        rows = src.at[_tile_of(tok_of_row, t, block_m)].get(
+            mode="fill", fill_value=0)
+        return jax.lax.dynamic_update_slice_in_dim(xs, rows, t * block_m, 0)
+
+    return _over_tiles(
+        tile, n_tiles,
+        jnp.zeros((tok_of_row.shape[0], src.shape[1]), src.dtype),
+        (src, tok_of_row, n_tiles))
+
+
+def _rows_out(rows, tok_of_row, n_tiles, block_m: int, n_tokens: int,
+              weights=None, slot_of_row=None):
+    """``[n_tokens, E]`` float32: ``out[tok_of_row[r]] += w_r rows[r]``
+    over the rows of the tiles in use, ``w_r = weights[tok_of_row[r],
+    slot_of_row[r]]`` (1 without *weights*), one scatter-add a tile.  A
+    tile is one expert's, so no token occurs twice in it and the update
+    is ``unique_indices`` (a padding row gets an index of its own past
+    the tokens, dropped); tiles are taken in order, so a token's terms
+    are added in expert order whatever the run."""
+    past = n_tokens + jnp.arange(block_m, dtype=tok_of_row.dtype)
+
+    def tile(t, out):
+        tok = _tile_of(tok_of_row, t, block_m)
+        vals = _tile_of(rows, t, block_m).astype(jnp.float32)
+        if weights is not None:
+            vals = vals * weights.at[
+                tok, _tile_of(slot_of_row, t, block_m)].get(
+                    mode="fill", fill_value=0)[:, None]
+        return out.at[jnp.where(tok < n_tokens, tok, past)].add(
+            vals, mode="drop", unique_indices=True)
+
+    return _over_tiles(
+        tile, n_tiles, jnp.zeros((n_tokens, rows.shape[1]), jnp.float32),
+        (rows, tok_of_row, n_tiles)
+        + (() if weights is None else (weights,)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(flat, tok_of_row, n_tiles, block_m):
+    """``xs [M, E]``: row r of a tile in use holds token
+    ``tok_of_row[r]`` (zeros where that is out of range: padding
+    rows)."""
+    return _rows_in(flat, tok_of_row, n_tiles, block_m)
+
+
+def _dispatch_fwd(flat, tok_of_row, n_tiles, block_m):
+    return (_dispatch(flat, tok_of_row, n_tiles, block_m),
+            (tok_of_row, n_tiles, flat.shape[0]))
+
+
+def _dispatch_bwd(block_m, res, d_xs):
+    tok_of_row, n_tiles, n_tokens = res
+    d_flat = _rows_out(d_xs, tok_of_row, n_tiles, block_m, n_tokens)
+    return d_flat.astype(d_xs.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(ys, weights, pos, tok_of_row, slot_of_row):
-    """``out [N, E]`` float32: ``sum_k weights[n, k] * ys[pos[n, k]]``,
-    a pair that landed elsewhere (``pos`` out of range) adding nothing."""
-    picked = ys.at[pos].get(mode="fill", fill_value=0)         # [N, k, E]
-    return (picked.astype(jnp.float32) * weights[..., None]).sum(axis=1)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(ys, weights, tok_of_row, slot_of_row, n_tiles, block_m):
+    """``out [N, E]`` float32: ``sum_k weights[n, k] * ys[row of pair
+    (n, k)]``, a pair that landed elsewhere adding nothing."""
+    return _rows_out(ys, tok_of_row, n_tiles, block_m, weights.shape[0],
+                     weights, slot_of_row)
 
 
-def _combine_fwd(ys, weights, pos, tok_of_row, slot_of_row):
-    return (_combine(ys, weights, pos, tok_of_row, slot_of_row),
-            (ys, weights, pos, tok_of_row, slot_of_row))
+def _combine_fwd(ys, weights, tok_of_row, slot_of_row, n_tiles, block_m):
+    return (_combine(ys, weights, tok_of_row, slot_of_row, n_tiles, block_m),
+            (ys, weights, tok_of_row, slot_of_row, n_tiles))
 
 
-def _combine_bwd(res, d_out):
-    ys, weights, pos, tok_of_row, slot_of_row = res
-    w_row = weights.at[tok_of_row, slot_of_row].get(
-        mode="fill", fill_value=0)                             # [M]
-    d_ys = (d_out.at[tok_of_row].get(mode="fill", fill_value=0)
-            * w_row[:, None]).astype(ys.dtype)
-    picked = ys.at[pos].get(mode="fill", fill_value=0)
-    d_w = (picked.astype(jnp.float32) * d_out[:, None, :]).sum(axis=-1)
+def _combine_bwd(block_m, res, d_out):
+    """One loop over the tiles in use, with ``g = d_out[tok_of_row[r]]``:
+    ``d_ys[r] = w_r g``, and ``<ys[r], g>`` is ``d_w`` of the pair row r
+    holds (a pair that landed elsewhere keeps 0)."""
+    ys, weights, tok_of_row, slot_of_row, n_tiles = res
+    past = weights.shape[0] + jnp.arange(block_m, dtype=tok_of_row.dtype)
+
+    def tile(t, carry):
+        d_ys, d_w = carry
+        tok = _tile_of(tok_of_row, t, block_m)
+        slot = _tile_of(slot_of_row, t, block_m)
+        g = d_out.at[tok].get(mode="fill", fill_value=0)
+        w = weights.at[tok, slot].get(mode="fill", fill_value=0)
+        dw = (_tile_of(ys, t, block_m).astype(jnp.float32) * g).sum(axis=-1)
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    d_ys, (g * w[:, None]).astype(ys.dtype), t * block_m, 0),
+                d_w.at[jnp.where(tok < weights.shape[0], tok, past),
+                       slot].set(dw, mode="drop", unique_indices=True))
+
+    d_ys, d_w = _over_tiles(
+        tile, n_tiles,
+        (jnp.zeros(ys.shape, ys.dtype),
+         jnp.zeros(weights.shape, jnp.float32)),
+        (ys, weights, d_out, tok_of_row, n_tiles))
     return d_ys, d_w, None, None, None
 
 
@@ -164,14 +260,14 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
     T_local, k]`` int32 and float32.
 
     The rows' buffers have room for EVERY pair (all ``N k`` may land
-    here); the kernels' grids end at the tiles in use, while what is not
-    a kernel — the gathers into and out of expert order, the gates —
-    reads and writes the buffers whole (PERF.md section 5)."""
+    here); the kernels' grids and the loops into and out of expert
+    order end at the tiles in use, while the gate between the products
+    still reads and writes the buffers whole (PERF.md section 5)."""
     B, T, E = h.shape
     N, k = B * T, cfg.moe_top_k
     held = cfg.experts_held
     n_loc = held // n_model
-    block_m = BLOCK_M if N * k >= 16 * BLOCK_M else 16
+    block_m = block_rows(N * k)
     flat = h.reshape(N, E)
     me = jax.lax.axis_index(model_axis)
     with jax.named_scope("tf.moe_route"):
@@ -183,9 +279,8 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
                          n_loc).reshape(N * k)
         plan = routing_plan(dest, n_loc)
         counts = plan[1]
-        pos, pair_of_row, tile_group, n_tiles = expert_order(
+        _, pair_of_row, tile_group, n_tiles = expert_order(
             dest, plan, n_loc, block_m, tiles_for(N * k, n_loc, block_m))
-        pos = pos.reshape(N, k)
         placed = pair_of_row < N * k
         tok_of_row = jnp.where(placed, pair_of_row // k, N)
         slot_of_row = pair_of_row % k
@@ -196,7 +291,7 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
             else jax.lax.pcast(a, model_axis, to="varying")
             for a in (flat, weights))
     with jax.named_scope("tf.moe_dispatch"):
-        xs = _dispatch(flat, tok_of_row, pos)
+        xs = _dispatch(flat, tok_of_row, n_tiles, block_m)
     with jax.named_scope("tf.moe_experts"):
         def product(x, w):
             return grouped_matmul(x, w, tile_group, n_tiles,
@@ -207,7 +302,8 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
                * up.astype(jnp.float32)).astype(cfg.dtype)
         ys = product(act, lp["moe_w_out"])
     with jax.named_scope("tf.moe_combine"):
-        out = _combine(ys, weights, pos, tok_of_row, slot_of_row)
+        out = _combine(ys, weights, tok_of_row, slot_of_row, n_tiles,
+                       block_m)
         out = jax.lax.psum(out, model_axis)
         # this rank's experts' loads at their place among the held ones
         mine = jnp.arange(held) // n_loc == me

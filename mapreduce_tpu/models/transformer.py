@@ -35,7 +35,8 @@ from ..obs.trace import TRACER
 from ..ops.flash_attention import KEPT_NAMES, flash_attention
 from ..parallel.ring import ring_attention
 from .looplm import apply_rope, looped_loss, rope_tables
-from .moe import STAT_DROPPED, STAT_ROUTED, routed_experts
+from .moe import (STAT_DROPPED, STAT_ROUTED, block_rows, routed_experts,
+                  tiles_for)
 from .operators import grouped_qkv, rmsnorm as _rmsnorm, short_conv
 
 Params = Dict[str, jax.Array]
@@ -88,6 +89,13 @@ _MOE_SHARE = _metrics.gauge(
     "mrtpu_moe_pairs_held_share",
     "pairs landing on the experts held here over all pairs routed, of "
     "an expert layer at the last step observed (labels: layer)")
+_MOE_ROWS = _metrics.gauge(
+    "mrtpu_moe_rows_in_use_share",
+    "rows of the tiles in use (each held expert's pairs, padded to whole "
+    "tiles, at least one) over the rows the expert layer's buffers hold "
+    "for every pair, at the last step observed: what the loops into and "
+    "out of expert order and the grouped kernels touch of them; 1 would "
+    "mean every pair landed here (labels: layer)")
 
 
 @dataclass(frozen=True)
@@ -984,18 +992,26 @@ class TransformerTrainer:
         and count
         ``mrtpu_moe_pairs_held_total`` and
         ``mrtpu_moe_dropped_pairs_total``, set
-        ``mrtpu_moe_expert_load_max_over_mean{layer}`` and
-        ``mrtpu_moe_pairs_held_share{layer}`` from it; returns it as
-        numpy."""
+        ``mrtpu_moe_expert_load_max_over_mean{layer}``,
+        ``mrtpu_moe_pairs_held_share{layer}`` and
+        ``mrtpu_moe_rows_in_use_share{layer}`` from it (the last as on
+        one data shard: over several the loads are the shards' sums and
+        it reads low); returns it as numpy."""
         stats = np.asarray(stats["loads"])
         loads = stats[:, :STAT_DROPPED]
         _MOE_PAIRS.inc(int(loads.sum()))
         _MOE_DROPPED.inc(int(stats[:, STAT_DROPPED].sum()))
+        n_model = self.mesh.shape["model"]
         for layer, row, routed in zip(self.cfg.moe_layers, loads,
                                       stats[:, STAT_ROUTED]):
             _MOE_LOAD.set(float(row.max() / max(row.mean(), 1e-9)),
                           layer=layer)
             _MOE_SHARE.set(float(row.sum() / routed), layer=layer)
+            pairs = int(routed) // self.n_data        # a device routes
+            block_m = block_rows(pairs)
+            tiles = np.maximum(-(-row // block_m), 1).sum()
+            _MOE_ROWS.set(float(tiles / (self.n_data * n_model * tiles_for(
+                pairs, len(row) // n_model, block_m))), layer=layer)
         return stats
 
     # -- optimizer (optax) path -----------------------------------------

@@ -368,6 +368,18 @@ def test_observe_experts_counts_and_sets_the_gauges(trainer):
             == pytest.approx(row[:-2].max() / row[:-2].mean())
 
 
+def test_rows_in_use_share_is_the_loads_in_whole_tiles(trainer):
+    """1,024 pairs a step on 4 held experts: tiles of 16 rows, 64 + 4 of
+    them in the buffers; every expert owns at least one."""
+    by_hand = {1: ([0, 16, 17, 100], (1 + 1 + 2 + 7) / 68),
+               2: ([256, 256, 256, 256], 64 / 68)}
+    trainer.observe_experts({"loads": np.array(
+        [loads + [0, PAIRS] for loads, _ in by_hand.values()])})
+    for layer, (_, share) in by_hand.items():
+        assert REGISTRY.sum("mrtpu_moe_rows_in_use_share", layer=layer) \
+            == pytest.approx(share)
+
+
 def test_save_and_load_carry_the_new_tensors(trainer, tmp_path):
     params, opt_state = trainer.init_state()
     params, opt_state, *_ = trainer.step_opt(params, opt_state, TOKENS)

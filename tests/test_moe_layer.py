@@ -69,6 +69,147 @@ def test_expert_order_places_every_held_pair_once(name):
             1, -(-len(rows) // bm))
 
 
+# -- rows into expert order and back ----------------------------------------
+#
+# The plain reference: every row gathered, whether its tile is in use or
+# not (``M`` rows by ``tok_of_row``, ``N k`` rows by ``pos``), as
+# ``models/moe.py`` moved them until PR 36.
+
+@jax.custom_vjp
+def _gather_dispatch(flat, tok_of_row, pos):
+    return flat.at[tok_of_row].get(mode="fill", fill_value=0)
+
+
+def _gather_dispatch_fwd(flat, tok_of_row, pos):
+    return _gather_dispatch(flat, tok_of_row, pos), pos
+
+
+def _gather_dispatch_bwd(pos, d_xs):
+    d_flat = d_xs.at[pos].get(mode="fill", fill_value=0)       # [N, k, E]
+    return (d_flat.astype(jnp.float32).sum(axis=1).astype(d_xs.dtype),
+            None, None)
+
+
+_gather_dispatch.defvjp(_gather_dispatch_fwd, _gather_dispatch_bwd)
+
+
+@jax.custom_vjp
+def _gather_combine(ys, weights, pos, tok_of_row, slot_of_row):
+    picked = ys.at[pos].get(mode="fill", fill_value=0)         # [N, k, E]
+    return (picked.astype(jnp.float32) * weights[..., None]).sum(axis=1)
+
+
+def _gather_combine_fwd(ys, weights, pos, tok_of_row, slot_of_row):
+    return (_gather_combine(ys, weights, pos, tok_of_row, slot_of_row),
+            (ys, weights, pos, tok_of_row, slot_of_row))
+
+
+def _gather_combine_bwd(res, d_out):
+    ys, weights, pos, tok_of_row, slot_of_row = res
+    w_row = weights.at[tok_of_row, slot_of_row].get(
+        mode="fill", fill_value=0)                             # [M]
+    d_ys = (d_out.at[tok_of_row].get(mode="fill", fill_value=0)
+            * w_row[:, None]).astype(ys.dtype)
+    picked = ys.at[pos].get(mode="fill", fill_value=0)
+    d_w = (picked.astype(jnp.float32) * d_out[:, None, :]).sum(axis=-1)
+    return d_ys, d_w, None, None, None
+
+
+_gather_combine.defvjp(_gather_combine_fwd, _gather_combine_bwd)
+
+
+def _top_k_of(n_tokens, experts, k):
+    """k distinct experts a token, as a router chooses them."""
+    return np.stack([RNG.permutation(experts)[:k] for _ in range(n_tokens)])
+
+
+#: ``(dest [N, k], held experts)``: the cases above a pair a token, then
+#: the loops' edges among 8 held experts (8 = landed elsewhere)
+ROW_CASES = {
+    **{name: (d[:, None], 3) for name, d in DESTS.items()},
+    "no pair lands, an empty tile an expert": (np.full((24, 4), 8), 8),
+    "every pair on one expert": (np.full((90, 1), 5), 8),
+    "one expert over five tiles beside seven empty ones": (
+        np.concatenate([np.full((70, 1), 3), np.full((70, 3), 8)], axis=1),
+        8),
+    "top-4 of 16 experts, 8 held": (np.minimum(_top_k_of(50, 16, 4), 8), 8),
+}
+ROW_TILES = {"no pair lands, an empty tile an expert": 8,
+             "every pair on one expert": 6 + 7,
+             "one expert over five tiles beside seven empty ones": 5 + 7}
+
+
+def _row_case(name, E=24):
+    dest, G = ROW_CASES[name]
+    (N, k), bm = dest.shape, 16
+    flat_dest = jnp.asarray(dest.reshape(-1), jnp.int32)
+    pos, pair_of_row, _, n_tiles = moe.expert_order(
+        flat_dest, moe.routing_plan(flat_dest, G), G, bm,
+        moe.tiles_for(N * k, G, bm))
+    tok_of_row = jnp.where(pair_of_row < N * k, pair_of_row // k, N)
+    tables = dict(pos=pos.reshape(N, k), tok_of_row=tok_of_row,
+                  slot_of_row=pair_of_row % k, n_tiles=n_tiles)
+    M, U = pair_of_row.shape[0], int(n_tiles[0]) * bm
+    assert U // bm == ROW_TILES.get(name, U // bm) and U <= M
+    # what the kernels leave past the tiles in use is not zeros
+    unwritten = np.where(np.arange(M)[:, None] < U, 0.0, np.nan)
+    normal = lambda *shape: RNG.normal(size=shape).astype(np.float32)
+    return tables, bm, U, dict(
+        flat=normal(N, E), rows=normal(M, E) + unwritten,
+        weights=np.abs(normal(N, k)), d_out=normal(N, E))
+
+
+@pytest.mark.parametrize("name", sorted(ROW_CASES))
+def test_dispatch_moves_the_rows_in_use_as_the_gather_of_every_row(name):
+    t, bm, U, a = _row_case(name)
+
+    @jax.jit
+    def loops(flat, d_xs):
+        xs, vjp = jax.vjp(lambda f: moe._dispatch(
+            f, t["tok_of_row"], t["n_tiles"], bm), flat)
+        return xs, vjp(d_xs)[0]
+
+    @jax.jit
+    def gathers(flat, d_xs):
+        xs, vjp = jax.vjp(lambda f: _gather_dispatch(
+            f, t["tok_of_row"], t["pos"]), flat)
+        return xs, vjp(d_xs)[0]
+
+    xs, d_flat = map(np.asarray, loops(a["flat"], a["rows"]))
+    want_xs, want_d_flat = map(np.asarray, gathers(a["flat"], a["rows"]))
+    assert np.array_equal(xs[:U], want_xs[:U])     # a copy: to the bit
+    np.testing.assert_allclose(d_flat, want_d_flat, rtol=1e-6, atol=1e-6)
+    again = loops(a["flat"], a["rows"])
+    assert np.array_equal(xs, again[0]) and np.array_equal(d_flat, again[1])
+
+
+@pytest.mark.parametrize("name", sorted(ROW_CASES))
+def test_combine_sums_the_rows_in_use_as_the_gather_of_every_pair(name):
+    t, bm, U, a = _row_case(name)
+    rows = (t["tok_of_row"], t["slot_of_row"])
+
+    @jax.jit
+    def loops(ys, weights, d_out):
+        out, vjp = jax.vjp(lambda y, w: moe._combine(
+            y, w, *rows, t["n_tiles"], bm), ys, weights)
+        return (out,) + vjp(d_out)
+
+    @jax.jit
+    def gathers(ys, weights, d_out):
+        out, vjp = jax.vjp(lambda y, w: _gather_combine(
+            y, w, t["pos"], *rows), ys, weights)
+        return (out,) + vjp(d_out)
+
+    got = [np.asarray(x) for x in loops(a["rows"], a["weights"], a["d_out"])]
+    want = [np.asarray(x) for x in gathers(
+        np.nan_to_num(a["rows"]), a["weights"], a["d_out"])]
+    for g, w, rows in zip(got, want, (None, U, None)):   # out, d_ys, d_w
+        np.testing.assert_allclose(g[:rows], w[:rows], rtol=1e-6, atol=1e-6)
+    again = loops(a["rows"], a["weights"], a["d_out"])
+    assert all(np.array_equal(g, b, equal_nan=True)
+               for g, b in zip(got, again))
+
+
 # -- the grouped products ---------------------------------------------------
 
 
@@ -371,6 +512,75 @@ def test_grouped_kernels_compile_for_a_v5e_at_2304_by_896(one_chip, mosaic):
             sds((392,), jnp.int32), sds((1,), jnp.int32)
         ).compile().as_text()
         assert text.count("moe_gmm") >= 2 and "moe_tgmm" in text
+
+
+@pytest.mark.parametrize("N, E, k", [(32768, 2048, 4), (24576, 2304, 8)],
+                         ids=["train-lfm2moe-8k", "train-mellum2-long"])
+def test_no_row_mover_compiled_for_a_v5e_touches_every_row(one_chip, mosaic,
+                                                           N, E, k):
+    """``_dispatch`` and ``_combine`` with their transposes at a cell's
+    shape, 8 held experts: no gather or scatter of the compiled programs
+    has an operand or a result of ``M`` or ``N k`` rows of ``E`` (the
+    loops move 512 at a time; a scatter's ``[N, E]`` carry is updated in
+    place), and the two programs reserve less than the gathers of every
+    row do.  Each function alone: composed, the buffers BETWEEN them
+    (``xs``, ``d_ys``) are temporaries of either formulation."""
+    import re
+
+    bm = 512
+    M = moe.tiles_for(N * k, 8, bm) * bm
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    order = (sds((N, k), jnp.int32), sds((M,), jnp.int32),
+             sds((M,), jnp.int32), sds((1,), jnp.int32))
+
+    def vjp_of(dispatch, combine):
+        def rows_in(flat, d_xs, pos, tok, slot, n_tiles):
+            xs, vjp = jax.vjp(
+                lambda f: dispatch(f, pos, tok, slot, n_tiles), flat)
+            return xs, vjp(d_xs)
+
+        def rows_out(ys, weights, d_out, pos, tok, slot, n_tiles):
+            out, vjp = jax.vjp(
+                lambda y, w: combine(y, w, pos, tok, slot, n_tiles),
+                ys, weights)
+            return out, vjp(d_out)
+
+        return (jax.jit(rows_in).lower(
+                    sds((N, E), jnp.bfloat16), sds((M, E), jnp.bfloat16),
+                    *order).compile(),
+                jax.jit(rows_out).lower(
+                    sds((M, E), jnp.bfloat16), sds((N, k), jnp.float32),
+                    sds((N, E), jnp.float32), *order).compile())
+
+    loops = vjp_of(
+        lambda f, pos, tok, slot, n: moe._dispatch(f, tok, n, bm),
+        lambda y, w, pos, tok, slot, n: moe._combine(y, w, tok, slot, n,
+                                                     bm))
+    gathers = vjp_of(
+        lambda f, pos, tok, slot, n: _gather_dispatch(f, tok, pos),
+        lambda y, w, pos, tok, slot, n: _gather_combine(y, w, pos, tok,
+                                                        slot))
+
+    def whole_buffers_moved(compiled):
+        text = compiled.as_text()
+        shape_of = {name: tuple(int(d) for d in dims.split(",") if d)
+                    for name, dims in re.findall(
+                        r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text)}
+        moved = []
+        for line in re.findall(r"^.* (?:gather|scatter)\(.*$", text, re.M):
+            for name in re.findall(r"%[\w.\-]+", line.split(", metadata")[0]):
+                shape = shape_of.get(name, ())
+                if (len(shape) >= 2 and shape[-1] == E
+                        and int(np.prod(shape[:-1])) in (M, N * k)):
+                    moved.append((name, shape))
+        return moved
+
+    assert not any(whole_buffers_moved(c) for c in loops)
+    assert all(whole_buffers_moved(c) for c in gathers)   # the check sees
+    reserved = lambda pair: sum(
+        c.memory_analysis().temp_size_in_bytes for c in pair)
+    assert reserved(loops) < reserved(gathers)
 
 
 def test_windowed_flash_kernels_compile_for_a_v5e_at_the_cells_shape(
