@@ -19,9 +19,14 @@ Everything is bf16 matmuls on the MXU with f32 accumulators/params.
 
 from __future__ import annotations
 
+import collections
 import functools
+import gc
+import itertools
+import statistics
+import time
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -30,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import compile as _compile_obs
+from ..obs import memory as _memory_obs
 from ..obs import metrics as _metrics
 from ..obs.trace import TRACER
 from ..ops.flash_attention import KEPT_NAMES, flash_attention
@@ -56,15 +62,15 @@ _REMAT_KEPT = _metrics.gauge(
     "bytes a device holds from the forward to the backward pass of a "
     "training step because remat keeps them beside the layer inputs: the "
     "flash kernel's output and row statistics of every layer "
-    "application; 0 with remat or the kernel off; set at each dispatch "
-    "of a step (labels: program)")
+    "application; 0 with remat or the kernel off; set when a step meets "
+    "a batch of another shape (labels: program)")
 _LOSS_KEPT = _metrics.gauge(
     "mrtpu_train_loss_kept_bytes",
     "bytes a device holds from the forward to the backward pass of a "
     "training step because the loss keeps them beside its inputs: each "
     "position's float32 log-sum-exp over the vocabulary, after every "
-    "pass of a looped model; set at each dispatch of a step (labels: "
-    "program)")
+    "pass of a looped model; set when a step meets a batch of another "
+    "shape (labels: program)")
 _PASS_LOSS = _metrics.gauge(
     "mrtpu_train_loop_pass_loss",
     "a looped model's mean next-token loss after each pass over the "
@@ -96,6 +102,90 @@ _MOE_ROWS = _metrics.gauge(
     "for every pair, at the last step observed: what the loops into and "
     "out of expert order and the grouped kernels touch of them; 1 would "
     "mean every pair landed here (labels: layer)")
+_MOE_TILES = _metrics.gauge(
+    "mrtpu_moe_tiles_in_use",
+    "tiles in use of an expert layer at the last step observed: each "
+    "held expert's pairs in whole tiles, at least one an expert, summed "
+    "(the count mrtpu_moe_rows_in_use_share is the share of) (labels: "
+    "layer)")
+#: 10 ms to 10 s: a training step, and the host's wait for one
+STEP_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+_STEP_SECONDS = _metrics.histogram(
+    "mrtpu_train_step_seconds",
+    "a training step from the one before it proven done to itself "
+    "proven done (the record's step_s: TransformerTrainer.step_log) "
+    "(labels: program)", buckets=STEP_BUCKETS)
+_HOST_WAIT = _metrics.histogram(
+    "mrtpu_train_host_wait_seconds",
+    "the host's blocking read that proves a training step done (the "
+    "record's wait_s); near zero on a slow step, the host was late and "
+    "the device had finished (labels: program)", buckets=STEP_BUCKETS)
+_TOKENS = _metrics.counter(
+    "mrtpu_train_tokens_total",
+    "tokens (B x T of the batch) of the training steps proven done "
+    "(labels: program)")
+_SLOW_STEPS = _metrics.counter(
+    "mrtpu_train_slow_steps_total",
+    "training steps over 1.5 times the median of the 32 before them, "
+    "by what their record shows (labels: cause = compiled, gc, "
+    "host_turnaround, host_overlap, device)")
+
+#: step records a trainer keeps (``TransformerTrainer.step_log``)
+STEP_LOG_SIZE = 4096
+#: a step is slow over ``SLOW_OVER`` times the median ``step_s`` of the
+#: ``SLOW_WINDOW`` records before it, once there are ``SLOW_MIN`` of them
+SLOW_OVER, SLOW_WINDOW, SLOW_MIN = 1.5, 32, 8
+
+# seconds of Python garbage collection in this process so far (two
+# stamps a collection; the callback is registered by the first trainer)
+_GC = [0.0, 0.0]                       # [total, the running one's start]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC[1] = time.monotonic()
+    else:
+        _GC[0] += time.monotonic() - _GC[1]
+
+
+def _ledger_acquisitions() -> int:
+    """Programs the compile ledger compiled or fetched from the
+    persistent cache so far (``benchmark/run.ledger_acquisitions``'s
+    count)."""
+    programs = _compile_obs.LEDGER.snapshot().get("programs", {})
+    return sum(p["compiled"] + p["persistent_hit"]
+               for p in programs.values())
+
+
+def slow_cause(rec: dict, before: List[dict]) -> Optional[str]:
+    """Why the step of record *rec* was slow, or None where it was not:
+    *before* are the records before it, oldest first, the last of them
+    the step before.  Slow is ``step_s`` over ``SLOW_OVER`` times their
+    median; the excess over the median is booked to the first of
+    ``compiled`` (the compile ledger acquired a program), ``gc``
+    (Python's collector ran for over half the excess),
+    ``host_turnaround`` (the device sat idle between the step before,
+    proven done, and this one's dispatch for over half the excess),
+    ``host_overlap`` (the host's wait was under 1 ms: its own work while
+    the step was in flight outlasted the device), else ``device`` (the
+    host waited: the device or the runtime was late).  A step nobody
+    observed has no wait to read and is booked ``device`` too."""
+    before = before[-SLOW_WINDOW:]
+    if len(before) < SLOW_MIN:
+        return None
+    median = statistics.median(r["step_s"] for r in before)
+    excess = rec["step_s"] - median
+    if rec["step_s"] <= SLOW_OVER * median:
+        return None
+    if rec["compiled"]:
+        return "compiled"
+    if rec["gc_s"] > excess / 2:
+        return "gc"
+    if before[-1].get("turnaround_s", 0.0) > excess / 2:
+        return "host_turnaround"
+    if rec.get("wait_s", 1.0) < 1e-3:
+        return "host_overlap"
+    return "device"
 
 
 @dataclass(frozen=True)
@@ -802,6 +892,28 @@ class TransformerTrainer:
                 "sharded over data axis > 1 needs the ring path")
         self.mesh, self.cfg, self.lr = mesh, cfg, learning_rate
         self.seed = seed
+        # the step record (step_log): written by the thread that steps
+        self._steps = collections.deque(maxlen=STEP_LOG_SIZE)
+        self._n_steps = 0            # dispatches so far
+        self._flight = None          # (record, its step_in_flight span,
+        #                              the dispatch's return): not yet done
+        self._last = None            # (record, proven-done stamp) of the
+        #                              last step closed
+        self._due = False            # its deferred work (_finish) is due
+        self._counted = {}           # program -> the shape its gauges say
+        kinds = collections.Counter(
+            cfg.layer_kind(i)[0] for i in range(cfg.n_layers))
+        self._operator_apps = [(kind, cfg.loop_steps * n)
+                               for kind, n in kinds.items()]
+        self._gc_seen = _GC[0]
+        self._acquired = _ledger_acquisitions()
+        self._devices = list(mesh.local_devices)
+        #: the memory sample taken when the training state was last made
+        #: (init_state / init_opt_state outside a trace), as a record's
+        #: ``mem`` with ``phase="state"``; None where nothing reports
+        self.state_mem = None
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
 
         ref = jax.eval_shape(
             lambda: init_transformer(jax.random.key(0), cfg))
@@ -913,9 +1025,15 @@ class TransformerTrainer:
 
     def init_opt_state(self, params):
         """The optimizer's fresh state for *params*, placed on the mesh;
-        under ``jax.jit`` it is made on the devices."""
+        under ``jax.jit`` it is made on the devices.  Made outside a
+        trace, the devices' memory is sampled once it stands
+        (:attr:`state_mem`)."""
         self._need_tx()
-        return self._place_opt_state(self.tx.init(params))
+        opt_state = self._place_opt_state(self.tx.init(params))
+        if not any(isinstance(a, jax.core.Tracer)
+                   for a in jax.tree.leaves(opt_state)):
+            self.state_mem = self._sample_memory("state")
+        return opt_state
 
     def init_params(self, key=None) -> Params:
         """Fresh params on the mesh, from the trainer's seed or from
@@ -943,41 +1061,182 @@ class TransformerTrainer:
         """One SGD step; returns (params, loss) without waiting for the
         device.  Spans ``train_step ⊃ {place_batch, dispatch}``: the two
         ``device_put``s, and the call into the ledgered jit (which
-        returns once the program is enqueued).  A looped model, or one
-        with routed expert layers, trains through :meth:`step_opt`, which
-        returns its statistics; this step refuses one."""
+        returns once the program is enqueued); then ``step_in_flight``
+        until :meth:`observe_loss` proves the step done (:meth:`step_log`).
+        A looped model, or one with routed expert layers, trains through
+        :meth:`step_opt`, which returns its statistics; this step
+        refuses one."""
         if self.with_stats:
             raise RuntimeError(
                 "a looped model (loop_steps > 1) or one with routed "
                 "expert layers trains through step_opt; the SGD step "
                 "carries no statistics")
-        with TRACER.span("train_step"):
+        return self._dispatch("tf_step", self._train_step, (params,), tokens)
+
+    def _dispatch(self, program: str, jitted, state: tuple,
+                  tokens: np.ndarray, **span_args):
+        """Place *tokens*, call *jitted* on *state* and the batch, open
+        the step's record.  Up to the jit's return the device may sit
+        idle, so the record's work there is stamps (and closing a step
+        nobody observed); the rest runs behind the step in flight."""
+        t_enter = time.monotonic()
+        if self._flight is not None:
+            self._close(t_enter, "next_step")
+        with TRACER.span("train_step", **span_args) as root:
             with TRACER.span("place_batch"):
                 x, y = self.place_batch(tokens)
+            t_placed = time.monotonic()
             with TRACER.span("dispatch"):
-                self._count_step("tf_step", x)
-                return self._train_step(params, x, y)
+                self._count_step(program, x)
+                out = jitted(*state, x, y)
+            t_out = time.monotonic()
+        flight = TRACER.begin("step_in_flight", parent=root,
+                              step=self._n_steps)
+        # from here on the device is running the step
+        rec = {"step": self._n_steps, "program": program,
+               "tokens": int(x.size), "t_enter": t_enter,
+               "place_s": t_placed - t_enter, "dispatch_s": t_out - t_placed}
+        self._n_steps += 1
+        if self._last is not None and "turnaround_s" not in self._last[0]:
+            last, t_done = self._last
+            last["turnaround_s"] = t_out - t_done
+        acquired = _ledger_acquisitions()
+        rec["compiled"] = acquired - self._acquired
+        self._acquired = acquired
+        mem = self._sample_memory("in_flight")
+        if mem is not None:
+            rec["mem"] = mem
+        self._flight = (rec, flight, t_out)
+        self._finish()
+        return out
 
     def _count_step(self, program: str, x: jax.Array) -> None:
         """The step about to be dispatched on inputs *x* [B, T]: its
         layer applications, and what ``remat`` and the loss make it
-        keep."""
+        keep.  All of it follows from the configuration and the batch's
+        shape: the applications by operator were counted when the
+        trainer was made, the two gauges are set when *program* meets
+        another shape."""
         _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers)
-        for i in range(self.cfg.n_layers):
-            _OPERATOR_APPS.inc(self.cfg.loop_steps,
-                               operator=self.cfg.layer_kind(i)[0])
-        B, t_local = x.shape[0], x.shape[1] // self.n_data
-        _REMAT_KEPT.set(remat_kept_bytes(
-            self.cfg, self.mesh.shape["model"], B, t_local), program=program)
-        _LOSS_KEPT.set(loss_kept_bytes(self.cfg, B, t_local),
-                       program=program)
+        for kind, n in self._operator_apps:
+            _OPERATOR_APPS.inc(n, operator=kind)
+        if self._counted.get(program) != x.shape:
+            self._counted[program] = x.shape
+            B, t_local = x.shape[0], x.shape[1] // self.n_data
+            _REMAT_KEPT.set(remat_kept_bytes(
+                self.cfg, self.mesh.shape["model"], B, t_local),
+                program=program)
+            _LOSS_KEPT.set(loss_kept_bytes(self.cfg, B, t_local),
+                           program=program)
+
+    # -- the step record ------------------------------------------------
+
+    def step_log(self) -> List[dict]:
+        """One record a step proven done, oldest first, of the last
+        ``STEP_LOG_SIZE``; plain dicts, stamps ``time.monotonic()``:
+
+        ``step`` (0-based count of this trainer's dispatches), ``program``,
+        ``tokens``; ``t_enter``, ``place_s``, ``dispatch_s`` (the two
+        spans); ``overlap_s`` (the dispatch's return to the start of the
+        wait: the caller's own time while the device runs) and ``wait_s``
+        (the blocking read in ``observe_*``), both absent where nobody
+        observed; ``turnaround_s`` (proven done to the NEXT dispatch's
+        return: the device idle, the host at work; absent on the last);
+        ``step_s`` (proven done to proven done; the first from its own
+        ``t_enter``) and ``closed_by``: ``observe``, or ``next_step``
+        where the caller read the loss itself and the next step's entry
+        closed this one, enter to enter; ``compiled`` (programs the
+        compile ledger acquired between the dispatch before and this
+        one's return), ``gc_s`` (seconds of Python garbage collection);
+        ``mem`` (bytes in use and reserved, and their peaks, of the
+        fullest local device while the step was in flight; absent where
+        nothing reports); for a routed model ``pairs_held`` and, a layer,
+        ``tiles_in_use`` and ``load_max_over_mean``; ``slow``, absent or
+        :func:`slow_cause`'s.  The step in flight has no record yet."""
+        self._finish()
+        return [dict(r) for r in self._steps]
+
+    def _sample_memory(self, phase: str) -> Optional[dict]:
+        """``obs/memory.sample_device_memory`` of the local devices (it
+        sets ``mrtpu_device_memory_bytes``): the fullest one's bytes in
+        use and reserved and their peaks; None where none reports."""
+        devices = _memory_obs.sample_device_memory(self._devices)["devices"]
+        if not devices:
+            return None
+        device, entry = max(devices.items(), key=lambda kv: (
+            kv[1].get("bytes_in_use", 0) + kv[1].get("bytes_reserved", 0)))
+        return {"phase": phase, "device": device,
+                **{k: v for k, v in entry.items() if k != "bytes_limit"}}
+
+    def _await(self, value) -> Tuple[np.ndarray, Optional[float]]:
+        """*value* on the host, which waits for the step that made it,
+        and the stamp at which it was there (None with no step in
+        flight).  The wait is span ``step_wait``, of the step's trace and
+        under its ``step_in_flight``."""
+        if self._flight is None:
+            return np.asarray(value), None
+        rec, flight, t_out = self._flight
+        t0 = time.monotonic()
+        with TRACER.adopt(f"{flight.trace_id}:{flight.span_id}"), \
+                TRACER.span("step_wait", step=rec["step"]):
+            out = np.asarray(value)
+            t1 = time.monotonic()
+        rec["overlap_s"], rec["wait_s"] = t0 - t_out, t1 - t0
+        return out, t1
+
+    def _close(self, t_done: float, closed_by: str, **end_args) -> dict:
+        """The step in flight was done at *t_done*: end its span, stamp
+        and append its record.  Until the next dispatch returns the
+        device sits idle, so this is all that happens here; histograms,
+        the slow rule and the memory sample wait (:meth:`_finish`)."""
+        rec, flight, _ = self._flight
+        self._flight = None
+        TRACER.end(flight, **end_args)
+        rec["step_s"] = t_done - (rec["t_enter"] if self._last is None
+                                  else self._last[1])
+        rec["closed_by"] = closed_by
+        rec["gc_s"] = _GC[0] - self._gc_seen
+        self._gc_seen = _GC[0]
+        self._steps.append(rec)
+        self._last, self._due = (rec, t_done), True
+        return rec
+
+    def _finish(self) -> None:
+        """The deferred work of the last record closed: the counters an
+        operator reads, and the slow rule."""
+        if not self._due:
+            return
+        self._due = False
+        rec = self._last[0]
+        program = rec["program"]
+        _STEP_SECONDS.observe(rec["step_s"], program=program)
+        if "wait_s" in rec:
+            _HOST_WAIT.observe(rec["wait_s"], program=program)
+        _TOKENS.inc(rec["tokens"], program=program)
+        before = list(itertools.islice(reversed(self._steps), 1,
+                                       SLOW_WINDOW + 1))[::-1]
+        cause = slow_cause(rec, before)
+        if cause is not None:
+            rec["slow"] = cause
+            _SLOW_STEPS.inc(cause=cause)
+
+    def observe_loss(self, loss) -> float:
+        """Read a plain step's loss back to the host, which waits for the
+        step, and close its record; returns it as a float."""
+        loss, t_done = self._await(loss)
+        if t_done is not None:
+            self._close(t_done, "observe")
+        return float(loss)
 
     def observe_passes(self, stats) -> np.ndarray:
         """Read a looped step's ``stats`` ([2, R]: each pass's mean loss,
         each pass's mean exit mass) back to the host — which waits for
-        the step — and set ``mrtpu_train_loop_pass_loss{pass}`` and
+        the step, and closes its record — and set
+        ``mrtpu_train_loop_pass_loss{pass}`` and
         ``mrtpu_train_exit_mass{pass}`` from it; returns it as numpy."""
-        stats = np.asarray(stats)
+        stats, t_done = self._await(stats)
+        if t_done is not None:
+            self._close(t_done, "observe")
         for t in range(stats.shape[1]):
             _PASS_LOSS.set(float(stats[0, t]), **{"pass": t + 1})
             _EXIT_MASS.set(float(stats[1, t]), **{"pass": t + 1})
@@ -986,32 +1245,46 @@ class TransformerTrainer:
     def observe_experts(self, stats) -> np.ndarray:
         """Read a routed step's ``stats["loads"]`` (one row an expert
         layer: the pairs each held expert took, the pairs not placed,
-        the pairs routed) back to the host — which waits for the step;
-        ``stats["chosen"]`` and ``stats["weights"]``, every token's
+        the pairs routed) back to the host — which waits for the step,
+        and closes its record; ``stats["chosen"]`` and
+        ``stats["weights"]``, every token's
         experts and their weights, stay on the device for whoever asks —
         and count
         ``mrtpu_moe_pairs_held_total`` and
         ``mrtpu_moe_dropped_pairs_total``, set
         ``mrtpu_moe_expert_load_max_over_mean{layer}``,
-        ``mrtpu_moe_pairs_held_share{layer}`` and
-        ``mrtpu_moe_rows_in_use_share{layer}`` from it (the last as on
+        ``mrtpu_moe_pairs_held_share{layer}``,
+        ``mrtpu_moe_rows_in_use_share{layer}`` and
+        ``mrtpu_moe_tiles_in_use{layer}`` from it (the last two as on
         one data shard: over several the loads are the shards' sums and
-        it reads low); returns it as numpy."""
-        stats = np.asarray(stats["loads"])
+        the share reads low); returns it as numpy.  With no step in
+        flight (made-up ``stats``) it sets the gauges and records
+        nothing."""
+        stats, t_done = self._await(stats["loads"])
         loads = stats[:, :STAT_DROPPED]
-        _MOE_PAIRS.inc(int(loads.sum()))
+        held = int(loads.sum())
+        rec = (None if t_done is None
+               else self._close(t_done, "observe", pairs_held=held))
+        _MOE_PAIRS.inc(held)
         _MOE_DROPPED.inc(int(stats[:, STAT_DROPPED].sum()))
         n_model = self.mesh.shape["model"]
+        tiles_in_use, skews = [], []
         for layer, row, routed in zip(self.cfg.moe_layers, loads,
                                       stats[:, STAT_ROUTED]):
-            _MOE_LOAD.set(float(row.max() / max(row.mean(), 1e-9)),
-                          layer=layer)
+            skew = float(row.max() / max(row.mean(), 1e-9))
+            _MOE_LOAD.set(skew, layer=layer)
             _MOE_SHARE.set(float(row.sum() / routed), layer=layer)
             pairs = int(routed) // self.n_data        # a device routes
             block_m = block_rows(pairs)
-            tiles = np.maximum(-(-row // block_m), 1).sum()
+            tiles = int(np.maximum(-(-row // block_m), 1).sum())
+            _MOE_TILES.set(tiles, layer=layer)
             _MOE_ROWS.set(float(tiles / (self.n_data * n_model * tiles_for(
                 pairs, len(row) // n_model, block_m))), layer=layer)
+            tiles_in_use.append(tiles)
+            skews.append(skew)
+        if rec is not None:
+            rec.update(pairs_held=held, tiles_in_use=tiles_in_use,
+                       load_max_over_mean=skews)
         return stats
 
     # -- optimizer (optax) path -----------------------------------------
@@ -1032,14 +1305,12 @@ class TransformerTrainer:
         """One optimizer step; returns (params, opt_state, loss), and
         ``stats`` after the loss for a looped model, as
         :meth:`observe_passes` takes it, or one with routed expert
-        layers, as :meth:`observe_experts` does."""
+        layers, as :meth:`observe_experts` does (a dense model's loss
+        goes to :meth:`observe_loss`); spans and record as
+        :meth:`step`'s."""
         self._need_tx()
-        with TRACER.span("train_step", optimizer=True):
-            with TRACER.span("place_batch"):
-                x, y = self.place_batch(tokens)
-            with TRACER.span("dispatch"):
-                self._count_step("tf_step_opt", x)
-                return self._train_step_opt(params, opt_state, x, y)
+        return self._dispatch("tf_step_opt", self._train_step_opt,
+                              (params, opt_state), tokens, optimizer=True)
 
     # -- checkpointing (the reference's GridFS-serialized trainer role,
     # common.lua:24-39; rides the sharded manifest-committed layer of

@@ -51,7 +51,8 @@ from .trace import TRACER
 _DEVICE_MEMORY = gauge(
     "mrtpu_device_memory_bytes",
     "live per-device memory (labels: device, stat=bytes_in_use|"
-    "peak_bytes_in_use|bytes_limit, source=measured|analytic; analytic "
+    "peak_bytes_in_use|bytes_reserved|peak_bytes_reserved|bytes_limit, "
+    "source=measured|analytic; analytic "
     "= the engine's own held-bytes ledger on backends without "
     "memory_stats)")
 _PROGRAM_MEMORY = gauge(
@@ -231,7 +232,10 @@ def sample_device_memory(devices: Sequence[Any],
         if stats:
             measured = True
             entry = {}
+            # a TPU books a loaded program's temporaries apart from
+            # the live buffers, as bytes_reserved
             for stat in ("bytes_in_use", "peak_bytes_in_use",
+                         "bytes_reserved", "peak_bytes_reserved",
                          "bytes_limit"):
                 v = stats.get(stat)
                 if v is None:

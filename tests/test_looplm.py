@@ -670,11 +670,20 @@ def test_stage_report_reads_the_waiting_files_of_the_cell():
     assert waiting == {"looplm." + n for n in (
         "loss_share", "update_share", "ffn_share", "attn_proj_share",
         "rope_share", "exit_gate_share", "unscoped_share", "dispatch_ms",
-        "pass_loop_share", "final_norm_share")}
+        "pass_loop_share", "final_norm_share")} | {"step." + n for n in (
+            "wait_ms", "in_flight_ms", "idle_wait_share",
+            "idle_launch_share", "idle_place_share", "idle_dispatch_share")}
     result = stage_report.report(cell, config, seed=2**31 + 29,
                                  devices=jax.devices()[:1])
     assert result["correct"] is True
-    assert set(result["metrics"]) == {"looplm.step_ms", "looplm.dispatch_ms"}
+    # the program's spans are in a CPU trace too; no device plane, so no
+    # idle time under them
+    assert set(result["metrics"]) == {
+        "looplm.step_ms", "looplm.dispatch_ms", "step.wait_ms",
+        "step.in_flight_ms"}
+    ms = {n: m["value"] for n, m in result["metrics"].items()}
+    assert 0 < ms["step.wait_ms"] < ms["step.in_flight_ms"] \
+        < ms["looplm.step_ms"]
 
 
 def test_kind_faults_name_each_limit():
