@@ -25,12 +25,13 @@ pairs that did.  The products are grouped over the held experts
 the rows are moved into expert order and back (``_dispatch``,
 ``_combine`` and their transposes) by loops that end at the same bound,
 a tile a trip, from the row side: no operation of theirs touches the
-buffers whole.  What does not follow the tiles in use yet: the gate
-``silu(a) * u`` between the products, an elementwise pass over every
-row of the buffers, and the plan over all ``N k`` pairs (PERF.md
-section 5).  The plan that puts pairs into expert order (rank within
-destination, count a destination) is the exchange's,
-``parallel/shuffle.routing_plan``.
+buffers whole, and the gate ``silu(a) * u`` between the products and the
+sum of the first two products' gradients for the rows are kernels on
+that bound too (``ops/grouped_matmul.gated``, ``twice``).  What does not
+follow the tiles in use: the plan over all ``N k`` pairs, and the
+zeroing of the loops' carries (PERF.md section 5).  The plan that puts
+pairs into expert order (rank within destination, count a destination)
+is the exchange's, ``parallel/shuffle.routing_plan``.
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import grouped_matmul
+from ..ops.grouped_matmul import gated, grouped_matmul, twice
 from ..parallel.shuffle import routing_plan
 
 #: rows of a tile of the grouped products; every held expert's rows start
@@ -260,9 +261,10 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
     T_local, k]`` int32 and float32.
 
     The rows' buffers have room for EVERY pair (all ``N k`` may land
-    here); the kernels' grids and the loops into and out of expert
-    order end at the tiles in use, while the gate between the products
-    still reads and writes the buffers whole (PERF.md section 5)."""
+    here); the kernels' grids (the products, the gate between them,
+    the sum of two products' gradients) and the loops into and out of
+    expert order end at the tiles in use: rows past them are neither
+    written nor read."""
     B, T, E = h.shape
     N, k = B * T, cfg.moe_top_k
     held = cfg.experts_held
@@ -297,9 +299,11 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
             return grouped_matmul(x, w, tile_group, n_tiles,
                                   block_m=block_m)
 
-        gate, up = product(xs, lp["moe_w_gate"]), product(xs, lp["moe_w_in"])
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(cfg.dtype)
+        # two products take the rows: their gradients' sum is a kernel's
+        for_gate, for_up = twice(xs, n_tiles, block_m=block_m)
+        gate = product(for_gate, lp["moe_w_gate"])
+        up = product(for_up, lp["moe_w_in"])
+        act = gated(gate, up, n_tiles, block_m=block_m)
         ys = product(act, lp["moe_w_out"])
     with jax.named_scope("tf.moe_combine"):
         out = _combine(ys, weights, tok_of_row, slot_of_row, n_tiles,

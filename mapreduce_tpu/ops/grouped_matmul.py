@@ -15,20 +15,31 @@ prefetched table), so the arithmetic follows the rows that are there,
 not ``M``.  Rows of tiles past ``n_tiles`` are NOT written: whoever
 reads the result reads no row it did not fill.
 
-Two kernels, through ``ops/pallas_compat.pallas_call`` like the others:
+Four kernels, through ``ops/pallas_compat.pallas_call`` like the others:
 
 * ``moe_gmm``: the product above, and with ``transpose_rhs`` the
   gradient for ``lhs`` (``dout @ rhs[g]^T``);
 * ``moe_tgmm``: the gradient for ``rhs``, ``lhs[rows of g]^T @ dout[rows
   of g]`` summed over the group's tiles in float32 — the padding rows are
-  zero in ``lhs`` and add nothing.
+  zero in ``lhs`` and add nothing;
+* ``moe_gate``: what stands between two products of a gated layer,
+  ``silu(a) * u`` tile by tile, and its transpose, on the same bound;
+* ``moe_add``: the sum of two gradients for the same rows, which is the
+  transpose of handing one ``lhs`` to two products.
 
-:func:`grouped_matmul` ties them into one differentiable product; it
-takes the float32 master weights, multiplies in ``lhs``'s type and
-returns the weight gradient in float32 as the kernel accumulated it.
-Off the TPU, inside ``shard_map``, where the Pallas interpreter cannot
-run them, the same products are ``jax.lax.ragged_dot`` over the padded
-group sizes.
+The last two know no group and hold to the same contract: rows of tiles
+past ``n_tiles`` are NOT written, and the products and loops that read
+their results read none of them.
+
+:func:`grouped_matmul` ties the first two into one differentiable
+product; it takes the float32 master weights, multiplies in ``lhs``'s
+type and returns the weight gradient in float32 as the kernel
+accumulated it.  :func:`gated` is the third with its transpose,
+:func:`twice` the fan-out whose transpose is the fourth.  Off the TPU,
+inside ``shard_map``, where the Pallas interpreter cannot run them
+(:func:`_runs_kernels`), the products are ``jax.lax.ragged_dot`` over
+the padded group sizes, and the gate and the sum are XLA's own over
+every row.
 """
 
 from __future__ import annotations
@@ -183,6 +194,17 @@ def _grouped_bwd(block_m, kernels, interpret, res, dout):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def _runs_kernels(interpret: bool, table: jax.Array) -> bool:
+    """Whether a call takes the kernels.  The tables are values of the
+    run, each device's its own, and the Pallas interpreter cannot slice
+    a table that varies over shard_map's mesh by a grid index that does
+    not (jax 0.9: its discharge of the read fails the varying-axes
+    check).  Off the TPU the trainer's expert layer is therefore
+    ragged_dot and a plain gate; the kernels run interpreted outside
+    shard_map (tests/test_moe_layer.py) and compiled on the chip."""
+    return not (interpret and jax.typeof(table).vma)
+
+
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
                    n_tiles: jax.Array, *, block_m: int,
                    interpret=None) -> jax.Array:
@@ -200,13 +222,148 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
     if missing:
         rhs = jax.lax.pcast(rhs, missing, to="varying")
     interpret = default_interpret(interpret)
-    # the tables are values of the run, each device's its own, and the
-    # Pallas interpreter cannot slice a table that varies over
-    # shard_map's mesh by a grid index that does not (jax 0.9: its
-    # discharge of the read fails the varying-axes check).  Off the TPU
-    # the trainer's expert products are therefore ragged_dot; the
-    # kernels run interpreted outside shard_map (tests/test_moe_layer.py)
-    # and compiled on the chip
-    kernels = not (interpret and jax.typeof(tile_group).vma)
-    return _grouped(lhs, rhs, tile_group, n_tiles, int(block_m), kernels,
-                    interpret)
+    return _grouped(lhs, rhs, tile_group, n_tiles, int(block_m),
+                    _runs_kernels(interpret, tile_group), interpret)
+
+
+# -- between the products: the gate, and the sum of two gradients -----------
+#
+# Elementwise over the ``(block_m, block_f)`` tiles of the row tiles in
+# use; XLA's own passes run over every row of buffers sized for every
+# pair.
+
+
+def _silu_mul(a, u):
+    """``silu(a) * u`` in float32, as the reference writes it."""
+    return jax.nn.silu(a.astype(jnp.float32)) * u.astype(jnp.float32)
+
+
+def _gate_kernel(pids, n_ref, a_ref, u_ref, act_ref):
+    del pids, n_ref
+    act_ref[...] = _silu_mul(a_ref[...], u_ref[...]).astype(act_ref.dtype)
+
+
+def _gate_bwd_kernel(pids, n_ref, d_ref, a_ref, u_ref, da_ref, du_ref):
+    del pids, n_ref
+    a = a_ref[...].astype(jnp.float32)
+    d = d_ref[...].astype(jnp.float32)
+    s = jax.nn.sigmoid(a)
+    da_ref[...] = (d * u_ref[...].astype(jnp.float32)
+                   * (s * (1 + a * (1 - s)))).astype(da_ref.dtype)
+    du_ref[...] = (d * (a * s)).astype(du_ref.dtype)
+
+
+def _add_kernel(pids, n_ref, a_ref, b_ref, out_ref):
+    del pids, n_ref
+    out_ref[...] = (a_ref[...].astype(jnp.float32)
+                    + b_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def _tile_call(kernel, name, n_out, n_tiles, block_m, interpret, *operands,
+               into_first=False):
+    """*kernel* over the tiles of the row tiles in use: every operand
+    and each of the *n_out* results is ``[M, F]`` like the first.  With
+    *into_first* the one result takes the first operand's buffer (a grid
+    step reads its tile of every operand before it writes its own)."""
+    like = operands[0]
+    block_f = pick_lane_block(like.shape[1], BLOCK_N)
+    tile = pl.BlockSpec((block_m, block_f), lambda i, f, _n: (i, f))
+    out = sds(like.shape, like.dtype, like)
+    return pallas_call(
+        kernel,
+        name=name,
+        grid=(n_tiles[0], like.shape[1] // block_f),
+        num_scalar_prefetch=1,
+        in_specs=[tile] * len(operands),
+        out_specs=[tile] * n_out,
+        out_shape=[out] * n_out,
+        # the call's operand 0 is the prefetched bound
+        input_output_aliases={1: 0} if into_first else {},
+        # what the pass costs over EVERY tile, as XLA reckoned its own
+        # (the bytes decide): a call with no estimate counts as a short
+        # one, the compiler starts the next products' weight prefetches
+        # before it and the step reserves 50 MB more at the LFM2 cell's
+        # shape (PERF.md section 6, PR 38)
+        cost_estimate=pl.CostEstimate(
+            flops=4 * len(operands) * like.size, transcendentals=like.size,
+            bytes_accessed=(len(operands) + n_out) * like.size
+            * like.dtype.itemsize),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+    )(n_tiles, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gated(a, u, n_tiles, block_m, interpret):
+    return _tile_call(_gate_kernel, "moe_gate", 1, n_tiles, block_m,
+                      interpret, a, u)[0]
+
+
+def _gated_fwd(a, u, n_tiles, block_m, interpret):
+    return _gated(a, u, n_tiles, block_m, interpret), (a, u, n_tiles)
+
+
+def _gated_bwd(block_m, interpret, res, d_act):
+    a, u, n_tiles = res
+    da, du = _tile_call(_gate_bwd_kernel, "moe_gate", 2, n_tiles, block_m,
+                        interpret, d_act, a, u)
+    return da, du, None
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def _whole_tiles(x, block_m):
+    if x.shape[0] % block_m:
+        raise ValueError(f"{x.shape[0]} rows are not whole tiles of "
+                         f"{block_m}")
+
+
+def gated(a: jax.Array, u: jax.Array, n_tiles: jax.Array, *, block_m: int,
+          interpret=None) -> jax.Array:
+    """``silu(a) * u`` for ``a, u [M, F]`` of one type, computed in
+    float32 and returned in theirs, on the rows of the first ``n_tiles
+    [1] int32`` tiles of ``block_m``; the other rows of the result are
+    not written.  Differentiable in ``a`` and ``u``, on the same rows.
+    Where the kernels cannot run (:func:`_runs_kernels`) it is the
+    expression over every row."""
+    if a.shape != u.shape:
+        raise ValueError(f"a {a.shape} and u {u.shape} are not one shape")
+    _whole_tiles(a, block_m)
+    interpret = default_interpret(interpret)
+    if not _runs_kernels(interpret, n_tiles):
+        return _silu_mul(a, u).astype(a.dtype)
+    return _gated(a, u, n_tiles, int(block_m), interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _twice(x, n_tiles, block_m, interpret):
+    return x, x
+
+
+def _twice_fwd(x, n_tiles, block_m, interpret):
+    return (x, x), n_tiles
+
+
+def _twice_bwd(block_m, interpret, n_tiles, d):
+    return _tile_call(_add_kernel, "moe_add", 1, n_tiles, block_m, interpret,
+                      *d, into_first=True)[0], None
+
+
+_twice.defvjp(_twice_fwd, _twice_bwd)
+
+
+def twice(x: jax.Array, n_tiles: jax.Array, *, block_m: int,
+          interpret=None):
+    """``(x, x)`` for ``x [M, F]`` that two products take: the gradient
+    for ``x`` is then the sum of theirs, formed on the rows of the first
+    ``n_tiles`` tiles of ``block_m`` alone (in float32, rounded once);
+    its other rows are not written.  Where the kernels cannot run it is
+    a plain pair, and the sum autodiff's over every row."""
+    _whole_tiles(x, block_m)
+    interpret = default_interpret(interpret)
+    if not _runs_kernels(interpret, n_tiles):
+        return x, x
+    return _twice(x, n_tiles, int(block_m), interpret)

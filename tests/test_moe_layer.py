@@ -22,7 +22,8 @@ from mapreduce_tpu.models.transformer import (TransformerConfig,
                                               transformer_param_spec)
 from mapreduce_tpu.obs.metrics import REGISTRY
 from mapreduce_tpu.ops.flash_attention import flash_attention
-from mapreduce_tpu.ops.grouped_matmul import _grouped, grouped_matmul
+from mapreduce_tpu.ops.grouped_matmul import (_grouped, gated,
+                                              grouped_matmul, twice)
 from mapreduce_tpu.parallel import make_mesh
 
 RNG = np.random.default_rng(5)
@@ -262,6 +263,93 @@ def test_grouped_matmul_refuses_rows_that_are_not_the_tables():
         grouped_matmul(x[:-bm], w, tile_group, n_tiles, block_m=bm)
 
 
+# -- the gate between the products -------------------------------------------
+
+
+def _plain_gate(a, u):
+    """What ``models/moe.py`` ran until PR 38, over every row."""
+    return (jax.nn.silu(a.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(a.dtype)
+
+
+def _with_both_gradients(gate):
+    def f(a, u, d_act):
+        act, vjp = jax.vjp(gate, a, u)
+        return (act,) + vjp(d_act)
+
+    return jax.jit(f)
+
+
+@pytest.mark.parametrize("in_use", ["one tile", "a part", "every tile"])
+@pytest.mark.parametrize("Fe", [1536, 896])
+@pytest.mark.parametrize("bm", [16, 512])
+def test_the_gate_kernel_is_silu_times_up_on_the_tiles_in_use(bm, Fe, in_use):
+    """Values and both gradients, interpreted, at the cells' widths and
+    both tile sizes; rows past the tiles in use hold whatever they held
+    and are compared with nothing."""
+    max_tiles = 3
+    n = {"one tile": 1, "a part": 2, "every tile": max_tiles}[in_use]
+    a, u, d_act = (jnp.asarray(RNG.normal(size=(max_tiles * bm, Fe)),
+                               jnp.bfloat16) for _ in range(3))
+    before = REGISTRY.sum("mrtpu_pallas_kernel_builds_total",
+                          kernel="moe_gate", mode="interpret")
+    got = _with_both_gradients(functools.partial(
+        gated, n_tiles=jnp.asarray([n], jnp.int32), block_m=bm))(a, u, d_act)
+    want = _with_both_gradients(_plain_gate)(a, u, d_act)
+    exact = np.testing.assert_array_equal
+    # float32 between the same roundings, the factors of silu' taken in
+    # another order: at most the last place
+    close = functools.partial(np.testing.assert_allclose, rtol=2 ** -7,
+                              atol=1e-6)
+    for g, w, compare in zip(got, want, (exact, close, exact)):
+        assert g.dtype == w.dtype == jnp.bfloat16 and g.shape == w.shape
+        compare(*(np.asarray(x, np.float32)[:n * bm] for x in (g, w)))
+    # forward and backward, each traced once
+    assert REGISTRY.sum("mrtpu_pallas_kernel_builds_total", kernel="moe_gate",
+                        mode="interpret") == before + 2
+
+
+@pytest.mark.parametrize("in_use", [1, 2, 3])
+@pytest.mark.parametrize("E", [2048, 2304])
+@pytest.mark.parametrize("bm", [16, 512])
+def test_rows_taken_twice_get_their_gradients_sum_on_the_tiles_in_use(
+        bm, E, in_use):
+    """``twice`` hands the rows to two products; the gradient for the
+    rows is the two gradients' sum, in float32 and rounded once, on the
+    rows of the tiles in use (3 tiles in all; the rest is compared with
+    nothing)."""
+    x, d1, d2 = (jnp.asarray(RNG.normal(size=(3 * bm, E)), jnp.bfloat16)
+                 for _ in range(3))
+    before = REGISTRY.sum("mrtpu_pallas_kernel_builds_total",
+                          kernel="moe_add", mode="interpret")
+
+    def f(x, d1, d2):
+        both, vjp = jax.vjp(lambda x: twice(
+            x, jnp.asarray([in_use], jnp.int32), block_m=bm), x)
+        return both + vjp((d1, d2))
+
+    x1, x2, d_x = jax.jit(f)(x, d1, d2)
+    assert np.array_equal(x1, x) and np.array_equal(x2, x)
+    assert d_x.dtype == jnp.bfloat16 and d_x.shape == x.shape
+    want = (d1.astype(jnp.float32) + d2.astype(jnp.float32)).astype(
+        jnp.bfloat16)
+    np.testing.assert_array_equal(
+        np.asarray(d_x, np.float32)[:in_use * bm],
+        np.asarray(want, np.float32)[:in_use * bm])
+    assert REGISTRY.sum("mrtpu_pallas_kernel_builds_total", kernel="moe_add",
+                        mode="interpret") == before + 1
+
+
+def test_the_gate_and_the_fan_out_refuse_rows_that_are_not_whole_tiles():
+    a, n = jnp.zeros((40, 128), jnp.float32), jnp.asarray([1], jnp.int32)
+    with pytest.raises(ValueError, match="whole tiles of 16"):
+        gated(a, a, n, block_m=16)
+    with pytest.raises(ValueError, match="whole tiles of 16"):
+        twice(a, n, block_m=16)
+    with pytest.raises(ValueError, match="not one shape"):
+        gated(a[:32], a[:32, :64], n, block_m=16)
+
+
 # -- the routed layer: the shares add up -------------------------------------
 
 SHARE = dict(vocab=32, embed=32, n_layers=1, n_heads=2, head_dim=16, ffn=32,
@@ -315,6 +403,58 @@ def test_the_eight_shares_of_a_layer_sum_to_the_uncut_reference():
                                rtol=1e-4, atol=1e-5)
     assert loads == np.asarray(want_loads).tolist()
     assert sum(loads) == 2 * 24 * 4
+
+
+def test_the_layer_on_the_cpu_is_the_layer_with_the_plain_gate(monkeypatch):
+    """``routed_experts`` on the CPU, output and every gradient, against
+    itself with the gate written out over every row and the rows handed
+    to the two products as a plain pair: under ``shard_map``'s checking
+    the tables vary over the mesh, so the rule of the products takes the
+    plain expressions and builds no kernel.
+    With the checking off nothing varies and the forward runs the three
+    kernels interpreted (the transposes of the layer's casts need the
+    checking)."""
+    cfg = TransformerConfig(**SHARE)
+    E, Fe = SHARE["embed"], SHARE["moe_ffn"]
+    h = jnp.asarray(RNG.normal(size=(2, 24, E)), jnp.float32)
+    lp = {"w_router": jnp.asarray(RNG.normal(size=(E, 64)), jnp.float32),
+          "router_bias": jnp.zeros((64,), jnp.float32),
+          "moe_w_gate": jnp.asarray(RNG.normal(size=(8, E, Fe)) / 6,
+                                    jnp.float32),
+          "moe_w_in": jnp.asarray(RNG.normal(size=(8, E, Fe)) / 6,
+                                  jnp.float32),
+          "moe_w_out": jnp.asarray(RNG.normal(size=(8, Fe, E)) / 4,
+                                   jnp.float32)}
+    d_out = jnp.asarray(RNG.normal(size=h.shape), jnp.float32)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    built = lambda: REGISTRY.sum("mrtpu_pallas_kernel_builds_total",
+                                 kernel="moe_gate", mode="interpret")
+
+    def layer(check_vma=True):
+        return jax.jit(jax.shard_map(
+            lambda h, lp: moe.routed_experts(h, lp, cfg, 1, "data",
+                                             "model")[0],
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=check_vma))
+
+    def with_gradients():
+        out, vjp = jax.vjp(layer(), h, lp)
+        d_h, d_lp = vjp(d_out)
+        return [np.asarray(x) for x in
+                (out, d_h, *(d_lp[n] for n in sorted(lp)))]
+
+    before = built()
+    now = with_gradients()
+    assert built() == before
+    assert np.abs(now[0]).max() > 0.1 and np.abs(now[1]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(layer(check_vma=False)(h, lp)),
+                               now[0], rtol=1e-4, atol=1e-5)
+    assert built() == before + 1
+    monkeypatch.setattr(moe, "gated",
+                        lambda a, u, n_tiles, block_m: _plain_gate(a, u))
+    monkeypatch.setattr(moe, "twice", lambda x, n_tiles, block_m: (x, x))
+    for a, b in zip(now, with_gradients()):
+        np.testing.assert_array_equal(a, b)
 
 
 # -- grouped-query attention --------------------------------------------------
@@ -514,6 +654,16 @@ def test_grouped_kernels_compile_for_a_v5e_at_2304_by_896(one_chip, mosaic):
         assert text.count("moe_gmm") >= 2 and "moe_tgmm" in text
 
 
+def _hlo_shapes(text):
+    """``{%name: its array shape}`` of every value a compiled program's
+    text defines."""
+    import re
+
+    return {name: tuple(int(d) for d in dims.split(",") if d)
+            for name, dims in re.findall(
+                r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text)}
+
+
 @pytest.mark.parametrize("N, E, k", [(32768, 2048, 4), (24576, 2304, 8)],
                          ids=["train-lfm2moe-8k", "train-mellum2-long"])
 def test_no_row_mover_compiled_for_a_v5e_touches_every_row(one_chip, mosaic,
@@ -564,9 +714,7 @@ def test_no_row_mover_compiled_for_a_v5e_touches_every_row(one_chip, mosaic,
 
     def whole_buffers_moved(compiled):
         text = compiled.as_text()
-        shape_of = {name: tuple(int(d) for d in dims.split(",") if d)
-                    for name, dims in re.findall(
-                        r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text)}
+        shape_of = _hlo_shapes(text)
         moved = []
         for line in re.findall(r"^.* (?:gather|scatter)\(.*$", text, re.M):
             for name in re.findall(r"%[\w.\-]+", line.split(", metadata")[0]):
@@ -581,6 +729,68 @@ def test_no_row_mover_compiled_for_a_v5e_touches_every_row(one_chip, mosaic,
     reserved = lambda pair: sum(
         c.memory_analysis().temp_size_in_bytes for c in pair)
     assert reserved(loops) < reserved(gathers)
+
+
+@pytest.mark.parametrize("N, E, Fe, k",
+                         [(32768, 2048, 1536, 4), (24576, 2304, 896, 8)],
+                         ids=["train-lfm2moe-8k", "train-mellum2-long"])
+def test_nothing_between_the_products_compiled_for_a_v5e_touches_every_row(
+        one_chip, mosaic, N, E, Fe, k):
+    """The expert stage (rows to two products, the gate, the third
+    product) with every gradient at a cell's shape, 8 held experts: the
+    compiled program holds ``moe_gate`` forward and backward and
+    ``moe_add``, and outside the kernels no operation has an operand or
+    a result of ``M`` rows of ``Fe`` or of ``E``.  The plain
+    expressions' program has, so the check sees."""
+    import re
+
+    bm = 512
+    tiles = moe.tiles_for(N * k, 8, bm)
+    M = tiles * bm
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def compiled(fan, gate):
+        def f(xs, w_gate, w_in, w_out, d_ys, tile_group, n_tiles):
+            def stage(xs, w_gate, w_in, w_out):
+                product = lambda x, w: grouped_matmul(
+                    x, w, tile_group, n_tiles, block_m=bm)
+                for_gate, for_up = fan(xs, n_tiles)
+                act = gate(product(for_gate, w_gate), product(for_up, w_in),
+                           n_tiles)
+                return product(act, w_out)
+
+            ys, vjp = jax.vjp(stage, xs, w_gate, w_in, w_out)
+            return ys, vjp(d_ys)
+
+        return jax.jit(f).lower(
+            sds((M, E), jnp.bfloat16), sds((8, E, Fe), jnp.float32),
+            sds((8, E, Fe), jnp.float32), sds((8, Fe, E), jnp.float32),
+            sds((M, E), jnp.bfloat16), sds((tiles,), jnp.int32),
+            sds((1,), jnp.int32)).compile().as_text()
+
+    def whole_buffers_touched(text):
+        shape_of = _hlo_shapes(text)
+        touched = []
+        for name, op, rest in re.findall(
+                r"^\s*(?:ROOT )?(%[\w.\-]+) = \S+ ([\w\-]+)\((.*)$", text,
+                re.M):
+            if op in ("parameter", "custom-call", "tuple",
+                      "get-tuple-element", "bitcast"):
+                continue
+            names = [name] + re.findall(r"%[\w.\-]+",
+                                        rest.split(", metadata")[0])
+            if any(shape_of.get(n) in ((M, Fe), (M, E)) for n in names):
+                touched.append((name, op))
+        return touched
+
+    kernels = compiled(lambda x, n: twice(x, n, block_m=bm),
+                       lambda a, u, n: gated(a, u, n, block_m=bm))
+    assert kernels.count("moe_gate") >= 2 and "moe_add" in kernels
+    assert not whole_buffers_touched(kernels)
+    plain = whole_buffers_touched(compiled(
+        lambda x, n: (x, x), lambda a, u, n: _plain_gate(a, u)))
+    assert len(plain) >= 3      # the gate, its transpose, the sum
 
 
 def test_windowed_flash_kernels_compile_for_a_v5e_at_the_cells_shape(
