@@ -44,10 +44,12 @@ def yarn_ramp(cfg) -> np.ndarray:
                    / (high - low), 0.0, 1.0)
 
 
-def rope_tables(cfg, t_local: int, data_axis: str, yarn: bool = False):
+def rope_tables(cfg, t_local: int, data_axis: str, yarn: bool = False,
+                dim=None):
     """``(cos, sin)``, each [T_local, D/2] float32, of the rotary angles
-    ``position * inv_freq_i`` at this shard's GLOBAL positions.  Plain:
-    ``inv_freq_i = theta**(-2i/D)``.  With *yarn* (``cfg.yarn_factor`` s
+    ``position * inv_freq_i`` at this shard's GLOBAL positions; ``D`` is
+    *dim*, the dimensions the embedding turns (None = all of
+    ``cfg.head_dim``).  Plain: ``inv_freq_i = theta**(-2i/D)``.  With *yarn* (``cfg.yarn_factor`` s
     over ``cfg.yarn_original_positions``): ``inv_freq_i = (plain_i / s)
     ramp_i + plain_i (1 - ramp_i)`` with :func:`yarn_ramp`'s blend, and
     cos and sin both times the attention factor
@@ -55,8 +57,9 @@ def rope_tables(cfg, t_local: int, data_axis: str, yarn: bool = False):
     multiplies q and k alike and the scores by its square."""
     pos = (jax.lax.axis_index(data_axis) * t_local
            + jnp.arange(t_local)).astype(jnp.float32)
+    dim = dim or cfg.head_dim
     inv_freq = 1.0 / (jnp.float32(cfg.rope_theta) ** (
-        jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim))
+        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     if yarn:
         ramp = jnp.asarray(yarn_ramp(cfg))
         inv_freq = (inv_freq / jnp.float32(cfg.yarn_factor)) * ramp \

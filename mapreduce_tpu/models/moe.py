@@ -12,7 +12,13 @@ of the result its own experts give:
     (``moe_router_score="softmax"``: s = softmax(h W_g) over all
      moe_experts, g_e = s_e / sum_{e' in S} s_e')
     out = sum_{e in S and held here} g_e W2_e (silu(W1_e h) * W3_e h)
+    (``moe_routed_scale`` c: g_e = c s_e / (...);  ``shared_ffn``: out +=
+     Ws2 (silu(Ws1 h) * Ws3 h), a SHARED expert every token takes, whole
+     on every rank and added once, after the ranks' parts are summed)
 
+``b`` moves only by the rule of ``moe_bias_rate``
+(``transformer.TransformerTrainer``'s optimizer step: after the step,
+``b_e += rate sign(mean load - load_e)`` over all ``moe_experts``).
 ``g`` is normalised over all of ``S``, held or not; what the absent
 experts would add is left out, and a ``psum`` over the ``model`` axis adds
 the ranks' parts (one held expert a rank is classic expert parallelism;
@@ -52,13 +58,13 @@ STAT_DROPPED, STAT_ROUTED = -2, -1
 
 
 def route(flat: jax.Array, w_router: jax.Array, bias, top_k: int,
-          score: str = "sigmoid"):
+          score: str = "sigmoid", scale: float = 1.0):
     """``(chosen [N, k] int32, weights [N, k] float32)`` of tokens ``flat
     [N, E]``: the module's ``S`` and ``g``, the scores ``s`` by *score*:
     ``"sigmoid"`` of each expert's logit, the weights' denominator with
     its 1e-6, or ``"softmax"`` over all the experts' logits, the weights
-    the chosen probabilities over their plain sum.  Product and score in
-    float32.  The choice carries no gradient; the weights carry the
+    the chosen probabilities over their plain sum; times *scale* where
+    it is not 1.  Product and score in float32.  The choice carries no gradient; the weights carry the
     router's."""
     logits = jnp.einsum(
         "ne,ex->nx", flat.astype(jnp.float32), w_router,
@@ -73,6 +79,8 @@ def route(flat: jax.Array, w_router: jax.Array, bias, top_k: int,
     _, chosen = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(s, chosen, axis=1)
     weights = picked / (picked.sum(axis=-1, keepdims=True) + eps)
+    if scale != 1.0:
+        weights = weights * jnp.float32(scale)
     return chosen, weights
 
 
@@ -250,10 +258,26 @@ def _combine_bwd(block_m, res, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def shared_expert(h: jax.Array, lp, cfg) -> jax.Array:
+    """``Ws2 (silu(Ws1 h) * Ws3 h)`` in float32 for ``h [B, T_local,
+    E]``: the gated FFN every token takes (``shared_w_gate``,
+    ``shared_w_in``, ``shared_w_out``).  Its tensors are whole on every
+    rank and ``h`` is the same on every rank of the ``model`` axis, so
+    every rank computes the same and no collective follows."""
+    dt = cfg.dtype
+    gate = jnp.einsum("bte,ef->btf", h, lp["shared_w_gate"].astype(dt))
+    up = jnp.einsum("bte,ef->btf", h, lp["shared_w_in"].astype(dt))
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)).astype(dt)
+    return jnp.einsum("btf,fe->bte", act, lp["shared_w_out"].astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
 def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
                    model_axis: str):
     """``(out, loads, chosen, weights)`` for ``h [B, T_local, E]``: the
-    module's ``out`` (float32, summed over the ``model`` axis); the
+    module's ``out`` (float32, summed over the ``model`` axis, then the
+    shared expert's added once where the model has one); the
     layer's statistics ``[held + 2]`` int32, whole over both mesh axes:
     the pairs each held expert took, the pairs held but not placed (0:
     nothing is dropped), and the pairs routed, here or elsewhere; and the
@@ -274,7 +298,8 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
     me = jax.lax.axis_index(model_axis)
     with jax.named_scope("tf.moe_route"):
         chosen, weights = route(flat, lp["w_router"], lp.get("router_bias"),
-                                k, cfg.moe_router_score)
+                                k, cfg.moe_router_score,
+                                cfg.moe_routed_scale)
         routed = (chosen.reshape(B, T, k), weights.reshape(B, T, k))
         local = chosen - (cfg.moe_held_offset + me * n_loc)
         dest = jnp.where((local >= 0) & (local < n_loc), local,
@@ -318,4 +343,8 @@ def routed_experts(h: jax.Array, lp, cfg, n_model: int, data_axis: str,
         ).astype(jnp.int32), model_axis)
         if data_axis in jax.typeof(stats).vma:   # the shards' tokens differ
             stats = jax.lax.psum(stats, data_axis)
-    return (out.reshape(B, T, E), stats) + routed
+    out = out.reshape(B, T, E)
+    if cfg.shared_ffn:
+        with jax.named_scope("tf.shared_expert"):
+            out = out + shared_expert(h, lp, cfg)
+    return (out, stats) + routed

@@ -1,6 +1,7 @@
 """Token-mixing operators beside the block's fused multi-head attention:
-a gated short convolution, and the projections of grouped-query
-attention with a norm on every head of q and k.
+a gated short convolution, the projections of grouped-query attention
+with a norm on every head of q and k, and those of latent attention
+(MLA).
 
 A model may give each layer its own operator
 (``TransformerConfig.layer_ops``); ``transformer._layer_local`` calls
@@ -93,3 +94,53 @@ def grouped_qkv(h: jax.Array, lp, cfg, n_model: int, rope):
             q, k = apply_rope(q, rope, seq), apply_rope(k, rope, seq)
     k, v = (jnp.repeat(a, H // Hkv, axis=heads) for a in (k, v))
     return q, k, v
+
+
+def latent_qkv(h: jax.Array, lp, cfg, n_model: int, rope):
+    """q, k and v of latent attention (MLA), ``H`` heads:
+
+        c_q = rms(h W_qa);  [q_nope | q_rope] = c_q W_qb     (a head:
+                                          qk_nope_dim + qk_rope_dim)
+        [c_kv | k_r] = h W_kva;  c_kv = rms(c_kv)
+        [k_nope | v] = c_kv W_kvb     (a head: qk_nope_dim + v_head_dim)
+        q = [q_nope | rope(q_rope)],  k = [k_nope | rope(k_r)]
+
+    ``k_r`` (``qk_rope_dim`` wide) is ONE key for all the heads: rotated
+    once, then broadcast to them.  *rope* is ``looplm.rope_tables`` over
+    ``qk_rope_dim`` dimensions; the rotary embedding (rotate-half inside
+    those) leaves the ``qk_nope_dim`` others as they are.  ``wq_b [Rq, H
+    (nope + rope)]`` and ``wkv_b [Rkv, H (nope + v)]`` hold a head's
+    columns together and split by heads over the ``model`` axis; the
+    down-projections and the latent norms are whole on every rank.  In
+    the kernel's ``[B, H, T, D]`` layout with ``cfg.flash``, else the
+    ring's ``[B, T, H, D]``; the attention that follows takes q, k of
+    ``qk_nope_dim + qk_rope_dim`` and v of ``v_head_dim``, which
+    ``cfg.validate`` holds equal."""
+    H = cfg.n_heads // n_model
+    Rkv, Dn, Dr, Dv = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                       cfg.v_head_dim)
+    dt, eps = cfg.dtype, cfg.norm_eps
+    with jax.named_scope("tf.mla_down"):
+        c_q = rmsnorm(jnp.einsum("bte,er->btr", h, lp["wq_a"].astype(dt)),
+                      lp["q_a_norm_scale"].astype(dt), eps)
+        kv_a = jnp.einsum("bte,er->btr", h, lp["wkv_a"].astype(dt))
+        c_kv = rmsnorm(kv_a[..., :Rkv], lp["kv_a_norm_scale"].astype(dt),
+                       eps)
+        k_r = kv_a[..., Rkv:]                                # [B, T, Dr]
+    out = "bhtd" if cfg.flash else "bthd"
+    heads, seq = (1, 2) if cfg.flash else (2, 1)
+    with jax.named_scope("tf.mla_up"):
+        q = jnp.einsum(f"btr,rhd->{out}", c_q,
+                       lp["wq_b"].astype(dt).reshape(-1, H, Dn + Dr))
+        kv = jnp.einsum(f"btr,rhd->{out}", c_kv,
+                        lp["wkv_b"].astype(dt).reshape(-1, H, Dn + Dv))
+        k_r = jnp.expand_dims(k_r, heads)            # one head: all share
+    with jax.named_scope("tf.rope"):
+        q_r = apply_rope(q[..., Dn:], rope, seq)
+        k_r = apply_rope(k_r, rope, seq)
+    with jax.named_scope("tf.mla_up"):
+        shape = list(q_r.shape)
+        q = jnp.concatenate([q[..., :Dn], q_r], axis=-1)
+        k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(k_r, shape)],
+                            axis=-1)
+    return q, k, kv[..., Dn:]

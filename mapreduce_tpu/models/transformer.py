@@ -43,7 +43,8 @@ from ..parallel.ring import ring_attention
 from .looplm import apply_rope, looped_loss, rope_tables
 from .moe import (STAT_DROPPED, STAT_ROUTED, block_rows, routed_experts,
                   tiles_for)
-from .operators import grouped_qkv, rmsnorm as _rmsnorm, short_conv
+from .operators import (grouped_qkv, latent_qkv, rmsnorm as _rmsnorm,
+                        short_conv)
 
 Params = Dict[str, jax.Array]
 
@@ -108,6 +109,16 @@ _MOE_TILES = _metrics.gauge(
     "held expert's pairs in whole tiles, at least one an expert, summed "
     "(the count mrtpu_moe_rows_in_use_share is the share of) (labels: "
     "layer)")
+_MOE_BIAS = _metrics.gauge(
+    "mrtpu_moe_router_bias_abs_max",
+    "the largest |selection bias| over an expert layer's experts after "
+    "the last step observed, where the bias follows the loads "
+    "(moe_bias_rate) (labels: layer)")
+_MTP_LOSS = _metrics.gauge(
+    "mrtpu_train_mtp_loss",
+    "the multi-token-prediction block's own mean loss (the token after "
+    "next) at the last step observed; the objective adds mtp_weight "
+    "times it to the main loss")
 #: 10 ms to 10 s: a training step, and the host's wait for one
 STEP_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 _STEP_SECONDS = _metrics.histogram(
@@ -304,6 +315,43 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     #: the head is the embedding table transposed; no ``unembed``
     tied_embeddings: bool = False
+    #: LATENT ATTENTION (MLA; models/operators.latent_qkv) in every
+    #: attention layer, on with ``kv_lora_rank`` > 0: queries from a
+    #: latent of ``q_lora_rank`` (RMSNorm, then ``n_heads`` heads of
+    #: ``qk_nope_dim + qk_rope_dim``), keys and values from a latent of
+    #: ``kv_lora_rank`` (RMSNorm, then heads of ``qk_nope_dim`` for the
+    #: key and ``v_head_dim`` for the value) beside ONE rotary key of
+    #: ``qk_rope_dim`` that every head shares; the rotary embedding
+    #: turns the ``qk_rope_dim`` dimensions alone.  ``head_dim`` is the
+    #: query/key width ``qk_nope_dim + qk_rope_dim``, and the value's
+    #: must equal it (``validate``)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    #: a SHARED expert beside the routed ones: a gated FFN of this width
+    #: every token takes, whole on every rank, its output added once to
+    #: the routed experts' (0 = none)
+    shared_ffn: int = 0
+    #: the factor on the routed experts' renormalised weights
+    moe_routed_scale: float = 1.0
+    #: after every optimizer step the selection bias of each expert
+    #: layer follows the step's own loads over ALL ``moe_experts``:
+    #: ``b_e += moe_bias_rate * sign(mean load - load_e)`` (0 = the bias
+    #: is held as it stands)
+    moe_bias_rate: float = 0.0
+    #: MULTI-TOKEN PREDICTION: ``mtp_blocks`` (0 or 1) blocks after the
+    #: last layer.  The block joins the last layer's output (before the
+    #: final norm) with the embedding of the NEXT token, ``[rms(emb) ;
+    #: rms(x)] W_eh``, runs one more layer of the last layer's kind and
+    #: a final norm of its own, and predicts the token after next
+    #: through the model's own head; the objective is the main loss
+    #: plus ``mtp_weight`` times the block's.  Its tensors are layer
+    #: ``n_layers``'s (``L<n_layers>.``), as published checkpoints
+    #: number them
+    mtp_blocks: int = 0
+    mtp_weight: float = 0.3
 
     def __post_init__(self):
         for name in ("layer_ops", "layer_ffns"):        # lists from JSON
@@ -318,13 +366,17 @@ class TransformerConfig:
         return self.moe_held or self.moe_experts
 
     def layer_kind(self, i: int) -> Tuple[str, str]:
-        """``(operator, ffn)`` of layer *i*."""
+        """``(operator, ffn)`` of layer *i*; a prediction block's layer
+        (``i >= n_layers``) is of the last layer's kind."""
+        i = min(i, self.n_layers - 1)
         return (self.layer_ops[i] if self.layer_ops else "attn",
                 self.layer_ffns[i] if self.layer_ffns else "dense")
 
     @property
     def moe_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i in range(self.n_layers)
+        """The layers with routed experts, a prediction block's among
+        them, in the order their statistics are stacked."""
+        return tuple(i for i in range(self.n_layers + self.mtp_blocks)
                      if self.layer_kind(i)[1] == "moe")
 
     def validate(self, n_model: int) -> None:
@@ -358,6 +410,26 @@ class TransformerConfig:
             assert self.experts_held % n_model == 0, (
                 f"the {self.experts_held} held experts do not divide over "
                 f"{n_model} model ranks")
+        assert self.moe_layers or not self.shared_ffn, \
+            "a shared expert stands beside routed ones: no layer has any"
+        assert self.moe_router_bias or not self.moe_bias_rate, \
+            "moe_bias_rate moves the selection bias: moe_router_bias"
+        if self.kv_lora_rank:
+            assert self.q_lora_rank > 0 and self.qk_rope_dim % 2 == 0 \
+                and self.rope_theta is not None and not self.yarn_factor, (
+                    "latent attention: a query latent beside the key/value "
+                    "one, plain rotary tables over an even qk_rope_dim")
+            assert self.head_dim == self.qk_nope_dim + self.qk_rope_dim, \
+                "head_dim is the query/key width, qk_nope_dim + qk_rope_dim"
+            assert self.v_head_dim == self.head_dim, (
+                f"the value's width {self.v_head_dim} is not the "
+                f"query/key's {self.head_dim}: the flash kernels take one "
+                "width (ROADMAP B10 (d))")
+            assert not (self.n_kv_heads or self.qk_norm), \
+                "latent attention has its own projections and norms"
+        assert self.mtp_blocks in (0, 1), \
+            "one multi-token-prediction block at most"
+        assert not self.mtp_blocks or self.loop_steps == 1
 
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
@@ -365,7 +437,9 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
     A layer holds the tensors of its own operator and FFN
     (``cfg.layer_kind``); a layer's further tensors take keys folded
     from the layer's six, so that a block of one kind is initialised as
-    it always was."""
+    it always was.  A prediction block is layer ``n_layers`` with its
+    joining tensors beside its layer's; its keys are folded from *key*,
+    so that the layers before it are what they are without it."""
     E, H, D, F, V = (cfg.embed, cfg.n_heads, cfg.head_dim, cfg.ffn,
                      cfg.vocab)
     params: Params = {}
@@ -373,65 +447,103 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> Params:
     def norm(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)
 
+    def ones(*shape):
+        return jnp.ones(shape, jnp.float32)
+
+    def layer(i, lk):
+        """Layer *i*'s tensors from its six keys *lk*."""
+        op, ffn = cfg.layer_kind(i)
+        params[f"L{i}.ln1_scale"] = ones(E)
+        params[f"L{i}.ln2_scale"] = ones(E)
+        if op == "conv":
+            params[f"L{i}.conv_in"] = norm(lk[0], (E, 3, E), E)
+            params[f"L{i}.conv_w"] = norm(jax.random.fold_in(lk[0], 1),
+                                          (E, cfg.conv_taps), cfg.conv_taps)
+            params[f"L{i}.conv_out"] = norm(lk[1], (E, E), E)
+        else:
+            if cfg.kv_lora_rank:
+                Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+                fold = lambda j: jax.random.fold_in(lk[0], j)
+                params[f"L{i}.wq_a"] = norm(lk[0], (E, Rq), E)
+                params[f"L{i}.wq_b"] = norm(fold(1), (Rq, H * D), Rq)
+                params[f"L{i}.wkv_a"] = norm(
+                    fold(2), (E, Rkv + cfg.qk_rope_dim), E)
+                params[f"L{i}.wkv_b"] = norm(
+                    fold(3), (Rkv, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                    Rkv)
+                params[f"L{i}.q_a_norm_scale"] = ones(Rq)
+                params[f"L{i}.kv_a_norm_scale"] = ones(Rkv)
+            elif cfg.n_kv_heads:
+                params[f"L{i}.wq"] = norm(lk[0], (E, H * D), E)
+                params[f"L{i}.wkv"] = norm(jax.random.fold_in(lk[0], 1),
+                                           (E, 2, cfg.kv_heads * D), E)
+            else:
+                params[f"L{i}.wqkv"] = norm(lk[0], (E, 3, H * D), E)
+            params[f"L{i}.wo"] = norm(lk[1], (H * D, E), H * D)
+            if cfg.qk_norm:
+                params[f"L{i}.q_norm_scale"] = ones(D)
+                params[f"L{i}.k_norm_scale"] = ones(D)
+        if ffn == "moe":
+            X, Fe = cfg.experts_held, cfg.moe_ffn
+            params[f"L{i}.w_router"] = norm(lk[4], (E, cfg.moe_experts), E)
+            if cfg.moe_router_bias:
+                # a buffer no gradient moves (BUFFERS): spread wide
+                # enough that choosing by score + bias and weighting by
+                # the score are told apart
+                params[f"L{i}.router_bias"] = 0.1 * jax.random.normal(
+                    jax.random.fold_in(lk[4], 1),
+                    (cfg.moe_experts,), jnp.float32)
+            params[f"L{i}.moe_w_in"] = norm(lk[2], (X, E, Fe), E)
+            params[f"L{i}.moe_w_out"] = norm(lk[3], (X, Fe, E), Fe)
+            params[f"L{i}.moe_w_gate"] = norm(lk[5], (X, E, Fe), E)
+            if cfg.shared_ffn:
+                Fs = cfg.shared_ffn
+                params[f"L{i}.shared_w_in"] = norm(
+                    jax.random.fold_in(lk[2], 1), (E, Fs), E)
+                params[f"L{i}.shared_w_out"] = norm(
+                    jax.random.fold_in(lk[3], 1), (Fs, E), Fs)
+                params[f"L{i}.shared_w_gate"] = norm(
+                    jax.random.fold_in(lk[5], 1), (E, Fs), E)
+        else:
+            params[f"L{i}.w_in"] = norm(lk[2], (E, F), E)
+            params[f"L{i}.w_out"] = norm(lk[3], (F, E), F)
+            if cfg.ffn_gated:
+                params[f"L{i}.w_gate"] = norm(lk[5], (E, F), E)
+        if cfg.sandwich_norm:
+            params[f"L{i}.ln1_out_scale"] = ones(E)
+            params[f"L{i}.ln2_out_scale"] = ones(E)
+
     keys = jax.random.split(key, 2 + 6 * cfg.n_layers)
     params["embed"] = norm(keys[0], (V, E), 1.0) * 0.02
     if not cfg.tied_embeddings:
         params["unembed"] = norm(keys[1], (E, V), E)
     for i in range(cfg.n_layers):
-        k0 = 2 + 6 * i
-        op, ffn = cfg.layer_kind(i)
-        params[f"L{i}.ln1_scale"] = jnp.ones((E,), jnp.float32)
-        params[f"L{i}.ln2_scale"] = jnp.ones((E,), jnp.float32)
-        if op == "conv":
-            params[f"L{i}.conv_in"] = norm(keys[k0], (E, 3, E), E)
-            params[f"L{i}.conv_w"] = norm(jax.random.fold_in(keys[k0], 1),
-                                          (E, cfg.conv_taps), cfg.conv_taps)
-            params[f"L{i}.conv_out"] = norm(keys[k0 + 1], (E, E), E)
-        else:
-            if cfg.n_kv_heads:
-                params[f"L{i}.wq"] = norm(keys[k0], (E, H * D), E)
-                params[f"L{i}.wkv"] = norm(jax.random.fold_in(keys[k0], 1),
-                                           (E, 2, cfg.kv_heads * D), E)
-            else:
-                params[f"L{i}.wqkv"] = norm(keys[k0], (E, 3, H * D), E)
-            params[f"L{i}.wo"] = norm(keys[k0 + 1], (H * D, E), H * D)
-            if cfg.qk_norm:
-                params[f"L{i}.q_norm_scale"] = jnp.ones((D,), jnp.float32)
-                params[f"L{i}.k_norm_scale"] = jnp.ones((D,), jnp.float32)
-        if ffn == "moe":
-            X, Fe = cfg.experts_held, cfg.moe_ffn
-            params[f"L{i}.w_router"] = norm(keys[k0 + 4],
-                                            (E, cfg.moe_experts), E)
-            if cfg.moe_router_bias:
-                # a buffer the steps hold fixed (BUFFERS): spread wide
-                # enough that choosing by score + bias and weighting by
-                # the score are told apart
-                params[f"L{i}.router_bias"] = 0.1 * jax.random.normal(
-                    jax.random.fold_in(keys[k0 + 4], 1),
-                    (cfg.moe_experts,), jnp.float32)
-            params[f"L{i}.moe_w_in"] = norm(keys[k0 + 2], (X, E, Fe), E)
-            params[f"L{i}.moe_w_out"] = norm(keys[k0 + 3], (X, Fe, E), Fe)
-            params[f"L{i}.moe_w_gate"] = norm(keys[k0 + 5], (X, E, Fe), E)
-        else:
-            params[f"L{i}.w_in"] = norm(keys[k0 + 2], (E, F), E)
-            params[f"L{i}.w_out"] = norm(keys[k0 + 3], (F, E), F)
-            if cfg.ffn_gated:
-                params[f"L{i}.w_gate"] = norm(keys[k0 + 5], (E, F), E)
-        if cfg.sandwich_norm:
-            params[f"L{i}.ln1_out_scale"] = jnp.ones((E,), jnp.float32)
-            params[f"L{i}.ln2_out_scale"] = jnp.ones((E,), jnp.float32)
+        layer(i, keys[2 + 6 * i:8 + 6 * i])
     if cfg.final_norm:
-        params["final_scale"] = jnp.ones((E,), jnp.float32)
+        params["final_scale"] = ones(E)
     if cfg.loop_steps > 1:
         # one exit gate for every pass; a key of its own, so that the
         # tensors above are what they are without it
         params["exit_w"] = norm(jax.random.fold_in(key, 1), (E,), E)
         params["exit_b"] = jnp.zeros((1,), jnp.float32)
+    if cfg.mtp_blocks:
+        i = cfg.n_layers
+        mk = jax.random.split(jax.random.fold_in(key, 2), 7)
+        layer(i, mk[:6])
+        params[f"L{i}.enorm_scale"] = ones(E)
+        params[f"L{i}.hnorm_scale"] = ones(E)
+        params[f"L{i}.w_eh"] = norm(mk[6], (2 * E, E), 2 * E)
+        params[f"L{i}.final_scale"] = ones(E)
     return params
 
 
+#: a prediction block's tensors beside its layer's: the norms of the two
+#: halves it joins, the joining projection ``[2E, E]``, its final norm
+MTP_JOIN = ("enorm_scale", "hnorm_scale", "w_eh", "final_scale")
+
 #: name endings of tensors that are part of the model and not trained:
-#: every step leaves them as they are
+#: no gradient and no weight decay moves them (the selection bias moves
+#: by its own rule where ``moe_bias_rate`` is set, and not otherwise)
 BUFFERS = (".router_bias",)
 
 
@@ -442,10 +554,13 @@ def transformer_param_spec(name: str) -> P:
     the two is elementwise over the local columns.  The short
     convolution's channels split like heads (conv_in by columns, its
     taps and conv_out by rows); the held experts split over the axis
-    whole, a rank's experts its own."""
+    whole, a rank's experts its own.  Latent attention's up-projections
+    ``wq_b`` and ``wkv_b`` split by heads (columns); its down-projections
+    and latent norms, a shared expert and a prediction block's joining
+    tensors are whole on every rank."""
     if name.endswith((".wqkv", ".wkv", ".conv_in")):
         return P(None, None, "model")
-    if name.endswith((".w_in", ".w_gate", ".wq")):
+    if name.endswith((".w_in", ".w_gate", ".wq", ".wq_b", ".wkv_b")):
         return P(None, "model")
     if name.endswith((".wo", ".w_out", ".conv_w", ".conv_out")):
         return P("model", None)
@@ -513,9 +628,12 @@ def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                      n_model: int, data_axis: str, model_axis: str,
                      rope, window=None):
     """The block's attention sublayer, residual included: causal
-    multi-head attention from one fused ``wqkv``, or grouped-query
+    multi-head attention from one fused ``wqkv``, grouped-query
     attention from ``wq`` and ``wkv`` (``cfg.n_kv_heads``,
-    models/operators.grouped_qkv); over all earlier positions, or with
+    models/operators.grouped_qkv), or latent attention from its two
+    latents (``cfg.kv_lora_rank``, models/operators.latent_qkv, whose
+    scopes are ``tf.mla_down`` and ``tf.mla_up``); over all earlier
+    positions, or with
     *window* over the last *window* of them, the query's own included
     (the flash kernels' windowed programs under ``tf.flash``; the jnp
     ring masks the same way)."""
@@ -527,7 +645,9 @@ def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
     # path (obs/compile.CompileLedger.stage_map)
     with jax.named_scope("tf.attn_proj"):
         h = _rmsnorm(x, lp["ln1_scale"].astype(cfg.dtype), cfg.norm_eps)
-        if cfg.n_kv_heads:
+        if cfg.kv_lora_rank:
+            q, k, v = latent_qkv(h, lp, cfg, n_model, rope)
+        elif cfg.n_kv_heads:
             q, k, v = grouped_qkv(h, lp, cfg, n_model, rope)
         elif cfg.flash:
             # Pallas fast path: project straight into the kernel's
@@ -542,7 +662,7 @@ def _attention_local(x: jax.Array, lp: Params, cfg: TransformerConfig,
                              lp["wqkv"].astype(cfg.dtype))
             q, k, v = [qkv[:, :, j].reshape(*qkv.shape[:2], H_loc, D)
                        for j in range(3)]
-    if rope is not None and not cfg.n_kv_heads:
+    if rope is not None and not (cfg.n_kv_heads or cfg.kv_lora_rank):
         with jax.named_scope("tf.rope"):
             # q and k rotate as ONE tensor, sliced from the projection's
             # output in its layout: [B, 3, H, T, D] for the kernel,
@@ -615,19 +735,22 @@ def remat_kept_bytes(cfg: TransformerConfig, n_model: int, batch: int,
     input.  The kernel's output ``[B, H_loc, T, D]`` in ``cfg.dtype`` and
     its float32 row statistics ``[B, H_loc, T]``, an application of an
     attention layer, windowed or not (grouped-query attention reaches
-    the kernel with K and V repeated to ``H_loc`` heads)."""
+    the kernel with K and V repeated to ``H_loc`` heads, latent
+    attention with its value as wide as ``head_dim``), a prediction
+    block's layer among them."""
     if not (cfg.remat and cfg.flash):
         return 0
     rows = batch * (cfg.n_heads // n_model) * t_local
     attention_layers = sum(cfg.layer_kind(i)[0] != "conv"
-                           for i in range(cfg.n_layers))
+                           for i in range(cfg.n_layers + cfg.mtp_blocks))
     return cfg.loop_steps * attention_layers * rows * (
         cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
 
 
 def forward_local(params: Params, tokens: jax.Array,
                   cfg: TransformerConfig, n_model: int,
-                  data_axis: str = "data", model_axis: str = "model"):
+                  data_axis: str = "data", model_axis: str = "model",
+                  next_tokens=None):
     """Local-block forward INSIDE shard_map: ``tokens`` [B, T_local]
     int32; returns ``(hidden [B, T_local, E] f32, stats)`` where stats
     holds the statistics of the routed expert layers, stacked over the
@@ -636,6 +759,10 @@ def forward_local(params: Params, tokens: jax.Array,
     one.  A looped
     model (``loop_steps`` R > 1) returns the hidden state after EVERY
     pass, [R, B, T_local, E] in ``cfg.dtype`` (what the next pass read).
+    With *next_tokens* ``[B, T_local]`` (each position's NEXT token) a
+    model with a prediction block (``cfg.mtp_blocks``) returns ``hidden
+    [2, B, T_local, E]``, the main model's and the block's, and the
+    block's expert layer's statistics stacked after the others.
     Params arrive already sliced by transformer_param_spec."""
     with jax.named_scope("tf.embed"):
         x = params["embed"][tokens].astype(cfg.dtype)  # [B, T, E]
@@ -644,7 +771,8 @@ def forward_local(params: Params, tokens: jax.Array,
     rope = scaled = None
     if cfg.rope_theta is not None:
         with jax.named_scope("tf.rope"):
-            rope = scaled = rope_tables(cfg, tokens.shape[1], data_axis)
+            rope = scaled = rope_tables(cfg, tokens.shape[1], data_axis,
+                                        dim=cfg.qk_rope_dim or None)
             if cfg.yarn_factor:
                 scaled = rope_tables(cfg, tokens.shape[1], data_axis,
                                      yarn=True)
@@ -671,20 +799,43 @@ def forward_local(params: Params, tokens: jax.Array,
                 *(KEPT_NAMES if cfg.flash else ())))
         final_norm = jax.checkpoint(final_norm)
 
+    def layer_params(i):
+        prefix = f"L{i}."
+        return {k[len(prefix):]: v for k, v in params.items()
+                if k.startswith(prefix)}
+
     def stack(x):
-        """The n_layers once, then the final norm."""
+        """The n_layers once, then the final norm (and, with
+        *next_tokens*, the prediction block beside it)."""
         stats = []
-        for i in range(cfg.n_layers):
-            prefix = f"L{i}."
-            lp = {k[len(prefix):]: v for k, v in params.items()
-                  if k.startswith(prefix)}
-            kind = cfg.layer_kind(i)
+
+        def apply(x, lp, kind):
             x, layer_stats = layer(x, lp, scaled if kind[0] == "attn"
                                    else rope, kind)
             if layer_stats is not None:
                 stats.append(layer_stats)
+            return x
+
+        for i in range(cfg.n_layers):
+            x = apply(x, layer_params(i), cfg.layer_kind(i))
+        last = x
         if cfg.final_norm:
             x = final_norm(x, params["final_scale"])
+        if cfg.mtp_blocks and next_tokens is not None:
+            i = cfg.n_layers
+            lp, kind = layer_params(i), cfg.layer_kind(i)
+            join = {n: lp.pop(n) for n in MTP_JOIN}
+            with jax.named_scope("tf.mtp"):
+                both = jnp.concatenate([
+                    _rmsnorm(params["embed"][next_tokens].astype(cfg.dtype),
+                             join["enorm_scale"].astype(cfg.dtype),
+                             cfg.norm_eps),
+                    _rmsnorm(last, join["hnorm_scale"].astype(cfg.dtype),
+                             cfg.norm_eps)], axis=-1)
+                u = jnp.einsum("btf,fe->bte", both,
+                               join["w_eh"].astype(cfg.dtype))
+            u = apply(u, lp, kind)
+            x = jnp.stack([x, final_norm(u, join["final_scale"])])
         return x, (jax.tree.map(lambda *rows: jnp.stack(rows), *stats)
                    if stats else None)
 
@@ -814,9 +965,18 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
     stats)`` then, ``stats`` [2, R] float32: the mean loss of each pass
     and the mean exit mass of each pass.  A model with routed expert
     layers returns ``(loss, stats)`` too, ``stats`` being
-    :func:`forward_local`'s."""
+    :func:`forward_local`'s.
+
+    A model with a prediction block (``cfg.mtp_blocks``) has a second
+    loss: the block reads the last layer's output beside the embedding
+    of each position's next token (*targets*) and predicts the token
+    after next through the same head.  The objective is ``L_main +
+    mtp_weight L_mtp``, both means, ``L_mtp`` over the positions that
+    have a token after next; ``stats["losses"]`` is ``[L_main, L_mtp]``
+    float32."""
+    mtp = bool(cfg.mtp_blocks)
     x, stats = forward_local(params, tokens, cfg, n_model, data_axis,
-                             model_axis)
+                             model_axis, next_tokens=targets if mtp else None)
     if cfg.tied_embeddings:
         # this rank's rows of the table, transposed: [E, V_loc]
         V_loc = cfg.vocab // n_model
@@ -837,6 +997,12 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
     if cfg.loop_steps > 1:
         return looped_loss(x, targets, params, nll_of, cfg, data_axis)
 
+    if mtp:
+        # the block's targets: the token after next, and which positions
+        # have one (all but the sequence's last)
+        x = x.reshape(-1, *x.shape[2:])                  # [2 B, T, E]
+        after, has_after = _token_after_next(targets, data_axis)
+        targets = jnp.concatenate([targets, after])
     # everything from the unembedding on is the loss stage: chunk_nll
     # is traced where it is called, inside the scope, and its backward
     # rule under the call's
@@ -859,9 +1025,63 @@ def loss_local(params: Params, tokens: jax.Array, targets: jax.Array,
             _, nll_chunks = jax.lax.scan(
                 lambda _, xt: (None, nll_of(*xt)), None, (xs, ts))
             nll = jnp.moveaxis(nll_chunks, 0, 1).reshape(B, T)
-        total = nll.mean()
+        if mtp:
+            # one scan over both hidden states, one head: its gradient
+            # is the sum.  The block's mean is over the positions that
+            # have a token after next, T - 1 of every sequence
+            main, block = jnp.split(nll, 2)
+            n_after = jax.lax.psum(has_after.sum(), data_axis)
+            n_data = jax.lax.psum(1, data_axis)
+            losses = jnp.stack([
+                main.mean(),
+                (block * has_after).sum() * n_data
+                / (block.shape[0] * n_after)])
+            total = losses[0] + jnp.float32(cfg.mtp_weight) * losses[1]
+        else:
+            total = nll.mean()
     loss = jax.lax.pmean(total, data_axis)
+    if mtp:
+        stats = dict(stats or {}, losses=jax.lax.pmean(losses, data_axis))
     return loss if stats is None else (loss, stats)
+
+
+def _token_after_next(targets: jax.Array, data_axis: str):
+    """``(after [B, T_local], has_after [T_local] float32)``: with
+    *targets* each position's NEXT token, the token after that (the next
+    position's target; a shard's last position reads the first of the
+    shard after it) and 1.0 where there is one: everywhere but the
+    sequence's last position, whose entry is 0 and counts nothing."""
+    T = targets.shape[1]
+    n_data = jax.lax.psum(1, data_axis)
+    halo = jnp.zeros_like(targets[:, :1])
+    if n_data > 1:
+        halo = jax.lax.ppermute(targets[:, :1], data_axis,
+                                [(i + 1, i) for i in range(n_data - 1)])
+    after = jnp.concatenate([targets[:, 1:], halo], axis=1)
+    position = jax.lax.axis_index(data_axis) * T + jnp.arange(T)
+    return after, (position < n_data * T - 1).astype(jnp.float32)
+
+
+def _follow_loads(params: Params, stats: dict, cfg: TransformerConfig):
+    """The selection bias's rule, after an optimizer step: ``(params,
+    stats)`` with each expert layer's ``router_bias`` moved against the
+    step's own loads, ``b_e += moe_bias_rate * sign(mean load - load_e)``
+    over ALL ``moe_experts`` (the choices of every token, whichever rank
+    holds the expert), from ``stats["chosen"] [layers, B, T, k]``, and
+    *stats* with ``bias_abs_max [layers]``, each layer's largest ``|b_e|``
+    after the move."""
+    experts = jnp.arange(cfg.moe_experts)
+    params, largest = dict(params), []
+    with jax.named_scope("tf.bias_update"):
+        for chosen, layer in zip(stats["chosen"], cfg.moe_layers):
+            # a compare and a sum, no scatter: [B, T, k, X] is never whole
+            loads = (chosen[..., None] == experts).sum(axis=(0, 1, 2))
+            mean = chosen.size / cfg.moe_experts
+            name = f"L{layer}.router_bias"
+            params[name] = params[name] + jnp.float32(
+                cfg.moe_bias_rate) * jnp.sign(mean - loads)
+            largest.append(jnp.abs(params[name]).max())
+        return params, dict(stats, bias_abs_max=jnp.stack(largest))
 
 
 class TransformerTrainer:
@@ -902,7 +1122,8 @@ class TransformerTrainer:
         self._due = False            # its deferred work (_finish) is due
         self._counted = {}           # program -> the shape its gauges say
         kinds = collections.Counter(
-            cfg.layer_kind(i)[0] for i in range(cfg.n_layers))
+            cfg.layer_kind(i)[0]
+            for i in range(cfg.n_layers + cfg.mtp_blocks))
         self._operator_apps = [(kind, cfg.loop_steps * n)
                                for kind, n in kinds.items()]
         self._gc_seen = _GC[0]
@@ -930,15 +1151,21 @@ class TransformerTrainer:
         # fused steps stay the dense model's (step refuses the others):
         # no caller trains a looped or routed model through them
         with_stats = self.with_stats = (cfg.loop_steps > 1
-                                        or bool(cfg.moe_layers))
+                                        or bool(cfg.moe_layers)
+                                        or bool(cfg.mtp_blocks))
+        stats_specs = P()                       # a looped model's [2, R]
+        if cfg.moe_layers or cfg.mtp_blocks:
+            stats_specs = {}
+            if cfg.moe_layers:
+                stats_specs = {"loads": P(),
+                               "chosen": P(None, None, "data", None),
+                               "weights": P(None, None, "data", None)}
+            if cfg.mtp_blocks:                  # [L_main, L_mtp]
+                stats_specs["losses"] = P()
         loss_fn = jax.shard_map(
             sharded_loss, mesh=mesh,
             in_specs=(pspecs, tok_spec, tok_spec),
-            out_specs=(P(), {"loads": P(),
-                             "chosen": P(None, None, "data", None),
-                             "weights": P(None, None, "data", None)}
-                       if cfg.moe_layers else P())
-            if with_stats else P())
+            out_specs=(P(), stats_specs) if with_stats else P())
 
         def train_step(params, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(
@@ -992,6 +1219,9 @@ class TransformerTrainer:
                     updates = {n: jnp.zeros_like(u) if n.endswith(BUFFERS)
                                else u for n, u in updates.items()}
                     params = optax.apply_updates(params, updates)
+                if cfg.moe_bias_rate and cfg.moe_layers:
+                    params, stats = _follow_loads(params, out[1], cfg)
+                    out = out[0], stats
                 # a looped or routed model: (loss, stats)
                 return (params, opt_state,
                         *(out if with_stats else (out,)))
@@ -1117,7 +1347,8 @@ class TransformerTrainer:
         shape: the applications by operator were counted when the
         trainer was made, the two gauges are set when *program* meets
         another shape."""
-        _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers)
+        _LAYER_APPS.inc(self.cfg.loop_steps * self.cfg.n_layers
+                        + self.cfg.mtp_blocks)
         for kind, n in self._operator_apps:
             _OPERATOR_APPS.inc(n, operator=kind)
         if self._counted.get(program) != x.shape:
@@ -1151,7 +1382,8 @@ class TransformerTrainer:
         ``mem`` (bytes in use and reserved, and their peaks, of the
         fullest local device while the step was in flight; absent where
         nothing reports); for a routed model ``pairs_held`` and, a layer,
-        ``tiles_in_use`` and ``load_max_over_mean``; ``slow``, absent or
+        ``tiles_in_use`` and ``load_max_over_mean``; with a prediction
+        block ``mtp_loss``, the block's own loss; ``slow``, absent or
         :func:`slow_cause`'s.  The step in flight has no record yet."""
         self._finish()
         return [dict(r) for r in self._steps]
@@ -1257,9 +1489,18 @@ class TransformerTrainer:
         ``mrtpu_moe_rows_in_use_share{layer}`` and
         ``mrtpu_moe_tiles_in_use{layer}`` from it (the last two as on
         one data shard: over several the loads are the shards' sums and
-        the share reads low); returns it as numpy.  With no step in
+        the share reads low); of a model with a prediction block also
+        ``mrtpu_train_mtp_loss`` (and the record's ``mtp_loss``) from
+        ``stats["losses"]``, of one whose selection bias follows the
+        loads ``mrtpu_moe_router_bias_abs_max{layer}`` from
+        ``stats["bias_abs_max"]``; returns the loads as numpy.  With no step in
         flight (made-up ``stats``) it sets the gauges and records
         nothing."""
+        everything = stats
+        for name in ("losses", "bias_abs_max"):
+            # a few floats more: on their way while the loads are awaited
+            if name in everything:
+                everything[name].copy_to_host_async()
         stats, t_done = self._await(stats["loads"])
         loads = stats[:, :STAT_DROPPED]
         held = int(loads.sum())
@@ -1285,6 +1526,16 @@ class TransformerTrainer:
         if rec is not None:
             rec.update(pairs_held=held, tiles_in_use=tiles_in_use,
                        load_max_over_mean=skews)
+        # the step is done: what follows reads a few floats
+        if "losses" in everything:
+            mtp_loss = float(np.asarray(everything["losses"])[1])
+            _MTP_LOSS.set(mtp_loss)
+            if rec is not None:
+                rec["mtp_loss"] = mtp_loss
+        if "bias_abs_max" in everything:
+            for layer, b in zip(self.cfg.moe_layers,
+                                np.asarray(everything["bias_abs_max"])):
+                _MOE_BIAS.set(float(b), layer=layer)
         return stats
 
     # -- optimizer (optax) path -----------------------------------------
@@ -1356,6 +1607,17 @@ class TransformerTrainer:
             # its checkpoints were written under
             tag += (f".win{c.attn_window}.score{c.moe_router_score}.yarn"
                     + "x".join(map(str, yarn if yarn[0] else (0,))))
+        latent = (c.q_lora_rank, c.kv_lora_rank, c.qk_nope_dim,
+                  c.qk_rope_dim, c.v_head_dim)
+        beside = (c.shared_ffn, c.moe_routed_scale, c.moe_bias_rate,
+                  c.mtp_blocks)
+        if latent != (0,) * 5 or beside != (0, 1.0, 0.0, 0):
+            # the rotary part's width, the routed weights' scale, the
+            # bias's rate and the second loss's weight change no shape
+            tag += (".mla" + "x".join(map(str, latent))
+                    + f".shared{c.shared_ffn}.scale{c.moe_routed_scale}."
+                    f"follow{c.moe_bias_rate}.mtp{c.mtp_blocks}"
+                    + (f"x{c.mtp_weight}" if c.mtp_blocks else ""))
         return tag
 
     def save(self, path: str, params: Params, step: int = 0,

@@ -507,10 +507,16 @@ def _fwd_call(q, k, v, cfgt):
 #: core has 128 MiB); a longer (Tq x D) runs as several passes over Q-row
 #: ranges (see _bwd_call)
 _DQ_ACC_BYTES = 32 << 20
-#: VMEM the backward tile loop needs beside the accumulator at the
-#: default 1024 x 1024 tile: double-buffered operand tiles and the
-#: tile's float32 intermediates
-_BWD_TILE_BYTES = 40 << 20
+def _bwd_tile_bytes(D: int) -> int:
+    """VMEM the backward tile loop needs beside the accumulator at the
+    default 1024 x 1024 tile.  The tile's float32 intermediates (scores,
+    probabilities, dP, dS and their 2-byte copies) do not go by the head
+    width: 32 MiB.  What does: the double-buffered operand tiles (Q, K,
+    V, dO), the dK, dV and dQ output blocks and the two float32 dK, dV
+    scratch tiles, 8 MiB at ``D`` = 128 and in proportion above it (16
+    MiB at 256).  40 MiB at 128 and under, as every program built before
+    the reserve followed ``D`` asked for."""
+    return (32 << 20) + (8 << 20) * max(D, 128) // 128
 
 
 def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
@@ -551,7 +557,7 @@ def _bwd_call(q, k, v, out, lse, do, cfgt, dlse=None):
     row_spec = pl.BlockSpec((1, 1, block_q, 1), _q_index)
     part_spec = pl.BlockSpec((1, 1, 1, block_kv, D), _part_index)
     part_dtype = k.dtype if n_pass == 1 else jnp.float32
-    vmem = q_tiles * block_q * D * 4 + _BWD_TILE_BYTES
+    vmem = q_tiles * block_q * D * 4 + _bwd_tile_bytes(D)
     dk, dv, dqa = pallas_call(
         functools.partial(_dkv_kernel, causal=causal, block_q=block_q,
                           block_kv=block_kv, q_tiles=q_tiles, window=window),

@@ -628,6 +628,48 @@ def test_window_matches_a_masked_softmax(T, block_q, block_kv, window,
         assert np.array_equal(np.asarray(out), np.asarray(causal))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("part", ["forward", "dq", "dk", "dv"])
+def test_head_width_256_matches_a_masked_softmax(part, dtype):
+    """Latent attention's width (GLM-4.7-Flash: 192 + 64 for q and k, 256
+    for v): the forward and each of the three gradients at D = 256
+    against a masked jnp softmax, several tiles a head, scale 256^-1/2."""
+    B, H, T, D = 1, 2, 128, 256
+    q, k, v, w = (jax.random.normal(jax.random.key(i), (B, H, T, D), dtype)
+                  for i in range(4))
+    weigh = lambda out: jnp.sum(out.astype(jnp.float32)
+                                * w.astype(jnp.float32))
+    flash = lambda q, k, v: flash_attention(q, k, v, block_q=32,
+                                            block_kv=64)
+    tol = dict(atol=5e-5, rtol=1e-4) if dtype == jnp.float32 \
+        else dict(atol=5e-2, rtol=5e-2)
+    with jax.default_matmul_precision("float32"):
+        if part == "forward":
+            got, want = flash(q, k, v), _window_oracle(q, k, v, T)
+            assert got.dtype == dtype
+        else:
+            i = "qkv".index(part[1])
+            got = jax.grad(lambda *a: weigh(flash(*a)), argnums=i)(q, k, v)
+            want = jax.grad(lambda *a: weigh(_window_oracle(*a, T)),
+                            argnums=i)(q, k, v)
+            assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
+def test_backward_tile_reserve_follows_the_head_width():
+    """40 MiB at 128 and under, as every program built before the
+    reserve followed D asked for (the admitted cells' steps keep their
+    text); the operand, output and scratch tiles' 8 MiB double at 256."""
+    assert fa._bwd_tile_bytes(64) == fa._bwd_tile_bytes(128) == 40 << 20
+    assert fa._bwd_tile_bytes(256) == 48 << 20
+    assert fa._bwd_tile_bytes(512) == 64 << 20
+    # with the dQ of 8 Q tiles of 1024 x 256 resident: under a v5e
+    # core's 128 MiB
+    assert 8 * 1024 * 256 * 4 + fa._bwd_tile_bytes(256) == 56 << 20
+
+
 def test_window_with_several_backward_passes(monkeypatch):
     """An accumulator budget of two Q tiles: three passes over Q-row
     ranges, each Q tile's accumulator zeroed at its first windowed

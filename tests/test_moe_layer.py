@@ -817,6 +817,22 @@ def test_flash_kernels_compile_for_a_v5e_at_head_width_64(one_chip, mosaic):
     assert "flash_fwd" in text and "flash_dkv" in text
 
 
+def test_flash_kernels_compile_for_a_v5e_at_head_width_256(one_chip, mosaic):
+    """`train-glm47flash-mla`'s call: one sequence of 8,192 positions, 20
+    heads of 256 (latent attention's q/k width, 192 + 64, and its v's),
+    the default 1024 x 1024 tiles: forward, and the backward tile loop
+    with the float32 dQ of all 8 Q tiles (8 MiB) resident beside a tile
+    reserve that follows the head width."""
+    x = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    f = lambda q, k, v: flash_attention(q, k, v, causal=True).astype(
+        jnp.float32).sum()
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    for name in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert name in text
+
+
 def test_the_chunked_loss_compiles_to_three_products_a_chunk_backward(
         one_chip, mosaic):
     """The loss stage of ``train-lfm2moe-8k`` alone (no layer: B 4, T

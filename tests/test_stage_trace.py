@@ -38,6 +38,11 @@ LOOP_SCOPES = ["tf.rope", "tf.exit_gate", "tf.pass_loop",
 #: has (PR 33; tests/test_lfm2moe.py finds each in its stage map)
 MOE_SCOPES = ["tf.conv_op", "tf.qk_norm", "tf.moe_route", "tf.moe_dispatch",
               "tf.moe_experts", "tf.moe_combine"]
+#: scopes only a program with latent attention, a shared expert, a
+#: prediction block and a bias that follows the loads has (PR 39;
+#: tests/test_glm47flash.py finds each in its stage map)
+GLM_SCOPES = ["tf.mla_down", "tf.mla_up", "tf.shared_expert", "tf.mtp",
+              "tf.bias_update"]
 
 
 def load(*parts):
@@ -471,7 +476,7 @@ def test_metric_file_has_a_reader_and_waits_if_run_py_has_none(name):
                            else "device_trace")
     if "stage" in d["read"] and d["read"]["stage"] != stages.UNSCOPED:
         assert d["read"]["stage"] in (WAVE_SCOPES + TF_SCOPES + LOOP_SCOPES
-                                      + MOE_SCOPES)
+                                      + MOE_SCOPES + GLM_SCOPES)
     for cell in d["workloads"]:
         assert d in stage_report.metric_files(cell)
 
@@ -498,6 +503,10 @@ def test_the_waiting_metric_files_are_the_issues():
         "moe.attn_proj_share", "moe.ffn_share", "moe.rope_share",
         "moe.loss_share", "moe.update_share", "moe.unscoped_share",
         "moe.dispatch_ms", "moe.gmm_roofline"}
+    assert {n for n in waiting if n.startswith("glm.")} == {
+        "glm.mla_down_share", "glm.mla_up_share", "glm.shared_expert_share",
+        "glm.mtp_share", "glm.bias_update_share", "glm.flash_fwd_roofline",
+        "glm.flash_dkv_roofline"}
 
 
 # -- (h) the join, end to end, on the CPU's own trace ------------------------
