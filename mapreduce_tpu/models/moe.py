@@ -35,9 +35,14 @@ buffers whole, and the gate ``silu(a) * u`` between the products and the
 sum of the first two products' gradients for the rows are kernels on
 that bound too (``ops/grouped_matmul.gated``, ``twice``).  What does not
 follow the tiles in use: the plan over all ``N k`` pairs, and the
-zeroing of the loops' carries (PERF.md section 5).  The plan that puts
-pairs into expert order (rank within destination, count a destination)
-is the exchange's, ``parallel/shuffle.routing_plan``.
+zeroing of the loops' carries (PERF.md section 5).  The plan costs the
+pairs, whatever lands, but moves none of them by its index: the chosen
+scores are a compare and a sum over the experts (``route``), a held
+expert's load a compare and a sum over the pairs (the counts of the
+exchange's plan, ``parallel/shuffle.routing_plan``, whose ranks only
+``expert_order``'s ``pos`` reads), and the pairs in expert order one
+stable sort by destination, from which a tile takes a contiguous run
+(``expert_order``).
 """
 
 from __future__ import annotations
@@ -77,7 +82,11 @@ def route(flat: jax.Array, w_router: jax.Array, bias, top_k: int,
     if bias is not None:
         biased = biased + jax.lax.stop_gradient(bias)
     _, chosen = jax.lax.top_k(biased, top_k)
-    picked = jnp.take_along_axis(s, chosen, axis=1)
+    # s at the chosen columns, by a compare and a sum over the experts:
+    # one term a sum is not zero, so the value is the gathered one, and
+    # the transpose is the same compare, not a scatter of N k scalars
+    at = chosen[:, :, None] == jnp.arange(s.shape[1])[None, None, :]
+    picked = jnp.where(at, s[:, None, :], 0.0).sum(axis=-1)
     weights = picked / (picked.sum(axis=-1, keepdims=True) + eps)
     if scale != 1.0:
         weights = weights * jnp.float32(scale)
@@ -95,7 +104,15 @@ def expert_order(dest: jax.Array, plan, n_held: int, block_m: int,
     block_m``: a held pair's row (``M`` for the others), the pair a row
     holds (``P`` for a padding row), the expert of each tile and the
     tiles in use — each expert's rows start at a tile and it owns at
-    least one; *max_tiles* is :func:`tiles_for` the pairs."""
+    least one; *max_tiles* is :func:`tiles_for` the pairs.
+
+    ``pair_of_row`` inverts ``pos`` without moving a pair by its index:
+    the pairs in expert order are ONE sort of the pairs by destination
+    (stable: inside an expert the pairs keep their order), and a tile
+    of an expert is a contiguous run of ``block_m`` of them, read from
+    the row side by a loop over the tiles in use.  ``pos`` itself is
+    read by tests alone; the layer needs the plan's counts and not its
+    ranks."""
     P = dest.shape[0]
     M = max_tiles * block_m
     rank, counts = plan
@@ -104,13 +121,31 @@ def expert_order(dest: jax.Array, plan, n_held: int, block_m: int,
     row_start = (tile_end - tiles) * block_m
     held = dest < n_held
     pos = jnp.where(held, row_start[jnp.minimum(dest, n_held - 1)] + rank, M)
-    pair_of_row = jnp.full((M,), P, jnp.int32).at[pos].set(
-        jnp.arange(P, dtype=jnp.int32), mode="drop", unique_indices=True)
     tile_group = jnp.minimum(
         (jnp.arange(max_tiles)[:, None] >= tile_end[None, :]).sum(axis=1),
         n_held - 1).astype(jnp.int32)
-    return (pos.astype(jnp.int32), pair_of_row, tile_group,
-            tile_end[-1:].astype(jnp.int32))
+    # the held pairs first, expert by expert; a run may start at the
+    # last pair, so a tile of padding follows them
+    _, in_order = jax.lax.sort_key_val(
+        jnp.minimum(dest, n_held), jnp.arange(P, dtype=jnp.int32))
+    in_order = jnp.concatenate(
+        [in_order, jnp.full((block_m,), P, jnp.int32)])
+    # tile t of expert g holds the pairs from its first row's place
+    # among g's on, as many as g has left there; a row of no tile in use
+    # holds none
+    first = jnp.arange(max_tiles) * block_m - row_start[tile_group]
+    start = (jnp.cumsum(counts) - counts)[tile_group] + first
+    left = counts[tile_group] - first
+    n_tiles = tile_end[-1:].astype(jnp.int32)
+
+    def tile(t, rows):
+        run = jax.lax.dynamic_slice_in_dim(in_order, start[t], block_m)
+        run = jnp.where(jnp.arange(block_m) < left[t], run, P)
+        return jax.lax.dynamic_update_slice_in_dim(rows, run, t * block_m, 0)
+
+    pair_of_row = _over_tiles(tile, n_tiles, jnp.full((M,), P, jnp.int32),
+                              (dest, counts))
+    return pos.astype(jnp.int32), pair_of_row, tile_group, n_tiles
 
 
 def block_rows(pairs: int) -> int:

@@ -44,19 +44,25 @@ def routing_plan(dest: jax.Array, n_dest: int,
     destination (in row order) and the rows each destination gets.  A
     row whose ``dest`` is ``n_dest`` or more goes nowhere: it is counted
     for no destination and its rank means nothing.  The exchange packs
-    its send buffer by it, and the routed expert layer (models/moe.py)
-    puts (token, expert) pairs into expert order by it: ``counts`` is the
-    experts' load.  ``impl`` as in :func:`partition_exchange`."""
+    its send buffer by ``(dest, rank)`` and never inverts that, so it
+    needs no sort.  The routed expert layer (models/moe.py) takes the
+    experts' load from ``counts``; the pairs in expert order are a sort
+    by destination of its own (``moe.expert_order``), and the ranks say
+    where that order puts each pair.  ``impl`` as in
+    :func:`partition_exchange`."""
     if impl == "radix":
         # fused plan: one histogram kernel pass feeds both outputs
         from ..ops.radix_sort import radix_partition_plan
         return radix_partition_plan(dest, n_dest)
     # one-hot cumsum: rank[i] = #{j < i : dest[j] == dest[i]}
-    # (O(N * n_dest) elementwise, n_dest small; avoids a sort)
-    onehot = (dest[:, None] == jnp.arange(n_dest)[None, :]).astype(jnp.int32)
-    rank = jnp.take_along_axis(
-        jnp.cumsum(onehot, axis=0) - 1,
-        jnp.clip(dest, 0, n_dest - 1)[:, None], axis=1)[:, 0]
+    # (O(N * n_dest) elementwise, n_dest small; no sort), read at a
+    # row's own column by a compare and a sum over the columns: one
+    # term is not zero, and no row is moved by its index.  A row out of
+    # range reads the column it is clipped to, as it always has.
+    at = jnp.arange(n_dest)[None, :]
+    onehot = (dest[:, None] == at).astype(jnp.int32)
+    mine = jnp.clip(dest, 0, n_dest - 1)[:, None] == at
+    rank = jnp.where(mine, jnp.cumsum(onehot, axis=0) - 1, 0).sum(axis=1)
     return rank, onehot.sum(axis=0)
 
 

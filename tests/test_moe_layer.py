@@ -70,6 +70,121 @@ def test_expert_order_places_every_held_pair_once(name):
             1, -(-len(rows) // bm))
 
 
+# The plain reference of the plan: a stable argsort by destination, the
+# held experts' pairs laid out a tile-aligned run each.
+
+def _plain_order(dest, n_held, bm, max_tiles):
+    P, M = len(dest), max_tiles * bm
+    in_order = np.argsort(np.minimum(dest, n_held), kind="stable")
+    counts = np.array([(dest == g).sum() for g in range(n_held)])
+    pos, pair_of_row = np.full(P, M), np.full(M, P)
+    tile_group, row, taken = [], 0, 0
+    for g, n in enumerate(counts):
+        mine = in_order[taken:taken + n]
+        pair_of_row[row:row + n] = mine
+        pos[mine] = row + np.arange(n)
+        tiles = max(-(-n // bm), 1)
+        tile_group += [g] * tiles
+        row, taken = row + tiles * bm, taken + n
+    n_tiles = len(tile_group)
+    tile_group += [n_held - 1] * (max_tiles - n_tiles)
+    return pos, pair_of_row, np.array(tile_group), n_tiles, counts
+
+
+def _routed_dest(score, k, n_tokens):
+    """Destinations as the layer makes them: ``route`` over 64 experts
+    of which the first 8 are held (8 = landed elsewhere)."""
+    flat = jnp.asarray(RNG.normal(size=(n_tokens, 32)), jnp.float32)
+    w = jnp.asarray(RNG.normal(size=(32, 64)), jnp.float32)
+    chosen, _ = moe.route(flat, w, None, k, score)
+    return np.where(np.asarray(chosen) < 8, np.asarray(chosen), 8).reshape(-1)
+
+
+#: ``dest [P]`` among 8 held experts; 768 pairs are `train-mellum2-long`'s
+#: 196,608 scaled down: not a power of two
+PLAN_CASES = {
+    "sigmoid top-4, 512 pairs": lambda: _routed_dest("sigmoid", 4, 128),
+    "softmax top-8, 768 pairs": lambda: _routed_dest("softmax", 8, 96),
+    "every pair on one held expert": lambda: np.full(600, 5),
+    "no pair held": lambda: np.full(768, 8),
+    "an expert ends on a tile's last row": lambda: np.repeat(
+        [0, 8, 3, 3, 7, 8], [512, 40, 16, 496, 1, 87]),
+}
+
+
+@pytest.mark.parametrize("bm", [16, 512])
+@pytest.mark.parametrize("name", sorted(PLAN_CASES))
+def test_the_plan_is_a_stable_argsort_by_destination(name, bm):
+    """``routing_plan`` and ``expert_order`` against the plain
+    reference, in every entry, rows past the tiles in use included."""
+    dest, G = PLAN_CASES[name](), 8
+    max_tiles = moe.tiles_for(len(dest), G, bm)
+    d = jnp.asarray(dest, jnp.int32)
+    plan = jax.jit(lambda d: moe.routing_plan(d, G))(d)
+    got = jax.jit(lambda d, plan: moe.expert_order(
+        d, plan, G, bm, max_tiles))(d, plan)
+    pos, pair_of_row, tile_group, n_tiles, counts = _plain_order(
+        dest, G, bm, max_tiles)
+    np.testing.assert_array_equal(plan[1], counts)
+    np.testing.assert_array_equal(got[0], pos)
+    np.testing.assert_array_equal(got[1], pair_of_row)
+    np.testing.assert_array_equal(got[2], tile_group)
+    assert got[3].tolist() == [n_tiles]
+    assert all(np.asarray(x).dtype == np.int32 for x in got)
+
+
+def _route_by_gather(flat, w_router, bias, top_k, score="sigmoid",
+                     scale=1.0):
+    """``moe.route`` as it picked the chosen scores until PR 40: one
+    scalar an index."""
+    logits = jnp.einsum("ne,ex->nx", flat.astype(jnp.float32), w_router,
+                        precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        s, eps = jax.nn.softmax(logits, axis=-1), 0.0
+    else:
+        s, eps = jax.nn.sigmoid(logits), 1e-6
+    biased = jax.lax.stop_gradient(s)
+    if bias is not None:
+        biased = biased + jax.lax.stop_gradient(bias)
+    _, chosen = jax.lax.top_k(biased, top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=1)
+    weights = picked / (picked.sum(axis=-1, keepdims=True) + eps)
+    return chosen, weights * jnp.float32(scale) if scale != 1.0 else weights
+
+
+@pytest.mark.parametrize("score, k, biased, scale", [
+    ("sigmoid", 4, True, 1.0), ("softmax", 8, False, 1.0),
+    ("sigmoid", 4, True, 1.8)],
+    ids=["sigmoid top-4", "softmax top-8", "sigmoid top-4 scaled"])
+def test_route_picks_the_chosen_scores_as_a_gather_does(score, k, biased,
+                                                        scale):
+    """The choice, the weights and the weights' gradient for the tokens
+    and the router, against ``take_along_axis`` and its scatter-add."""
+    flat = jnp.asarray(RNG.normal(size=(96, 32)), jnp.float32)
+    w = jnp.asarray(RNG.normal(size=(32, 64)), jnp.float32)
+    bias = jnp.asarray(0.1 * RNG.normal(size=64), jnp.float32) if biased \
+        else None
+    d_weights = jnp.asarray(RNG.normal(size=(96, k)), jnp.float32)
+
+    def with_gradients(route):
+        def f(flat, w):
+            chosen, weights = route(flat, w, bias, k, score, scale)
+            return (weights * d_weights).sum(), (chosen, weights)
+
+        (_, routed), grads = jax.jit(jax.value_and_grad(
+            f, argnums=(0, 1), has_aux=True))(flat, w)
+        return [np.asarray(x) for x in (*routed, *grads)]
+
+    got, want = with_gradients(moe.route), with_gradients(_route_by_gather)
+    np.testing.assert_array_equal(got[0], want[0])
+    # one term of a sum is not zero; the quotient after it is compiled
+    # in another fusion: at most float32's last place
+    np.testing.assert_allclose(got[1], want[1], rtol=2.5e-7, atol=0)
+    assert np.abs(want[2]).max() > 1e-3 and np.abs(want[3]).max() > 1e-3
+    for g, w_ in zip(got[2:], want[2:]):
+        np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-7)
+
+
 # -- rows into expert order and back ----------------------------------------
 #
 # The plain reference: every row gathered, whether its tile is in use or
@@ -729,6 +844,68 @@ def test_no_row_mover_compiled_for_a_v5e_touches_every_row(one_chip, mosaic,
     reserved = lambda pair: sum(
         c.memory_analysis().temp_size_in_bytes for c in pair)
     assert reserved(loops) < reserved(gathers)
+
+
+def _moves_by_index(text, entries):
+    """The gathers and scatters of a compiled program's text whose index
+    operand has one of *entries* index vectors."""
+    import re
+
+    shape_of = _hlo_shapes(text)
+    moved = []
+    for line in re.findall(r"^.* (?:gather|scatter)\(.*$", text, re.M):
+        head = line.split(", metadata")[0]
+        indices = re.findall(r"%[\w.\-]+", head.split("(", 1)[1])[1]
+        shape = list(shape_of.get(indices, ()))
+        at = re.search(r"index_vector_dim=(\d+)", head)
+        if at and int(at.group(1)) < len(shape):
+            del shape[int(at.group(1))]
+        if int(np.prod(shape)) in entries:
+            moved.append((indices, shape_of.get(indices)))
+    return moved
+
+
+@pytest.mark.parametrize(
+    "N, E, k, score",
+    [(32768, 2048, 4, "sigmoid"), (24576, 2304, 8, "softmax"),
+     (8192, 2048, 4, "sigmoid")],
+    ids=["train-lfm2moe-8k", "train-mellum2-long", "train-glm47flash-mla"])
+def test_no_move_a_pair_in_the_plan_compiled_for_a_v5e(one_chip, mosaic, N,
+                                                       E, k, score):
+    """``route``, ``routing_plan`` and ``expert_order`` as the layer
+    calls them at a cell's shape, 8 of 64 experts held, value and
+    gradient: no gather and no scatter of the compiled program has an
+    index operand of ``N k`` or ``M`` entries (the chosen scores, the
+    rank and their transposes are compares and sums; the inversion is a
+    sort and a run of 512 a tile).  Written with ``take_along_axis`` the
+    program has them, so the check sees."""
+    bm = 512
+    max_tiles = moe.tiles_for(N * k, 8, bm)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def compiled(route):
+        def routed(flat, w_router, bias):
+            chosen, weights = route(flat, w_router, bias, k, score)
+            dest = jnp.where(chosen < 8, chosen, 8).reshape(N * k)
+            plan = moe.routing_plan(dest, 8)
+            order = moe.expert_order(dest, plan, 8, bm, max_tiles)
+            return weights, (chosen, plan, order)
+
+        def f(flat, w_router, bias, d_weights):
+            weights, vjp, tables = jax.vjp(
+                lambda a, b: routed(a, b, bias), flat, w_router,
+                has_aux=True)
+            return weights, tables, vjp(d_weights)
+
+        return jax.jit(f).lower(
+            sds((N, E), jnp.bfloat16), sds((E, 64), jnp.float32),
+            sds((64,), jnp.float32), sds((N, k), jnp.float32)
+        ).compile().as_text()
+
+    entries = (N * k, max_tiles * bm)
+    assert not _moves_by_index(compiled(moe.route), entries)
+    assert _moves_by_index(compiled(_route_by_gather), entries)
 
 
 @pytest.mark.parametrize("N, E, Fe, k",

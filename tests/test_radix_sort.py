@@ -136,6 +136,29 @@ def test_partition_plan_bit_equal_to_onehot_plan():
                               np.asarray(onehot.sum(axis=0))), (n, P)
 
 
+@pytest.mark.parametrize("n, P", [(7, 2), (300, 4), (2 * BLOCK + 31, 8)])
+def test_onehot_plan_reads_a_row_out_of_range_at_the_column_it_clips_to(
+        n, P):
+    """``routing_plan``'s ``"lax"`` formulation picks a row's rank out
+    of the one-hot cumsum by a compare and a sum, no longer by
+    ``take_along_axis``: every entry equals the gathered one, those of
+    rows that go nowhere (``dest`` of ``P``, past it, or negative)
+    included — their rank "means nothing" and stays what it was."""
+    from mapreduce_tpu.parallel.shuffle import routing_plan
+
+    rng = np.random.default_rng(31 + n)
+    dest = rng.integers(-1, P + 3, n).astype(np.int32)
+    dest[[0, n // 2, -1]] = [P, P + 2, -1]
+    rank, counts = jax.jit(lambda d: routing_plan(d, P))(jnp.asarray(dest))
+    onehot = (dest[:, None] == np.arange(P)[None, :]).astype(np.int32)
+    want = np.take_along_axis(
+        np.cumsum(onehot, axis=0) - 1,
+        np.clip(dest, 0, P - 1)[:, None], axis=1)[:, 0]
+    assert np.array_equal(np.asarray(rank), want)
+    assert np.array_equal(np.asarray(counts), onehot.sum(axis=0))
+    assert rank.dtype == counts.dtype == jnp.int32
+
+
 def test_sorted_unique_reduce_radix_all_arities():
     """Every record arity rides the rank-sort gather transport:
     unit values, scalar values, two-lane values, and a three-lane
