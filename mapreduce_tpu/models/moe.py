@@ -33,16 +33,20 @@ the rows are moved into expert order and back (``_dispatch``,
 a tile a trip, from the row side: no operation of theirs touches the
 buffers whole, and the gate ``silu(a) * u`` between the products and the
 sum of the first two products' gradients for the rows are kernels on
-that bound too (``ops/grouped_matmul.gated``, ``twice``).  What does not
-follow the tiles in use: the plan over all ``N k`` pairs, and the
-zeroing of the loops' carries (PERF.md section 5).  The plan costs the
-pairs, whatever lands, but moves none of them by its index: the chosen
-scores are a compare and a sum over the experts (``route``), a held
-expert's load a compare and a sum over the pairs (the counts of the
-exchange's plan, ``parallel/shuffle.routing_plan``, whose ranks only
-``expert_order``'s ``pos`` reads), and the pairs in expert order one
-stable sort by destination, from which a tile takes a contiguous run
-(``expert_order``).
+that bound too (``ops/grouped_matmul.gated``, ``twice``).  Where the
+kernels run, the rows' buffers the loops fill (``xs`` into the products,
+``d_ys`` out of the combine's transpose) are not zeroed first: no reader
+looks past the tiles in use (``ops/grouped_matmul.rows_buffer``).  What
+does not follow the tiles in use: the plan over all ``N k`` pairs, and
+the zeros of the carries whose every row is read (the ``[N, E]`` sums
+into token order, ``d_w``, ``pair_of_row``; PERF.md section 5).  The
+plan costs the pairs, whatever lands, but moves none of them by its
+index: the chosen scores are a compare and a sum over the experts
+(``route``), a held expert's load a compare and a sum over the pairs
+(the counts of the exchange's plan, ``parallel/shuffle.routing_plan``,
+whose ranks only ``expert_order``'s ``pos`` reads), and the pairs in
+expert order one stable sort by destination, from which a tile takes a
+contiguous run (``expert_order``).
 """
 
 from __future__ import annotations
@@ -52,7 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import gated, grouped_matmul, twice
+from ..ops.grouped_matmul import gated, grouped_matmul, rows_buffer, twice
 from ..parallel.shuffle import routing_plan
 
 #: rows of a tile of the grouped products; every held expert's rows start
@@ -164,19 +168,27 @@ def tiles_for(pairs: int, n_held: int, block_m: int) -> int:
 # side, by a loop over the tiles in use (the grouped kernels' own bound,
 # ``n_tiles``, a value of the run), so what a call costs follows the
 # pairs that landed here as the arithmetic does, not the buffers' size.
-# Rows past the tiles in use are never gathered and never read.  A tile
-# a trip: two or four read the same on the chip (PERF.md section 6).
+# Rows past the tiles in use are never gathered and never read, so the
+# ``[M, E]`` buffers a loop fills start unwritten where the kernels run
+# (``rows_buffer``: one Pallas call that writes nothing, not a fill of
+# every row); where they do not, ragged_dot reads every row and they
+# start as zeros.  The carries in token order (``[N, E]``, ``d_w``) and
+# ``expert_order``'s ``pair_of_row`` keep their fill: every row of them
+# is read.  A tile a trip: two or four read the same on the chip
+# (PERF.md section 6).
 
 def _over_tiles(tile, n_tiles, carry, like):
     """``tile(t, carry) -> carry`` for the tiles in use, ``t <
     n_tiles[0]``, in order, as a ``lax.fori_loop``.  The carry takes the
     varying mesh axes of the arrays *like* (a loop's carry keeps its
     type)."""
-    axes = tuple(set().union(*(jax.typeof(a).vma for a in like)))
-    if axes:
-        carry = jax.tree.map(
-            lambda c: jax.lax.pcast(c, axes, to="varying"), carry)
-    return jax.lax.fori_loop(0, n_tiles[0], tile, carry)
+    axes = frozenset().union(*(jax.typeof(a).vma for a in like))
+
+    def cast(c):
+        missing = tuple(axes - jax.typeof(c).vma)
+        return jax.lax.pcast(c, missing, to="varying") if missing else c
+
+    return jax.lax.fori_loop(0, n_tiles[0], tile, jax.tree.map(cast, carry))
 
 
 def _tile_of(table, t, block_m: int):
@@ -189,16 +201,18 @@ def _rows_in(src, tok_of_row, n_tiles, block_m: int):
     """``[M, E]`` in *src*'s type: row r of a tile in use holds
     ``src[tok_of_row[r]]``, zeros where that is out of range (a padding
     row, as ``moe_tgmm`` needs); rows past the tiles in use are not
-    gathered."""
+    gathered, and hold nothing where the kernels run
+    (:func:`rows_buffer`) and zeros where they do not."""
     def tile(t, xs):
         rows = src.at[_tile_of(tok_of_row, t, block_m)].get(
             mode="fill", fill_value=0)
         return jax.lax.dynamic_update_slice_in_dim(xs, rows, t * block_m, 0)
 
+    like = (src, tok_of_row, n_tiles)
     return _over_tiles(
         tile, n_tiles,
-        jnp.zeros((tok_of_row.shape[0], src.shape[1]), src.dtype),
-        (src, tok_of_row, n_tiles))
+        rows_buffer((tok_of_row.shape[0], src.shape[1]), src.dtype, n_tiles,
+                    like), like)
 
 
 def _rows_out(rows, tok_of_row, n_tiles, block_m: int, n_tokens: int,
@@ -266,7 +280,9 @@ def _combine_fwd(ys, weights, tok_of_row, slot_of_row, n_tiles, block_m):
 def _combine_bwd(block_m, res, d_out):
     """One loop over the tiles in use, with ``g = d_out[tok_of_row[r]]``:
     ``d_ys[r] = w_r g``, and ``<ys[r], g>`` is ``d_w`` of the pair row r
-    holds (a pair that landed elsewhere keeps 0)."""
+    holds (a pair that landed elsewhere keeps 0).  ``d_ys`` past the
+    tiles in use is what :func:`rows_buffer` leaves there, as in
+    ``_rows_in``; every entry of ``d_w`` is read, and starts at 0."""
     ys, weights, tok_of_row, slot_of_row, n_tiles = res
     past = weights.shape[0] + jnp.arange(block_m, dtype=tok_of_row.dtype)
 
@@ -282,11 +298,11 @@ def _combine_bwd(block_m, res, d_out):
                 d_w.at[jnp.where(tok < weights.shape[0], tok, past),
                        slot].set(dw, mode="drop", unique_indices=True))
 
+    like = (ys, weights, d_out, tok_of_row, n_tiles)
     d_ys, d_w = _over_tiles(
         tile, n_tiles,
-        (jnp.zeros(ys.shape, ys.dtype),
-         jnp.zeros(weights.shape, jnp.float32)),
-        (ys, weights, d_out, tok_of_row, n_tiles))
+        (rows_buffer(ys.shape, ys.dtype, n_tiles, like),
+         jnp.zeros(weights.shape, jnp.float32)), like)
     return d_ys, d_w, None, None, None
 
 

@@ -40,6 +40,14 @@ inside ``shard_map``, where the Pallas interpreter cannot run them
 (:func:`_runs_kernels`), the products are ``jax.lax.ragged_dot`` over
 the padded group sizes, and the gate and the sum are XLA's own over
 every row.
+
+The contract runs the other way too: a buffer whose every reader ends at
+``n_tiles`` needs no value past it.  :func:`rows_buffer` hands the loops
+that fill such buffers (``models/moe.py``) one that nobody writes, a
+Pallas call that allocates it and does nothing (``moe_rows_unwritten``),
+where XLA would fill every row of ``jnp.zeros``; where the kernels
+cannot run it is zeros, because the products and the gate there read
+every row.
 """
 
 from __future__ import annotations
@@ -51,6 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics as _obs
 from .pallas_compat import (default_interpret, pallas_call, pick_lane_block,
                             sds)
 
@@ -367,3 +376,58 @@ def twice(x: jax.Array, n_tiles: jax.Array, *, block_m: int,
     if not _runs_kernels(interpret, n_tiles):
         return x, x
     return _twice(x, n_tiles, int(block_m), interpret)
+
+
+# -- a buffer for the rows of the tiles in use --------------------------------
+
+_ROW_BUFFERS = _obs.gauge(
+    "mrtpu_moe_row_buffers",
+    "rows' buffers in expert order (xs, d_ys: [M, E]) that the expert "
+    "layer's loops start from, counted as a program traces them (labels: "
+    "kind): kind=unwritten a Pallas call allocates and nobody writes "
+    "(where the grouped kernels run, and every reader ends at the tiles in "
+    "use), "
+    "kind=zeroed filled with zeros (where the products are ragged_dot and "
+    "may read every row); a layer traced once counts once, whatever the "
+    "steps")
+
+
+def _leave_unwritten(pids, *refs):
+    del pids, refs
+
+
+def rows_buffer(shape, dtype, n_tiles: jax.Array, like, *,
+                interpret=None) -> jax.Array:
+    """The buffer a loop over the first ``n_tiles [1] int32`` tiles
+    writes its rows into; *like* are the arrays the loop reads.  Where
+    the kernels run (:func:`_runs_kernels`) nothing reads a row past
+    those tiles, and it is one Pallas call whose output stays in device
+    memory (``pl.ANY``) and whose body touches nothing,
+    ``moe_rows_unwritten``: no operation fills it (under the interpreter
+    it reads NaN, so a read past the tiles poisons what reads it).  The
+    call takes *like* and reads none of them: it cannot be placed before
+    they are made, where one with no operand is placed at the step's
+    start and every such buffer lives through the whole step.  Where the
+    kernels cannot run it is zeros: ``ragged_dot`` and the plain gate
+    there take every row, and NaN times 0 is NaN.  Counted in
+    ``mrtpu_moe_row_buffers``."""
+    interpret = default_interpret(interpret)
+    if not _runs_kernels(interpret, n_tiles):
+        _ROW_BUFFERS.inc(kind="zeroed")
+        return jnp.zeros(shape, dtype)
+    _ROW_BUFFERS.inc(kind="unwritten")
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pallas_call(
+        _leave_unwritten,
+        name="moe_rows_unwritten",
+        grid=(1,),
+        in_specs=[anywhere] * len(like),
+        out_specs=anywhere,
+        out_shape=jax.ShapeDtypeStruct(
+            tuple(shape), dtype,
+            vma=frozenset().union(*(jax.typeof(a).vma for a in like))),
+        # it does no work: said, not left to the scheduler's guess
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0,
+                                      bytes_accessed=0),
+        interpret=interpret,
+    )(*like)

@@ -294,9 +294,12 @@ def test_dispatch_moves_the_rows_in_use_as_the_gather_of_every_row(name):
     xs, d_flat = map(np.asarray, loops(a["flat"], a["rows"]))
     want_xs, want_d_flat = map(np.asarray, gathers(a["flat"], a["rows"]))
     assert np.array_equal(xs[:U], want_xs[:U])     # a copy: to the bit
+    # past the tiles in use nothing is written: the interpreter's NaN
+    assert np.isnan(xs[U:]).all()
     np.testing.assert_allclose(d_flat, want_d_flat, rtol=1e-6, atol=1e-6)
     again = loops(a["flat"], a["rows"])
-    assert np.array_equal(xs, again[0]) and np.array_equal(d_flat, again[1])
+    assert (np.array_equal(xs, again[0], equal_nan=True)
+            and np.array_equal(d_flat, again[1]))
 
 
 @pytest.mark.parametrize("name", sorted(ROW_CASES))
@@ -465,6 +468,91 @@ def test_the_gate_and_the_fan_out_refuse_rows_that_are_not_whole_tiles():
         gated(a[:32], a[:32, :64], n, block_m=16)
 
 
+# -- rows past the tiles in use: nobody reads them --------------------------
+#
+# Under the interpreter the rows' buffers the loops start from read NaN
+# (``ops/grouped_matmul.rows_buffer``): a read of a row past the tiles in
+# use would carry it into the result.
+
+#: ``(dest [N k], held experts, every tile in use)``: pairs' experts
+POISON_CASES = {
+    "no pair lands, one tile": (np.full(40, 1), 1, False),
+    "some pairs land": (RNG.integers(0, 4, size=120), 3, False),
+    "every pair lands": (RNG.integers(0, 3, size=120), 3, False),
+    "every tile in use": (RNG.integers(0, 3, size=120), 3, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(POISON_CASES))
+def test_no_reader_looks_past_the_tiles_in_use(name, monkeypatch):
+    """``_dispatch``, the grouped products with the gate between them,
+    and ``_combine``, with every gradient, kernels interpreted: ``xs``
+    and ``d_ys`` hold NaN past the tiles in use, and yet the output and
+    the gradients for the tokens, the weights and the three matrices are
+    finite and, to the bit, what the chain gives from zeroed buffers."""
+    dest, G, whole = POISON_CASES[name]
+    k, bm, E, Fe = 4, 16, 32, 48
+    N = len(dest) // k
+    dest = jnp.asarray(dest, jnp.int32)
+    plan = moe.routing_plan(dest, G)
+    max_tiles = moe.tiles_for(len(dest), G, bm)
+    if whole:
+        max_tiles = int(moe.expert_order(dest, plan, G, bm, max_tiles)[3][0])
+    _, pair_of_row, tile_group, n_tiles = moe.expert_order(
+        dest, plan, G, bm, max_tiles)
+    U, M = int(n_tiles[0]) * bm, max_tiles * bm
+    assert (U == M) == whole
+    tok = jnp.where(pair_of_row < N * k, pair_of_row // k, N)
+    slot = pair_of_row % k
+    flat = jnp.asarray(RNG.normal(size=(N, E)), jnp.bfloat16)
+    weights = jnp.asarray(np.abs(RNG.normal(size=(N, k))), jnp.float32)
+    mats = tuple(jnp.asarray(RNG.normal(size=shape) / 4, jnp.float32)
+                 for shape in ((G, E, Fe), (G, E, Fe), (G, Fe, E)))
+    d_out = jnp.asarray(RNG.normal(size=(N, E)), jnp.float32)
+
+    def chain(flat, weights, w_gate, w_in, w_out):
+        product = lambda x, w: grouped_matmul(x, w, tile_group, n_tiles,
+                                              block_m=bm)
+        xs = moe._dispatch(flat, tok, n_tiles, bm)
+        for_gate, for_up = twice(xs, n_tiles, block_m=bm)
+        act = gated(product(for_gate, w_gate), product(for_up, w_in),
+                    n_tiles, block_m=bm)
+        ys = product(act, w_out)
+        return moe._combine(ys, weights, tok, slot, n_tiles, bm), (xs, ys)
+
+    def run():
+        @jax.jit
+        def f(flat, weights, mats, d_out):
+            out, vjp, (xs, ys) = jax.vjp(chain, flat, weights, *mats,
+                                         has_aux=True)
+            _, vjp_ys = jax.vjp(lambda y: moe._combine(
+                y, weights, tok, slot, n_tiles, bm), ys)
+            return (out,) + vjp(d_out), (xs, vjp_ys(d_out)[0])
+
+        got, buffers = f(flat, weights, mats, d_out)
+        return ([np.asarray(x, np.float32) for x in got],
+                [np.asarray(x, np.float32) for x in buffers])
+
+    unwritten = REGISTRY.sum("mrtpu_moe_row_buffers", kind="unwritten")
+    poisoned, (xs, d_ys) = run()
+    assert REGISTRY.sum("mrtpu_moe_row_buffers", kind="unwritten") \
+        > unwritten
+    for rows in (xs, d_ys):
+        assert rows.shape == (M, E) and np.isnan(rows[U:]).all()
+        assert np.isfinite(rows[:U]).all()
+    assert all(np.isfinite(x).all() for x in poisoned)
+    if (dest < G).any():
+        assert np.abs(poisoned[0]).max() > 0.1
+
+    monkeypatch.setattr(moe, "rows_buffer",
+                        lambda shape, dtype, *_: jnp.zeros(shape, dtype))
+    zeroed, (xs0, d_ys0) = run()
+    assert not xs0[U:].any() and not d_ys0[U:].any()
+    assert np.array_equal(xs[:U], xs0[:U])
+    for a, b in zip(poisoned, zeroed):     # out, d_flat, d_w, three mats
+        np.testing.assert_array_equal(a, b)
+
+
 # -- the routed layer: the shares add up -------------------------------------
 
 SHARE = dict(vocab=32, embed=32, n_layers=1, n_heads=2, head_dim=16, ffn=32,
@@ -558,13 +646,18 @@ def test_the_layer_on_the_cpu_is_the_layer_with_the_plain_gate(monkeypatch):
         return [np.asarray(x) for x in
                 (out, d_h, *(d_lp[n] for n in sorted(lp)))]
 
+    buffers = lambda kind: REGISTRY.sum("mrtpu_moe_row_buffers", kind=kind)
     before = built()
+    unwritten, zeroed = buffers("unwritten"), buffers("zeroed")
     now = with_gradients()
     assert built() == before
+    # ragged_dot may read every row: the loops' buffers start as zeros
+    assert buffers("unwritten") == unwritten and buffers("zeroed") > zeroed
     assert np.abs(now[0]).max() > 0.1 and np.abs(now[1]).max() > 0.1
     np.testing.assert_allclose(np.asarray(layer(check_vma=False)(h, lp)),
                                now[0], rtol=1e-4, atol=1e-5)
     assert built() == before + 1
+    assert buffers("unwritten") == unwritten + 1
     monkeypatch.setattr(moe, "gated",
                         lambda a, u, n_tiles, block_m: _plain_gate(a, u))
     monkeypatch.setattr(moe, "twice", lambda x, n_tiles, block_m: (x, x))
@@ -906,6 +999,60 @@ def test_no_move_a_pair_in_the_plan_compiled_for_a_v5e(one_chip, mosaic, N,
     entries = (N * k, max_tiles * bm)
     assert not _moves_by_index(compiled(moe.route), entries)
     assert _moves_by_index(compiled(_route_by_gather), entries)
+
+
+@pytest.mark.parametrize(
+    "N, E, k", [(32768, 2048, 4), (24576, 2304, 8), (8192, 2048, 4)],
+    ids=["train-lfm2moe-8k", "train-mellum2-long", "train-glm47flash-mla"])
+def test_no_row_buffer_compiled_for_a_v5e_is_filled_first(one_chip, mosaic,
+                                                          monkeypatch, N, E,
+                                                          k):
+    """``_dispatch`` forward and ``_combine`` with its transpose at a
+    cell's shape, 8 held experts: the ``[M, E]`` buffers the loops fill
+    (``xs``, ``d_ys``) come from ``moe_rows_unwritten``, and no broadcast
+    or copy of the compiled programs has a result of ``M`` rows of
+    ``E``.  Every such call takes operands: one with none is placed at
+    the start of the whole training step, where twelve of them outgrow
+    a v5e's memory at `train-mellum2-long`'s shape.  Started from
+    ``jnp.zeros`` the programs have such a broadcast, so the check
+    sees."""
+    import re
+
+    bm = 512
+    M = moe.tiles_for(N * k, 8, bm) * bm
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def compiled():     # traced anew: jit's cache keys on the function
+        def rows_in(flat, tok, n_tiles):
+            return moe._dispatch(flat, tok, n_tiles, bm)
+
+        def rows_out(ys, weights, d_out, tok, slot, n_tiles):
+            out, vjp = jax.vjp(lambda y, w: moe._combine(
+                y, w, tok, slot, n_tiles, bm), ys, weights)
+            return out, vjp(d_out)
+
+        table, n = sds((M,), jnp.int32), sds((1,), jnp.int32)
+        return [jax.jit(rows_in).lower(sds((N, E), jnp.bfloat16), table,
+                                       n).compile().as_text(),
+                jax.jit(rows_out).lower(
+                    sds((M, E), jnp.bfloat16), sds((N, k), jnp.float32),
+                    sds((N, E), jnp.float32), table, table,
+                    n).compile().as_text()]
+
+    def filled(text):
+        return re.findall(r"^.* = \w+\[%d,%d\][^ ]* (?:broadcast|copy)\("
+                          % (M, E), text, re.M)
+
+    texts = compiled()
+    for t in texts:
+        calls = re.findall(
+            r"%[\w.]*moe_rows_unwritten[\w.]* = \S+ custom-call\((.*?)\)", t)
+        assert calls and all(calls)
+    assert not any(filled(t) for t in texts)
+    monkeypatch.setattr(moe, "rows_buffer",
+                        lambda shape, dtype, *_: jnp.zeros(shape, dtype))
+    assert all(filled(t) for t in compiled())
 
 
 @pytest.mark.parametrize("N, E, Fe, k",
